@@ -422,6 +422,8 @@ def main(argv: List[str] = None) -> int:
         print("tasks: train | predict | convert_model | refit | serve "
               "| route | continual | sweep")
         return 0
+    from .utils.env import configure_compile_cache
+    configure_compile_cache()
     params = _parse_args(argv)
     config = Config(params)
     task = config.task

@@ -5,7 +5,7 @@ PAPERS.md): training data arrives as a stream of finite batch shards
 on disk, not a resident matrix.  :class:`DirectoryBatchSource` tails a
 directory in NAME order — producers write shards under temporary names
 and rename into place, so a sorted listing is a stable consumption
-order — and owns the failure taxonomy of getting bytes off disk:
+order — and owns the failure classes of getting bytes off disk:
 
 - **transient** read failures (``OSError``: flaky NFS, a mid-copy
   file) retry under bounded exponential backoff

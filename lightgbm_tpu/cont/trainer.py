@@ -636,7 +636,7 @@ class ContinualTrainer:
         except NumericalHealthError as exc:
             box["error"] = exc
         except BaseException as exc:       # noqa: BLE001 - the loop
-            box["error"] = exc             # owns the failure taxonomy
+            box["error"] = exc             # owns the failure classes
 
     def _refit_attempt(self, batch: Batch, eng: Dict[str, Any],
                        start_iter: int, box: Dict[str, Any],

@@ -195,6 +195,8 @@ def train(params: Dict[str, Any], train_set: Dataset,
     byte-identical to the in-memory path, and checkpoint manifests
     record the cache identity so resume never re-bins published
     chunks."""
+    from .utils.env import configure_compile_cache
+    configure_compile_cache()
     params = dict(params)
     # canonical name first, then aliases (Config resolution order);
     # num_boost_round is accepted for reference-python compatibility

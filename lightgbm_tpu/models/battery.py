@@ -299,11 +299,10 @@ def _train_group_vmapped(dataset, specs: Sequence[MemberSpec],
         # shared dataset replicates — no collectives, hence the exact
         # same per-member program (parity preserved by construction)
         from jax.sharding import PartitionSpec as P
-        from ..parallel.learners import shard_map_compat
         Pb, R = P("battery"), P()
         in_specs = (Pb, Pb, Pb, Pb, R, R, R, R, R, R, Pb, R, Pb, Pb)
-        fn = shard_map_compat(
-            fn, mesh, in_specs=in_specs,
+        fn = jax.shard_map(
+            fn, mesh=mesh, check_vma=False, in_specs=in_specs,
             out_specs=(Pb, Pb, Pb, Pb, Pb, Pb, Pb))
     fn = jax.jit(fn)
 

@@ -174,12 +174,20 @@ class GrowParams:
     vals_i8: bool = True
     # best-split engine: "xla" = the vectorized jnp scans in
     # ops/split.py (every tier); "pallas" = the on-chip kernel family
-    # (find_best_split_pallas + the fused histogram→split epilogue in
-    # the batched passes) — numerical features, serial learner, no
-    # EFB/forced/c2f; the DRIVER gates this (models/gbdt.py records
-    # the gate that rejected it), build_tree only falls back silently
-    # for the sub-paths the kernel cannot serve
+    # (find_best_split_pallas and, with split_fused, the histogram→split
+    # epilogue in the batched passes) — numerical features, serial
+    # learner, no EFB/forced/c2f; the DRIVER gates this (models/gbdt.py
+    # records the gate that rejected it), build_tree only falls back
+    # silently for the sub-paths the kernel cannot serve
     split_kernel: str = "xla"
+    # the batched wave passes also scan their own accumulated tile in
+    # VMEM for the smaller children (the fused histogram→split
+    # epilogue); off, every child goes through the standalone kernel.
+    # Needs split_kernel=pallas, wave growth and hist_impl=pallas.
+    # The DRIVER decides (models/gbdt.py): Mosaic refuses the epilogue,
+    # so it is off wherever Pallas is compiled and the tier record
+    # carries gates.split_fused
+    split_fused: bool = False
     # >0: relative gain tolerance for preferring an already-ARMED leaf
     # over a fresh unarmed one when their best gains are within
     # tol*|best|.  Late boosting iterations have near-flat gains and
@@ -602,9 +610,13 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
     # fused histogram→split epilogue: the batched pass scans its own
     # accumulated tile in VMEM for the smaller children (the larger,
     # subtraction-trick children go through the standalone kernel on
-    # the pool histogram)
-    use_split_fused = (use_split_pallas and use_wave and
-                       p.hist_impl == "pallas")
+    # the pool histogram).  Driver-gated (GrowParams.split_fused).
+    use_split_fused = p.split_fused
+    if use_split_fused:
+        assert use_split_pallas and use_wave and \
+            p.hist_impl == "pallas", \
+            "split_fused: split_kernel=pallas, wave growth and " \
+            "hist_impl=pallas (driver-gated)"
     if do_spec:
         base_vals = jnp.stack([grad * sample_mask, hess * sample_mask,
                                sample_mask], axis=-1)

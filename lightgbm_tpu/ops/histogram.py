@@ -153,11 +153,8 @@ def _compiler_params():
     """Raise Mosaic's scoped-VMEM ceiling (default ~16-32 MB) so the
     large one-hot row tiles the tiler picks actually compile; v5e has
     128 MB of VMEM."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
-    except Exception:  # pragma: no cover - older pallas versions
-        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
 def _split_hi_lo(v: jax.Array) -> jax.Array:

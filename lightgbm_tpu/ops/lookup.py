@@ -18,6 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..utils.env import pallas_interpret, pallas_interpret_forced
+
 __all__ = ["take_small", "MAX_LOOKUP_TABLE"]
 
 MAX_LOOKUP_TABLE = 512
@@ -57,13 +59,38 @@ def _take_small_pallas(vals: jax.Array, idx: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
+        interpret=pallas_interpret(),
     )(ix[None, :], vt)
     return out[0, :n]
 
 
 def take_small(vals: jax.Array, idx: jax.Array) -> jax.Array:
-    """``vals[idx]`` with the TPU-friendly kernel when applicable."""
-    if (vals.ndim == 1 and vals.shape[0] <= MAX_LOOKUP_TABLE and
-            jax.default_backend() not in ("cpu",)):
+    """``vals[idx]`` with the TPU-friendly kernel when applicable.
+
+    A concrete ``idx`` placed over several devices (the parallel
+    learners' leaf vector) runs the kernel under ``shard_map`` with
+    ``idx``'s own sharding: XLA cannot partition a Mosaic kernel by
+    itself ("Mosaic kernels cannot be automatically partitioned").
+    Pass the mesh-placed array whole and slice the result: an uneven
+    slice of it comes back replicated, and every device then looks up
+    every row.  A traced ``idx`` is already inside the caller's
+    ``shard_map`` (or on one device)."""
+    if not (vals.ndim == 1 and vals.shape[0] <= MAX_LOOKUP_TABLE and
+            (jax.default_backend() != "cpu" or pallas_interpret_forced())):
+        return jnp.take(vals, idx)
+    if isinstance(idx, jax.core.Tracer) or \
+            len(idx.sharding.device_set) == 1:
         return _take_small_pallas(vals, idx)
-    return jnp.take(vals, idx)
+    sharding = idx.sharding
+    if not isinstance(sharding, jax.sharding.NamedSharding):
+        return jnp.take(vals, idx)     # XLA partitions its own gather
+    return _lookup_over(sharding.mesh, sharding.spec)(vals, idx)
+
+
+@functools.lru_cache(maxsize=8)
+def _lookup_over(mesh, spec):
+    """The lookup kernel over ``mesh``, ``idx`` laid out by ``spec``
+    and the table replicated; cached so repeated calls reuse one jit."""
+    return jax.jit(jax.shard_map(
+        _take_small_pallas, mesh=mesh, check_vma=False,
+        in_specs=(jax.sharding.PartitionSpec(), spec), out_specs=spec))

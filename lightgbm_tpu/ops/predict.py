@@ -35,11 +35,12 @@ LOCATION, so a per-depth-step pointer chase can never win):
   statics), so steady-state serving never re-traces.
 
 Float64 end to end (thresholds, leaf values, accumulation) under a
-locally-scoped ``jax.experimental.enable_x64`` so the global f32
-default used by training kernels is untouched.  Accumulation order
-differs from the per-tree host loop only within a tree chunk (a
-k-strided reshape-sum instead of tree-by-tree adds); raw scores agree
-with the host loop to ~1e-13 relative.
+locally-scoped ``jax.enable_x64(True)`` so the global f32 default used
+by training kernels is untouched, and computed on the host CPU
+(:func:`engine_device`) whatever the session's default backend is.
+Accumulation order differs from the per-tree host loop only within a
+tree chunk (a k-strided reshape-sum instead of tree-by-tree adds); raw
+scores agree with the host loop to ~1e-13 relative.
 
 ``Tree.predict`` (models/tree.py) remains the single-tree oracle; the
 flatten→traverse round-trip is pinned against it in
@@ -711,7 +712,6 @@ class PredictEngine:
     def _run(self, flat: FlatForest, X: np.ndarray, n_trees: int,
              want_leaf: bool, es: bool, freq: int, margin: float,
              chunk_rows: Optional[int] = None, buckets=None):
-        import contextlib
         import jax
         import jax.numpy as jnp
 
@@ -727,16 +727,7 @@ class PredictEngine:
         if buckets is None:
             buckets = self._buckets(n, max_chunk)
         outs = []
-        # the engine is a host-memory-bound kernel: pin it to the CPU
-        # backend even when the session's default device is a TPU
-        dev_ctx = contextlib.nullcontext()
-        if jax.default_backend() != "cpu":
-            try:
-                cpu = jax.local_devices(backend="cpu")[0]
-                dev_ctx = jax.default_device(cpu)
-            except Exception:
-                pass
-        with dev_ctx, jax.experimental.enable_x64():
+        with jax.default_device(engine_device()), jax.enable_x64(True):
             tabs = flat.device_tables(n_trees, Tc)
             xmat_fn = _xmat_compiled()
             for start, rows, B in buckets:
@@ -805,6 +796,24 @@ class PredictEngine:
         out = self._run(flat, X, n_trees, True, False, 10, 10.0,
                         chunk_rows)
         return np.ascontiguousarray(out.T.astype(np.int32))
+
+
+def engine_device():
+    """The device the predict and SHAP engines compute on: the host
+    CPU, on every backend.  Their kernels are float64 end to end and
+    gather-heavy; nothing has moved them to an accelerator yet, so a
+    process that only scores never needs one (serve replicas run with
+    ``JAX_PLATFORMS=cpu``, serve/fleet.py).  Raises where the process
+    has no CPU backend (``JAX_PLATFORMS=tpu`` alone) instead of
+    computing somewhere unstated."""
+    import jax
+    return jax.local_devices(backend="cpu")[0]
+
+
+def engine_device_info() -> Dict[str, str]:
+    """``engine_device`` as a record for run_info and smoke output."""
+    dev = engine_device()
+    return {"platform": dev.platform, "device_kind": dev.device_kind}
 
 
 _ENGINE: Optional[PredictEngine] = None

@@ -25,7 +25,8 @@ set at (tree_chunk, depth+1, bucket) instead of materializing
 per-leaf pweights for the whole forest.
 
 Engine discipline is shared with :class:`~.predict.PredictEngine`:
-f64 under scoped ``enable_x64``, CPU device pinning, a locked LRU of
+f64 under scoped ``jax.enable_x64(True)`` on the host CPU
+(``predict.engine_device``), a locked LRU of
 compiled kernels keyed by static layout + bucket, power-of-two row
 buckets with full-padded-output fetch and host-side slicing (a
 device-side slice would compile one executable per request size and
@@ -696,10 +697,9 @@ class ShapEngine:
                         chunk_rows: Optional[int] = None) -> np.ndarray:
         """Per-row contributions, shape (k, num_features+1, rows) f64
         (last feature column is the bias/expected-value term)."""
-        import contextlib
         import jax
         import jax.numpy as jnp
-        from .predict import _xmat_compiled
+        from .predict import _xmat_compiled, engine_device
 
         n_trees = flat.n_trees if n_trees is None else n_trees
         n = X.shape[0]
@@ -712,14 +712,7 @@ class ShapEngine:
         Tc = self._tree_chunk_for(flat)
         max_chunk = self._max_chunk(flat, chunk_rows)
         outs = []
-        dev_ctx = contextlib.nullcontext()
-        if jax.default_backend() != "cpu":
-            try:
-                cpu = jax.local_devices(backend="cpu")[0]
-                dev_ctx = jax.default_device(cpu)
-            except Exception:
-                pass
-        with dev_ctx, jax.experimental.enable_x64():
+        with jax.default_device(engine_device()), jax.enable_x64(True):
             tabs = flat.device_tables(n_trees, Tc)
             xmat_fn = _xmat_compiled()
             for start, rows, B in self._buckets(n, max_chunk):

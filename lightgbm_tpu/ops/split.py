@@ -574,14 +574,18 @@ def find_best_split_c2f(coarse: jax.Array, win: jax.Array,
 #
 # Parity contract: numerical features only (the driver gates
 # categorical/EFB/c2f/forced to the XLA scan and records why —
-# models/gbdt.py tier gates); identical (feature, bin, default_left)
-# choice to :func:`find_best_split` with first-max tie order (lowest
-# bin within a feature, lowest feature globally), gains bit-equal in
-# the interpret-mode lane (the kernel evaluates the same jnp
-# expression tree) and within float tolerance across backends.  On a
-# CPU backend the kernels run under ``pl.pallas_call(...,
-# interpret=True)`` (utils/env.pallas_interpret) so tier-1 exercises
-# this path without a TPU.
+# models/gbdt.py tier gates); on identical inputs the same (feature,
+# bin, default_left) choice as :func:`find_best_split` with first-max
+# tie order (lowest bin within a feature, lowest feature globally),
+# and gains within 1e-4 relative (measured worst 5.9e-5).  Never
+# bit-equal: the kernel takes its prefix sums as a matmul
+# (:func:`_prefix_sum`), each compiler fuses the gain expression its
+# own way, and a prefix sum of mixed-sign gradients cancels, so its
+# drift is an ulp of the summands.  tests/test_split_kernel.py
+# holds the pin in the interpret lane (``pl.pallas_call(...,
+# interpret=True)`` on a CPU backend, utils/env.pallas_interpret);
+# tools/check_tpu_integration.py holds the choice (same trees as the
+# segsum + XLA twin) under Mosaic.
 
 _PART_LANES = 16  # partial-row width: [gain, f_loc, j, dir, Lg, Lh, Lc, pad]
 
@@ -589,19 +593,35 @@ _PART_LANES = 16  # partial-row width: [gain, f_loc, j, dir, Lg, Lh, Lc, pad]
 def _split_compiler_params():
     """Same scoped-VMEM raise as ops/histogram.py (the two modules
     cannot share it without an import cycle)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
-    except Exception:  # pragma: no cover - older pallas versions
-        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum over the last (bin) axis, as a contraction
+    with an upper-triangular ones matrix.  Mosaic has no lowering for
+    ``cumsum`` (jax 0.9.0: "Unimplemented primitive in Pallas TPU
+    lowering for KernelType.TC: cumsum"), and the MXU is idle in the
+    scan.  The weights are 0/1 and ``HIGHEST`` splits each f32 term
+    into exact bf16 pieces, so every product is exact; only the order
+    of the f32 additions differs from a sequential scan — last-ulp
+    class, covered by the parity contract above."""
+    B = x.shape[-1]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (B, B), 0) <=
+           jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
+           ).astype(x.dtype)
+    out = jax.lax.dot_general(
+        x.reshape(-1, B), tri, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=x.dtype)
+    return out.reshape(x.shape)
 
 
 def _scan_tile(g, h, c, nb, mt, fm, mono, pen, pg, ph, pc, gshift,
                mn, mx, p: SplitParams):
-    """Shared numerical scan over one feature tile — the exact jnp
-    expression tree of :func:`find_best_split`'s numeric section, so
-    the kernel and the XLA scan agree bit-for-bit wherever the
-    backend evaluates both identically (always, in interpret mode).
+    """Shared numerical scan over one feature tile — the expression
+    tree of :func:`find_best_split`'s numeric section except for the
+    prefix sums (see the parity contract above).
 
     g/h/c: (..., FC, B) per-channel histograms (dequantized);
     nb/mt: (..., FC, 1) int32; fm: (..., FC, 1) bool; mono: (..., FC,
@@ -610,9 +630,8 @@ def _scan_tile(g, h, c, nb, mt, fm, mono, pen, pg, ph, pc, gshift,
     unconstrained).  mono/pen/mn/mx None-ness must mirror the XLA
     call exactly: a neutral-VALUE operand (zeros / ones / ±inf) is
     value-identical but compiles a different expression tree, and the
-    extra clip/select ops fuse differently — gains then drift in the
-    last ulp vs :func:`find_best_split` (observed on the CPU
-    backend), which is exactly the bit-drift the static gating kills.
+    extra clip/select ops fuse differently — more drift against
+    :func:`find_best_split` for work the static gating saves.
     Returns (masked gain, dir_left, winner-side Lg/Lh/Lc), all
     (..., FC, B).
     """
@@ -632,9 +651,7 @@ def _scan_tile(g, h, c, nb, mt, fm, mono, pen, pg, ph, pc, gshift,
         mg = jnp.sum(g * moh, axis=-1, keepdims=True)
         mh = jnp.sum(h * moh, axis=-1, keepdims=True)
         mc = jnp.sum(c * moh, axis=-1, keepdims=True)
-    cum_g = jnp.cumsum(gv, axis=-1)
-    cum_h = jnp.cumsum(hv, axis=-1)
-    cum_c = jnp.cumsum(cv, axis=-1)
+    cum_g, cum_h, cum_c = (_prefix_sum(v) for v in (gv, hv, cv))
     cand_ok = jidx <= nv - 2
 
     def scan_dir(default_left: bool):
@@ -879,7 +896,7 @@ def _split_scan_kernel(g_ref, h_ref, c_ref, nb_ref, mt_ref, fm_ref,
     nb = nb_ref[...][None]                       # (1, FC, 1)
     mt = mt_ref[...][None]
     fm = fm_ref[...][None] > 0
-    lane = lane_ref[...]                         # (1, 8)
+    lane = lane_ref[0]                           # (1, 8)
     pg = lane[:, 0:1][..., None]                 # (1, 1, 1)
     ph = lane[:, 1:2][..., None]
     pc = lane[:, 2:3][..., None]
@@ -889,7 +906,7 @@ def _split_scan_kernel(g_ref, h_ref, c_ref, nb_ref, mt_ref, fm_ref,
     gain, dirl, Lg, Lh, Lc = _scan_tile(g, h, c, nb, mt, fm, mono, pen,
                                         pg, ph, pc, gs, mn, mx, params)
     row, best_pf = _tile_best(gain, dirl, Lg, Lh, Lc)
-    part_ref[...] = row[:, None, :]              # (1, 1, 16)
+    part_ref[...] = row[:, None, None, :]        # (1, 1, 1, 16)
     if with_pfg:
         pfg_ref[...] = best_pf                   # (1, FC, 1)
 
@@ -951,12 +968,16 @@ def find_best_split_pallas(hist: jax.Array, parent: jax.Array,
     if has_pen:
         in_specs.append(desc_spec)
         operands.append(pen)
-    in_specs.append(pl.BlockSpec((1, 8), lambda w, j: (w, 0)))
-    operands.append(lane)
+    # per-lane and per-tile rows ride with a unit second-minor dim:
+    # Mosaic wants a block's last two dims on the (8, 128) grid or
+    # equal to the array's, and a (1, 8) block of a (W, 8) array is
+    # neither once W > 1
+    in_specs.append(pl.BlockSpec((1, 1, 8), lambda w, j: (w, 0, 0)))
+    operands.append(lane[:, None, :])
 
-    out_specs = [pl.BlockSpec((1, 1, _PART_LANES),
-                              lambda w, j: (w, j, 0))]
-    out_shape = [jax.ShapeDtypeStruct((W, nt, _PART_LANES),
+    out_specs = [pl.BlockSpec((1, 1, 1, _PART_LANES),
+                              lambda w, j: (w, j, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((W, nt, 1, _PART_LANES),
                                       jnp.float32)]
     if with_per_feature_gain:
         out_specs.append(pl.BlockSpec((1, fc, 1),
@@ -976,7 +997,7 @@ def find_best_split_pallas(hist: jax.Array, parent: jax.Array,
         interpret=pallas_interpret(),
     )(*operands)
 
-    part = res[0] if with_per_feature_gain else res
+    part = (res[0] if with_per_feature_gain else res)[:, :, 0]
     rec = finish_split_partials(part, fc, num_bins, missing_type, p, B)
     if with_per_feature_gain:
         rec["per_feature_gain"] = res[1][:, :F, 0]

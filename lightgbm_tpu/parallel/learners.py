@@ -26,19 +26,6 @@ DATA_AXIS = "data"
 FEAT_AXIS = "feature"
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (>=0.5 exposes it at the
-    top level with ``check_vma``; earlier versions live in
-    ``jax.experimental`` with ``check_rep``)."""
-    import jax
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, check_vma=False,
-                             in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, check_rep=False, in_specs=in_specs,
-               out_specs=out_specs)
-
-
 def resolve_num_shards(config, mesh=None) -> int:
     """How many ways to shard: an explicit mesh wins; otherwise all
     GLOBAL devices, capped by ``num_machines`` when the user set it.
@@ -291,8 +278,8 @@ class DistributedBuilder:
                                        quant_key=qk)
             return build_tree(xt, grad, hess, mask, fmask, nb, mt, cat,
                               self.params, quant_key=qk)
-        sharded = shard_map_compat(
-            fn, self.mesh,
+        sharded = jax.shard_map(
+            fn, mesh=self.mesh, check_vma=False,
             in_specs=(xt_spec, row_spec, row_spec, row_spec, feat_spec,
                       feat_spec, feat_spec, feat_spec, R),
             out_specs=out_specs)

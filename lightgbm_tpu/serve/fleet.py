@@ -157,12 +157,18 @@ class ProcessReplica:
         cmd = [sys.executable, "-m", "lightgbm_tpu"] + \
             [f"{k}={v}" for k, v in args.items()]
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # a replica only scores, and the predict and SHAP engines
+        # compute on the host CPU (ops/predict.py engine_device): pin
+        # its platform so it never takes a chip it would not use — a
+        # chip belongs to one process.  ``self.env`` may override.
+        env["JAX_PLATFORMS"] = "cpu"
         # propagate the active trace (if a span is open — e.g. the
         # supervisor restarting a replica during a publish) so the
         # replica can mark its boot against it (obs/spans.py)
         env.update(_spans.env_carrier())
         env.update(self.env)
+        Log.info("fleet: replica %d starts with JAX_PLATFORMS=%s",
+                 self.slot, env["JAX_PLATFORMS"])
         log = open(self.log_path, "ab")
         try:
             self.proc = subprocess.Popen(cmd, stdout=log, stderr=log,

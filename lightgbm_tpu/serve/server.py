@@ -44,6 +44,8 @@ class Server:
                  params: Optional[Dict[str, Any]] = None,
                  config: Optional[ServeConfig] = None,
                  telemetry=None):
+        from ..utils.env import configure_compile_cache
+        configure_compile_cache()
         self.config = config or ServeConfig.from_params(params)
         self.config.validate()
         self.queue = AdmissionQueue(
@@ -198,12 +200,11 @@ class Server:
             return telemetry                     # caller-owned recorder
         if not self.config.telemetry_file:
             return None
-        info: Dict[str, Any] = {"task": "serve"}
-        try:
-            import jax
-            info["backend"] = jax.default_backend()
-        except Exception:
-            info["backend"] = "unknown"
+        import jax
+        from ..ops.predict import engine_device_info
+        info: Dict[str, Any] = {"task": "serve",
+                                "backend": jax.default_backend(),
+                                "engine_device": engine_device_info()}
         return _t.RunRecorder(self.config.telemetry_file, run_info=info)
 
     # -- lifecycle -------------------------------------------------------
@@ -604,7 +605,7 @@ class Server:
                             **fields)
 
     def stats(self) -> Dict[str, Any]:
-        from ..ops.predict import get_engine
+        from ..ops.predict import engine_device_info, get_engine
         from ..ops.shap import get_shap_engine
         with self._counts_lock:
             counts = dict(self._counts)
@@ -626,6 +627,7 @@ class Server:
                 "p99": round(self._lat_hist.percentile(0.99), 3),
             },
             "retry_after_ms": self.queue.retry_after_ms(),
+            "engine_device": engine_device_info(),
             "engine_cache": get_engine().cache_info(),
             "explain_cache": get_shap_engine().cache_info(),
             "versions": self.registry.history(),

@@ -91,6 +91,7 @@ def test_resume_parity_unfused_bagging(tmp_path):
                   "feature_fraction": 0.6}, fused=1)
 
 
+@pytest.mark.slow
 def test_resume_parity_fused_goss(tmp_path):
     _kill_resume(tmp_path, "binary", {"boosting": "goss"}, fused=4)
 

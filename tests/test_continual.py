@@ -1,7 +1,7 @@
 """Continual training daemon tests (``lightgbm_tpu/cont/``).
 
 Fast lane: validation gates, the batch source's backoff/quarantine
-taxonomy, the faults-registry typo warning, the numerical-health guard
+classes, the faults-registry typo warning, the numerical-health guard
 (one-shot engine.train AND the daemon's exact rewind), the stall
 watchdog, preemption drain + bit-exact resume, and the refit ->
 watcher republish hookup.

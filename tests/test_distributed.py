@@ -48,8 +48,6 @@ _WORKER = textwrap.dedent("""
     import os, sys
     sys.path.insert(0, {repo!r})
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from lightgbm_tpu.utils.env import strip_non_cpu_backends
-    strip_non_cpu_backends()
     from lightgbm_tpu.parallel.distributed import (init_from_machines,
                                                    process_info)
     machines = "127.0.0.1:{port},127.0.0.1:{port2}"

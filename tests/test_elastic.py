@@ -427,6 +427,7 @@ def test_cross_width_resume_data_bit_exact(data601, tmp_path):
     assert resumed.model_to_string() == _oracle_remesh_at(X, y, 3, 4)
 
 
+@pytest.mark.slow
 def test_cross_width_resume_feature_full_parity(data601, tmp_path):
     """Feature-parallel reduces no float histograms, so its cross-width
     resume is byte-identical to a FROM-SCRATCH run at any width —

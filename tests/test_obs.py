@@ -280,8 +280,14 @@ def test_concurrent_multi_subsystem_writers(tmp_path):
             tele.counters.incr("obs_conc")
 
     def scraper():
-        while not stop.is_set():
-            scrapes.append(reg.render())    # must never throw/tear
+        # must never throw/tear; the last scrape STARTS after the
+        # writers joined (one render of a large process-global registry
+        # can outlast them all, and then holds no observation yet)
+        while True:
+            last = stop.is_set()
+            scrapes.append(reg.render())
+            if last:
+                return
 
     threads = [threading.Thread(target=f, args=(i,))
                for i, f in enumerate([serve_writer, serve_writer,

@@ -239,6 +239,7 @@ def test_warmup_covers_explain_and_fastpath_buckets(
 # ----------------------------------------------------------------------
 # ACCEPTANCE: 504 concurrent distinct-size explains, zero compiles
 # ----------------------------------------------------------------------
+@pytest.mark.slow
 def test_steady_state_explain_504_distinct_sizes_zero_compiles(
         warm_explain_server, binary_pair):
     bst, X = binary_pair

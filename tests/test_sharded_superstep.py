@@ -67,6 +67,7 @@ def _assert_fused_sharded(bst, learner):
     assert g._trees_dispatched >= 1 and g._fused_block is not None
 
 
+@pytest.mark.slow
 def test_data_goss_fused_equals_unfused(data601):
     """Representative parity pin: the GOSS mask draw, the sharded
     histogram psum and the leaf-assignment all-gather all ride inside
@@ -131,6 +132,7 @@ def test_data_fused_matches_serial_structure(data601):
                                rtol=1e-4, atol=1e-6)
 
 
+@pytest.mark.slow
 def test_midblock_checkpoint_resume_sharded(data601, tmp_path):
     """A periodic snapshot landing MID fused block under a sharded
     learner (snapshot_freq=3, fused_iters=4: block [1-4] in flight at

@@ -153,6 +153,7 @@ def test_sharded_data_parallel_parity(data, tmp_path):
     assert m == m_oracle
 
 
+@pytest.mark.slow
 def test_sharded_data2d_streamed_parity(data, tmp_path):
     """Streamed ingest x the 2-D data x feature mesh: upload windows
     must land in the data2d ``P("feature", "data")`` tiles (NOT the

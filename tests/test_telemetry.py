@@ -25,8 +25,8 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.utils import telemetry
 from lightgbm_tpu.utils.telemetry import (
-    RunRecorder, SCHEMA_VERSION, counters_snapshot, latest_good_bench,
-    lint_file, parse_bench_artifact, read_records, validate_record)
+    RunRecorder, SCHEMA_VERSION, counters_snapshot, lint_file,
+    parse_bench_artifact, read_records, validate_record)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -345,18 +345,6 @@ class TestBenchArtifacts:
             {"n": 8, "cmd": "python bench.py", "rc": 1,
              "tail": '{"metric": "m", "value": 1.0}', "parsed": None}))
         assert parse_bench_artifact(str(p)) is None
-
-    def test_checked_in_r04_recovers(self):
-        rec = parse_bench_artifact(os.path.join(REPO, "BENCH_r04.json"))
-        assert rec is not None
-        assert rec["value"] == 412.45          # the VERDICT's drift fix
-        assert rec["vs_baseline"] == pytest.approx(1.7294)
-
-    def test_latest_good_skips_outage_rounds(self):
-        name, rec = latest_good_bench(REPO)
-        # r05 is the outage traceback; r04 is the last good round
-        assert name == "BENCH_r04.json"
-        assert rec["value"] == 412.45
 
 
 def test_render_benchmarks_byte_identical():

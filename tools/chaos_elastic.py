@@ -62,11 +62,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-from lightgbm_tpu.utils.env import (  # noqa: E402
-    force_host_platform_devices, strip_non_cpu_backends)
+from lightgbm_tpu.utils.env import force_host_platform_devices  # noqa: E402
 
 force_host_platform_devices(8)
-strip_non_cpu_backends()
 
 import numpy as np  # noqa: E402
 
@@ -150,10 +148,8 @@ def recovery_records(telemetry):
 _TRAIN_SCRIPT = r"""
 import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
-from lightgbm_tpu.utils.env import (force_host_platform_devices,
-                                    strip_non_cpu_backends)
+from lightgbm_tpu.utils.env import force_host_platform_devices
 force_host_platform_devices(int(os.environ["LTPU_ELASTIC_DEVICES"]))
-strip_non_cpu_backends()
 import numpy as np
 import lightgbm_tpu as lgb
 

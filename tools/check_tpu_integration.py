@@ -12,7 +12,10 @@ TPU backend) — and requires structurally identical models for:
 - wave + quantized with CATEGORICAL features (mask-chain routing).
 
 Run after touching ops/histogram.py or ops/grow.py (the CPU suite
-pins the segsum half; this closes the kernel half end to end).
+pins the segsum half; this closes the kernel half end to end).  It
+refuses to run anywhere but on a TPU with Pallas compiled
+(``chip_smoke.acquire_chip``): on a CPU backend both twins resolve to
+segsum and the comparison proves nothing.
 """
 import os
 import sys
@@ -21,9 +24,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax  # noqa: E402
+from chip_smoke import acquire_chip  # noqa: E402
 
-print("backend:", jax.default_backend(), flush=True)
+acquire_chip()
 
 import lightgbm_tpu as lgb  # noqa: E402
 
@@ -37,6 +40,8 @@ Xm[rng.random_sample(Xm.shape) < 0.1] = np.nan
 Xc = X.copy()
 for c in range(3):
     Xc[:, c] = np.floor(np.abs(Xc[:, c]) * 4) % 11
+# c2f engages from 7000 feature x bin units (models/gbdt.py): 28 x 256
+Xw = np.hstack([Xm, Xm, Xm[:, :4]])
 
 CASES = {
     "exact": (X, {}, {}),
@@ -45,7 +50,7 @@ CASES = {
     "wave_missing": (Xm, {"wave_splits": True, "use_quantized_grad": True,
                           "min_data_in_leaf": 1,
                           "hist_refinement": False}, {}),
-    "wave_c2f_missing": (Xm, {"wave_splits": True,
+    "wave_c2f_missing": (Xw, {"wave_splits": True,
                               "use_quantized_grad": True,
                               "min_data_in_leaf": 1, "max_bin": 255,
                               "hist_refinement": True}, {}),
@@ -55,8 +60,7 @@ CASES = {
                          {"categorical_feature": [0, 1, 2]}),
 }
 
-fail = 0
-for name, (Xd, extra, dkw) in CASES.items():
+def train_pair(Xd, extra, dkw):
     models = {}
     for dev in ("tpu", "cpu"):   # cpu => segsum ops on the same device
         p = {"objective": "binary", "num_leaves": 31, "verbose": -1,
@@ -64,9 +68,31 @@ for name, (Xd, extra, dkw) in CASES.items():
              "device_type": dev}
         p.update(extra)
         ds = lgb.Dataset(Xd, label=y, params=p, **dkw)
-        bst = lgb.train(p, ds, num_boost_round=5, verbose_eval=False)
-        models[dev] = bst
-    ok = True
+        models[dev] = lgb.train(p, ds, num_boost_round=5,
+                                verbose_eval=False)
+    return models
+
+
+fail = 0
+for name, (Xd, extra, dkw) in CASES.items():
+    try:
+        models = train_pair(Xd, extra, dkw)
+    except Exception as exc:  # noqa: BLE001 - reported, run fails
+        # a kernel Mosaic refuses fails its case and the run goes on,
+        # so one call on the chip names every case that does not lower
+        print(f"{name}: RAISED {type(exc).__name__}: "
+              f"{str(exc).strip()[-1500:]}", flush=True)
+        fail += 1
+        continue
+    tier = models["tpu"]._gbdt.tier_decision
+    impls = {d: m._gbdt.tier_decision["hist_impl"]
+             for d, m in models.items()}
+    print(f"{name}: tier", tier["tier"], "hist_impl", impls,
+          "split_kernel", tier["split_kernel"], "c2f", tier["c2f"],
+          flush=True)
+    # the case runs what it names, kernels against their segsum twins
+    ok = tier["c2f"] == ("c2f" in name) and \
+        impls == {"tpu": "pallas", "cpu": "segsum"}
     for tp, tc in zip(models["tpu"]._gbdt.models,
                       models["cpu"]._gbdt.models):
         n = tp.num_leaves - 1

@@ -360,10 +360,6 @@ def scan_anomalies(records):
                                f"train wall time ({save_ms:.0f} of "
                                f"{train_ms:.0f} ms) — raise "
                                f"snapshot_freq or shrink keep_last_n"))
-    for r in records:
-        if r.get("type") == "run_start" and r.get("backend_degraded"):
-            out.append(("HIGH", "backend identity unavailable at "
-                                "run_start (degraded environment)"))
     return out
 
 
