@@ -1,0 +1,330 @@
+"""The benchmark's own tests, at a size a CPU test run can hold.
+
+They cover the plain reference against the trainer on both growth
+tiers, the control (the reference in the precision below the cell's)
+and the planted faults coming out as not correct, the work count, the
+trace reduction on a small recorded trace, the loader finding files a
+later PR would add, and run.py's refusals.  Nothing here touches a JAX
+backend while it is imported."""
+from __future__ import annotations
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, BENCH)
+
+import run as bench_run                                    # noqa: E402
+from harness import (cells, datagen, readers, reference,   # noqa: E402
+                     trainer as trainer_mod, work, xplane)
+
+SEED = 2 ** 31 + 77          # the driver's seeds are large
+TINY = ("tiny.fused", "tiny.plain")
+
+
+@pytest.fixture(scope="module")
+def bench_root(tmp_path_factory):
+    """A copy of benchmark/ with the test cells added as new files, the
+    way a later PR adds a cell, a configuration and a traffic mix."""
+    root = str(tmp_path_factory.mktemp("bench"))
+    shutil.copytree(BENCH, root, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns(".build", "__pycache__"))
+    shutil.copytree(os.path.join(HERE, "files"), root, dirs_exist_ok=True)
+    return root
+
+
+@pytest.fixture
+def on_cpu(monkeypatch):
+    """run_cell without the look for a chip, and without moving this
+    test process's compile cache."""
+    monkeypatch.setattr(trainer_mod, "configure_jax", lambda log: "")
+
+
+def run_tiny(bench_root, name, seed=SEED):
+    return bench_run.run_cell(cells.load_cell(name, bench_root), seed,
+                              0.3, False)
+
+
+# ---------------------------------------------------------------- cells
+@pytest.mark.parametrize("name", TINY)
+def test_trainer_agrees_with_reference(bench_root, on_cpu, name):
+    res = run_tiny(bench_root, name)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert list(res)[-1] == "checks"
+    assert set(res["metrics"]) == {"setup_s", "train_s_per_iter"}
+    for value, limit in res["checks"].values():
+        assert value <= limit
+
+
+@pytest.mark.parametrize("name", TINY)
+def test_control_is_not_correct(bench_root, name):
+    """The reference in the precision below the cell's, put in the
+    trainer's place, fails one of the cell's limits."""
+    cell = cells.load_cell(name, bench_root)
+    x, y = datagen.make_data(cell.config["rows"], cell.config["features"],
+                             cell.config["data"], SEED)
+    c = cell.workload["control"]
+    exact = reference.train_in_place(x, y, cell.params, 3, SEED)
+    cut = reference.train_in_place(x, y, cell.params, 3, SEED,
+                                   c["hist"], c["leaf"])
+    limits = cell.workload["limits"]
+    n_exact = reference.compare(exact, x, y, cell.params, SEED, 3)
+    n_cut = reference.compare(cut, x, y, cell.params, SEED, 3)
+    assert all(n_exact[k] <= v for k, v in limits.items()), n_exact
+    assert any(n_cut[k] > v for k, v in limits.items()), n_cut
+
+
+def _state_unchanged(monkeypatch):
+    monkeypatch.setattr(trainer_mod.Trainer, "step", lambda self: None)
+
+
+def _half_batch(monkeypatch):
+    init = trainer_mod.Trainer.__init__
+
+    def half(self, params, x, y, telemetry_file=None):
+        n = len(y) // 2
+        init(self, params, np.ascontiguousarray(x[:n]), y[:n],
+             telemetry_file)
+    monkeypatch.setattr(trainer_mod.Trainer, "__init__", half)
+
+
+def _altered_answer(monkeypatch):
+    trainer_mod.use_program()
+    from lightgbm_tpu.models.gbdt import GBDT
+    made = GBDT._records_to_tree
+
+    def altered(self, rec):
+        tree = made(self, rec)
+        j = int(np.argmax(np.abs(tree.leaf_value[:tree.num_leaves])))
+        tree.leaf_value[j] = -tree.leaf_value[j]
+        return tree
+    monkeypatch.setattr(GBDT, "_records_to_tree", altered)
+
+
+@pytest.mark.parametrize("plant", [_state_unchanged, _half_batch,
+                                   _altered_answer],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_fault_is_not_correct(bench_root, on_cpu, monkeypatch, plant):
+    """The rest of a run with the timed path broken underneath."""
+    plant(monkeypatch)
+    res = run_tiny(bench_root, "tiny.fused")
+    assert res["correct"] is False, res["checks"]
+
+
+@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
+                                   "altered_answer"])
+def test_reference_fault_is_not_correct(bench_root, fault):
+    cell = cells.load_cell("tiny.plain", bench_root)
+    x, y = datagen.make_data(cell.config["rows"], cell.config["features"],
+                             cell.config["data"], SEED)
+    bad = reference.train_in_place(x, y, cell.params, 3, SEED, fault=fault)
+    nums = reference.compare(bad, x, y, cell.params, SEED, 3)
+    assert any(not nums[k] <= v
+               for k, v in cell.workload["limits"].items()), nums
+
+
+# ------------------------------------------------------------ reference
+def test_same_seed_same_data():
+    spec = {"generator": "higgs_shaped", "integer_columns": 2}
+    a = datagen.make_data(5000, 7, spec, SEED)
+    b = datagen.make_data(5000, 7, spec, SEED)
+    c = datagen.make_data(5000, 7, spec, SEED + 1)
+    assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
+    assert not np.array_equal(a[0], c[0])
+    assert a[0].dtype == np.float32 and set(np.unique(a[1])) == {0.0, 1.0}
+
+
+def test_unknown_generator_is_an_error():
+    with pytest.raises(ValueError):
+        datagen.make_data(10, 3, {"generator": "other"}, 1)
+
+
+def test_bins_route_like_thresholds():
+    """A row's bin and the raw threshold of a split agree: the tree the
+    reference grows on bins routes raw rows to the same leaves."""
+    x, y = datagen.make_data(4000, 5, {"generator": "higgs_shaped",
+                                       "integer_columns": 1}, 3)
+    params = {"num_leaves": 9, "learning_rate": 0.1, "min_data_in_leaf": 1,
+              "min_sum_hessian_in_leaf": 1.0, "max_bin": 31}
+    p = reference.GrowParams.from_config(params)
+    uppers, nbins = reference.make_bins(x, p.max_bin, 3)
+    bins = reference.native.bin_rows(x, uppers, nbins)
+    assert bins.max() < 31 and nbins.max() <= 31
+    g, h = reference.gradients(np.zeros(len(y)), y)
+    tree, leaf_of = reference.grow_tree(bins, uppers, nbins, g, h, g, h, p)
+    assert tree.num_leaves == 9
+    assert np.array_equal(tree.route(x), leaf_of)
+    assert np.array_equal(np.bincount(leaf_of, minlength=9),
+                          tree.leaf_count)
+
+
+def _brute_rows_touched(tree, x):
+    """Count by routing: rows at the root, then at every split the
+    smaller side."""
+    def rows_at(node, idx):
+        if node < 0:
+            return 0
+        go = x[idx, tree.feature[node]] <= tree.threshold[node]
+        li, ri = idx[go], idx[~go]
+        return (min(len(li), len(ri)) + rows_at(tree.left[node], li)
+                + rows_at(tree.right[node], ri))
+    idx = np.arange(len(x))
+    return len(x) + rows_at(0, idx)
+
+
+def test_rows_touched_against_brute_force():
+    x, y = datagen.make_data(3000, 4, {"generator": "higgs_shaped"}, 11)
+    params = {"num_leaves": 12, "learning_rate": 0.1, "min_data_in_leaf": 1,
+              "min_sum_hessian_in_leaf": 1.0, "max_bin": 63}
+    made = reference.train_in_place(x, y, params, 1, 11)
+    tree = made.trees[0]
+    assert work.rows_touched(tree) == _brute_rows_touched(tree, x)
+    assert work.hist_bytes([tree], 4, 3000) == 4 * work.rows_touched(tree)
+    assert work.iteration_bytes([tree], 4, 3000) == \
+        12 * work.rows_touched(tree) + 12 * 3000
+
+
+def test_unknown_device_kind_is_an_error():
+    assert work.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(SystemExit):
+        work.peaks_for("a chip nobody has")
+
+
+# ---------------------------------------------------------------- trace
+@pytest.fixture(scope="module")
+def small_trace():
+    with open(os.path.join(HERE, "trace_small.json")) as f:
+        raw = json.load(f)
+    return {"devices": {k: [tuple(e) for e in v]
+                        for k, v in raw["devices"].items()},
+            "host": [tuple(e) for e in raw["host"]]}
+
+
+def test_trace_busy_union(small_trace):
+    b = xplane.busy(small_trace)
+    lo, hi = xplane.window_of(small_trace)
+    evs = xplane.clip(next(iter(small_trace["devices"].values())), lo, hi)
+    assert 0 < b["busy_s"] <= b["window_s"] == pytest.approx(hi - lo)
+    # the union never exceeds the sum, and a grid over the window agrees
+    assert b["busy_s"] <= sum(e - s for _, s, e in evs) + 1e-12
+    grid = np.linspace(lo, hi, 20001)
+    mid = (grid[:-1] + grid[1:]) / 2
+    on = np.zeros(len(mid), bool)
+    for _, s, e in evs:
+        on |= (mid >= s) & (mid < e)
+    assert b["busy_s"] == pytest.approx(on.mean() * (hi - lo), rel=5e-3)
+
+
+def test_trace_name_patterns(small_trace):
+    with open(os.path.join(BENCH, "metrics",
+                           "hist_kernel_s_per_iter.json")) as f:
+        spec = json.load(f)["read"]
+    lo, hi = xplane.window_of(small_trace)
+    evs = xplane.clip(next(iter(small_trace["devices"].values())), lo, hi)
+    got = xplane.reduce_events(evs, spec["patterns"], "sum")
+    by_hand = sum(e - s for n, s, e in evs
+                  if n.startswith(("histogram_pallas", "leaf_stats_pallas")))
+    assert got == pytest.approx(by_hand) and got > 0
+    assert xplane.reduce_events(evs, ["no such kernel"], "sum") is None
+    assert xplane.reduce_events(evs, spec["patterns"], "union") <= got + 1e-12
+
+
+def test_trace_breakdown(small_trace):
+    ops = xplane.top_ops(small_trace)
+    gaps = xplane.idle_gaps(small_trace)
+    assert 0 < len(ops) <= 10 and len(gaps) <= 10
+    assert ops == sorted(ops, key=lambda o: -o[1])
+    assert all(g[1] > 0 for g in gaps)
+
+
+def test_reader_without_anything_to_read_returns_nothing():
+    ctx = {"spans": {}, "quantities": {}, "counters":
+           {"setup": ({}, {}), "window": ({}, {})}, "trace": None,
+           "traced_trees": [], "features": 4, "rows": 10, "peaks": {}}
+    metrics = [json.load(open(os.path.join(BENCH, "metrics", f)))
+               for f in sorted(os.listdir(os.path.join(BENCH, "metrics")))]
+    got = readers.read_all(metrics, ctx)
+    assert set(got) <= {"compiles_in_window"}     # a count may be 0
+    assert not any("roofline" in k or "mfu" in k for k in got)
+
+
+# --------------------------------------------------------------- loader
+def test_loader_finds_files_added_later(bench_root):
+    cell = cells.load_cell("tiny.fused", bench_root)
+    assert cell.config["name"] == "tiny" and cell.block == 4
+    assert cell.params["wave_splits"] and cell.params["num_leaves"] == 15
+    before = {m["name"] for m in cell.metrics}
+    new = {"name": "warmup_s", "layer": "Entry", "unit": "s",
+           "better": "lower", "source": "host_clock", "moves": "setup_s",
+           "read": {"kind": "span", "span": "warmup_s"},
+           "workloads": ["tiny.fused"]}
+    with open(os.path.join(bench_root, "metrics", "warmup_s.json"), "w") as f:
+        json.dump(new, f)
+    after = {m["name"] for m in
+             cells.load_cell("tiny.fused", bench_root).metrics}
+    other = {m["name"] for m in
+             cells.load_cell("tiny.plain", bench_root).metrics}
+    assert after == before | {"warmup_s"} and "warmup_s" not in other
+    with pytest.raises(SystemExit):
+        cells.load_cell("no.such.cell", bench_root)
+
+
+def _manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_manifest_names_files_that_exist():
+    m = _manifest()
+    assert m["command"] == ["python3", "benchmark/run.py"]
+    for c in m["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["source"] == c["source"]
+        assert cfg["reduced"] == c["reduced"]
+    for w in m["workloads"]:
+        cell = cells.load_cell(w["name"])
+        assert cell.workload["config"] == w["config"]
+        assert cell.workload["traffic"] == w["traffic"]
+        assert cell.workload["chips"] == w["chips"] == 1
+        assert cell.workload["why"] == w["why"]
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in
+                                    _manifest()["per_layer"]])
+def test_manifest_metric_has_its_reader(metric):
+    entry = next(m for m in _manifest()["per_layer"] if m["name"] == metric)
+    with open(os.path.join(BENCH, "metrics", f"{metric}.json")) as f:
+        spec = json.load(f)
+    for key in ("layer", "unit", "better", "source", "moves"):
+        assert spec[key] == entry[key]
+    assert spec.get("workloads") == entry.get("workloads")
+    assert spec["read"]["kind"] in readers.KINDS
+
+
+# ------------------------------------------------------------- refusals
+def test_run_refuses_off_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(BENCH, "run.py"),
+                        "--workload", "higgs28.fast", "--seed", "1",
+                        "--seconds", "1", "--trace", "0"],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert p.returncode != 0
+    assert "needs a TPU" in p.stderr and p.stdout.strip() == ""
+
+
+def test_run_refuses_another_tier(bench_root):
+    cell = cells.load_cell("tiny.fused", bench_root)
+    good = dict(cell.workload["expect_tier"])
+    bench_run.check_tier(cell, good)
+    with pytest.raises(SystemExit):
+        bench_run.check_tier(cell, dict(good, tier="exact"))
