@@ -252,8 +252,7 @@ def run_variant(lgb, params, train, n_meas, auc_fn, profiling=None,
     for _ in range(WARMUP):
         booster.update()
     warmup_s = time.time() - t0
-    if profiling is not None:
-        profiling.reset()
+    ph0 = profiling.snapshot() if profiling is not None else {}
     c0 = _telemetry.counters_snapshot()
     times = []
     arm = []
@@ -302,14 +301,16 @@ def run_variant(lgb, params, train, n_meas, auc_fn, profiling=None,
         out["hist_passes_per_tree"] = round(
             sorted(arm)[len(arm) // 2] + 1, 1)  # + root pass
     if profiling is not None:
-        tot, _ = profiling.get("tree/build")
+        ph1 = profiling.snapshot()
         phases = {}
         for name in ("boosting/gradients", "tree/prep", "tree/dispatch",
                      "tree/fetch", "tree/to_tree", "tree/renew",
                      "tree/score_update", "tree/valid"):
-            t, c = profiling.get(name)
-            if c:
-                phases[name.split("/")[-1]] = round(t / c * 1e3, 1)
+            t0_, c0_ = ph0.get(name, (0.0, 0))
+            t, c = ph1.get(name, (0.0, 0))
+            if c - c0_:
+                phases[name.split("/")[-1]] = round(
+                    (t - t0_) / (c - c0_) * 1e3, 1)
         if phases:
             out["phase_ms_per_iter"] = phases
     if diagnose_fetch:

@@ -29,6 +29,7 @@ from .models import model_io
 from .models.tree import Tree
 from .objectives import create_objective
 from .utils.log import Log
+from .utils.profiling import timed
 
 __all__ = ["Dataset", "Booster"]
 
@@ -235,10 +236,11 @@ class Dataset:
             if self.reference is not None:
                 self.reference.construct()
                 mappers = self.reference._constructed.mappers
-            self._constructed = TpuDataset.from_sparse(
-                self.data, label, cfg, weight=weight, group=group,
-                init_score=self.init_score, feature_names=names,
-                categorical_features=cat_idx, mappers=mappers)
+            with timed("dataset/bin"):
+                self._constructed = TpuDataset.from_sparse(
+                    self.data, label, cfg, weight=weight, group=group,
+                    init_score=self.init_score, feature_names=names,
+                    categorical_features=cat_idx, mappers=mappers)
             # raw stays SPARSE; dense consumers densify on demand
             self.raw_mat = None if self.free_raw_data else self.data
             return self
@@ -260,11 +262,12 @@ class Dataset:
         if self.reference is not None:
             self.reference.construct()
             mappers = self.reference._constructed.mappers
-        self._constructed = TpuDataset.from_raw(
-            mat, label, cfg, weight=weight, group=group,
-            init_score=self.init_score,
-            feature_names=self.feature_name if self.feature_name else None,
-            categorical_features=cat_idx, mappers=mappers)
+        with timed("dataset/bin"):
+            self._constructed = TpuDataset.from_raw(
+                mat, label, cfg, weight=weight, group=group,
+                init_score=self.init_score,
+                feature_names=self.feature_name or None,
+                categorical_features=cat_idx, mappers=mappers)
         self.raw_mat = None if self.free_raw_data else mat
         return self
 

@@ -455,4 +455,6 @@ def create_boosting(config: Config, train_set: TpuDataset,
     cls = _BOOSTING_TYPES.get(config.boosting)
     if cls is None:
         Log.fatal("unknown boosting type %s", config.boosting)
-    return cls(config, train_set, objective, metrics, mesh=mesh)
+    from ..utils.profiling import timed
+    with timed("boost/init"):
+        return cls(config, train_set, objective, metrics, mesh=mesh)

@@ -668,19 +668,24 @@ class GBDT:
                           if self._dist is not None else None))
             self._stream_upload = fetcher.stats()
         else:
-            if self._bundles is not None:
-                xt = self._bundles.bundle_matrix(
-                    train_set.binned).T  # (G, N)
-            else:
-                xt = train_set.binned.T  # (F, N) narrow uint8/16
-            col_pad = 0 if self._bundles is not None \
-                else self._F_pad - F
-            xt = np.pad(xt, ((0, col_pad), (0, self._n_pad - n)))
-            # NARROW dtype end to end: the host->device copy AND device
-            # residency (uint8 = 295 MB at bench shape vs 1.18 GB
-            # int32); the pallas kernels and routing selects widen per
-            # tile
-            self._xt = jnp.asarray(xt)
+            # the host's part of the upload: transpose, pad and the
+            # enqueue of the copy (which lands later, under whichever
+            # phase first waits for the device)
+            from ..utils.profiling import timed
+            with timed("dataset/xt_host_prep"):
+                if self._bundles is not None:
+                    xt = self._bundles.bundle_matrix(
+                        train_set.binned).T  # (G, N)
+                else:
+                    xt = train_set.binned.T  # (F, N) narrow uint8/16
+                col_pad = 0 if self._bundles is not None \
+                    else self._F_pad - F
+                xt = np.pad(xt, ((0, col_pad), (0, self._n_pad - n)))
+                # NARROW dtype end to end: the host->device copy AND
+                # device residency (uint8 = 295 MB at bench shape vs
+                # 1.18 GB int32); the pallas kernels and routing
+                # selects widen per tile
+                self._xt = jnp.asarray(xt)
         self._base_mask = jnp.asarray(
             np.pad(np.ones(n, np.float32), (0, self._n_pad - n)))
         if self._F_pad != F:
@@ -1549,7 +1554,7 @@ class GBDT:
                  "tid": self._trees_dispatched}
         if self.__dict__.get("_dispatch_fence") is None:
             self._dispatch_fence = fence
-        with timed("superstep/dispatch"):
+        with timed("superstep/dispatch", iter=i0, k=K):
             # host feature-fraction draws consumed in sequential order
             fmasks = jnp.stack([self._feature_fraction_mask()
                                 for _ in range(K)])
@@ -1685,7 +1690,7 @@ class GBDT:
         rng_state = entry["fence"]["rng_state"]
         start_tid = int(entry["fence"]["tid"])
         t_fetch0 = _time.perf_counter()
-        with timed("superstep/fetch"):
+        with timed("superstep/fetch", iter=i0, k=K):
             # the block's ONE device->host transfer (packed f32)
             _telemetry.counters.incr("superstep_fetches")
             host = self._fetch_records(entry["outs"][4])
@@ -1724,7 +1729,7 @@ class GBDT:
                                 i0 + j, "superstep",
                                 f"fused block of {K} starting at "
                                 f"iteration {i0}")
-        with timed("superstep/to_tree"):
+        with timed("superstep/to_tree", iter=i0, k=K):
             n_leaves_k = host["n_leaves"]
             trees, stop_idx = [], None
             for t in range(K):
@@ -1739,12 +1744,7 @@ class GBDT:
                 tree = self._records_to_tree(rec_t)
                 tree.apply_shrinkage(entry["lr"])
                 trees.append(tree)
-        if "n_arm_passes" in host:
-            passes = host["n_arm_passes"][:len(trees)]
-            self.last_arm_passes = int(passes[-1])
-            hist_passes = int(np.sum(passes)) + len(trees)
-        else:
-            hist_passes = None
+        hist_passes = self._count_growth(host, len(trees))
         self._fused_block = {
             "start_score": start_score, "start_iter": i0,
             "start_tid": start_tid, "rng_state": rng_state,
@@ -2132,7 +2132,7 @@ class GBDT:
         from ..utils.profiling import timed
 
         n, n_pad = self.num_data, self._n_pad
-        with timed("tree/prep"):
+        with timed("tree/prep", iter=self.iter):
             gp = jnp.pad(grad_k.astype(jnp.float32), (0, n_pad - n))
             hp = jnp.pad(hess_k.astype(jnp.float32), (0, n_pad - n))
             mask = self._base_mask
@@ -2152,7 +2152,7 @@ class GBDT:
             kw["quant_key"] = jax.random.fold_in(
                 self._quant_key, self._trees_dispatched)
         self._trees_dispatched += 1
-        with timed("tree/dispatch"):
+        with timed("tree/dispatch", iter=self.iter):
             if self._bundle_maps is not None:
                 rec = self._build_tree(
                     self._xt, gp, hp, mask, fmask, self._num_bins,
@@ -2183,11 +2183,10 @@ class GBDT:
         if os.environ.get("LTPU_SPLIT_FETCH_TIMER"):
             import jax
             from ..utils.profiling import timed
-            with timed("tree/device_wait"):
+            with timed("tree/device_wait", iter=self.iter):
                 jax.block_until_ready(rec["n_leaves"])
         recs = self._fetch_records(rec)
-        if "n_arm_passes" in recs:
-            self.last_arm_passes = int(recs["n_arm_passes"])
+        self._count_growth(recs)
         n_leaves = int(recs["n_leaves"])
         if n_leaves <= 1:
             # non-finite gradients produce NaN gains everywhere and
@@ -2287,7 +2286,7 @@ class GBDT:
                 init_score = init
                 self._score = self._score.at[0].add(init)
                 Log.info("Start training from score %f", init)
-        with timed("boosting/gradients"):
+        with timed("boosting/gradients", iter=self.iter):
             # the jitted wrapper, not the eager chain: one fused pass,
             # and the same compiled math the fused super-step inlines
             # (bit-parity between the two paths requires it).  An
@@ -2301,14 +2300,14 @@ class GBDT:
         bag = self._bagging_mask(grad, hess)
         n = self.num_data
         rec, _ = self._dispatch_build(grad[0], hess[0], bag)
-        with timed("tree/score_update"):
+        with timed("tree/score_update", iter=self.iter):
             vals = rec["leaf_values_final"] * \
                 jnp.float32(self.shrinkage_rate)
             self._score = self._score.at[0].add(
                 take_small(vals, rec["leaf_idx"])[:n])
         prev_stop = False
         if self._pending is not None:
-            with timed("tree/fetch"):
+            with timed("tree/fetch", iter=self.iter):
                 prev_stop = self._materialize_pending()
         self._pending = {"rec": rec, "init_score": init_score,
                          "lr": self.shrinkage_rate,
@@ -2530,7 +2529,7 @@ class GBDT:
                             vs.score[k] += init
                         Log.info("Start training from score %f", init)
             from ..utils.profiling import timed
-            with timed("boosting/gradients"):
+            with timed("boosting/gradients", iter=self.iter):
                 grad_fn = self.objective.gradient_fn() or \
                     self.objective.get_gradients
                 grad, hess = grad_fn(self._score)
@@ -2544,7 +2543,7 @@ class GBDT:
         bag = self._bagging_mask(grad, hess)
         should_stop = True
         for k in range(self.num_tree_per_iteration):
-            with timed("tree/build"):
+            with timed("tree/build", iter=self.iter):
                 tree = self._train_one_tree(grad[k], hess[k], bag,
                                             init_scores[k])
             if tree.num_leaves > 1:
@@ -2570,13 +2569,12 @@ class GBDT:
             mask = self._base_mask
         else:
             rec, mask = self._dispatch_build(grad, hess, bag)
-            with timed("tree/fetch"):
+            with timed("tree/fetch", iter=self.iter):
                 # one packed device->host transfer per tree; doubles as
                 # the device sync
                 recs = self._fetch_records(rec)
             n_leaves = int(recs["n_leaves"])
-            if "n_arm_passes" in recs:
-                self.last_arm_passes = int(recs["n_arm_passes"])
+            self._count_growth(recs)
 
         if n_leaves <= 1:
             # constant tree holding the init score (gbdt.cpp:380-397)
@@ -2594,7 +2592,7 @@ class GBDT:
                     vs.leaf_idx_per_tree.append(None)
             return tree
 
-        with timed("tree/to_tree"):
+        with timed("tree/to_tree", iter=self.iter):
             tree = self._records_to_tree(recs)
         self._check_tree_health(tree, self.iter, "tree")
         if self._track_train_leaf:
@@ -2605,11 +2603,11 @@ class GBDT:
                 np.asarray(rec["leaf_idx"][:n].astype(dt)))
         # leaf renewal hook (RenewTreeOutput) — objective-specific
         if self.objective is not None:
-            with timed("tree/renew"):
+            with timed("tree/renew", iter=self.iter):
                 self.objective.renew_tree_output(
                     tree, self._score, rec["leaf_idx"][:n], mask)
         tree.apply_shrinkage(self.shrinkage_rate)
-        with timed("tree/score_update"):
+        with timed("tree/score_update", iter=self.iter):
             # train-score update via the leaf assignment from the build;
             # the (N,) table lookup runs as the select-chain kernel (an
             # XLA gather here costs ~150 ms per iteration at bench
@@ -2626,7 +2624,7 @@ class GBDT:
         # matrix is resident, host traversal fallback otherwise
         from ..ops.grow import route_rows
         dt_leaf = np.uint8 if self.config.num_leaves <= 256 else np.uint16
-        with timed("tree/valid"):
+        with timed("tree/valid", iter=self.iter):
             for vs in self.valid_sets:
                 if vs.xt is not None:
                     li = route_rows(vs.xt, rec["leaf"], rec["feature"],
@@ -2658,6 +2656,37 @@ class GBDT:
         return tree
 
     # ------------------------------------------------------------------
+    def _count_growth(self, recs, n_trees: int = 1):
+        """Add the growth loop's own counts (``ops/grow.py``
+        ``GROW_COUNTERS``) of the ``n_trees`` trees just fetched to the
+        process counters: ``trees_grown``, ``hist_passes_coarse`` (one
+        routing pass a wave), ``hist_passes_refine`` (the rest: c2f's
+        windowed passes), ``grow_waves`` and, on the wave tiers, where
+        every lane a wave fills is one split, ``grow_lanes_live`` (the
+        trees' splits) and ``grow_lanes_offered`` (waves x the wave's
+        lanes).  Returns the trees' histogram passes, each tree's root
+        pass included (a record's ``hist_passes``), or None on a tier
+        whose loop does not count (no batched passes)."""
+        if "n_arm_passes" not in recs:
+            return None
+        from ..utils.telemetry import counters
+        arm = np.atleast_1d(recs["n_arm_passes"])[:n_trees]
+        waves = int(np.sum(np.atleast_1d(recs["n_waves"])[:n_trees]))
+        self.last_arm_passes = int(arm[-1])
+        arm = int(np.sum(arm))
+        counters.incr("trees_grown", n_trees)
+        counters.incr("hist_passes_coarse", waves)
+        counters.incr("hist_passes_refine", arm - waves)
+        counters.incr("grow_waves", waves)
+        p = self.grow_params
+        if p.wave:
+            leaves = np.atleast_1d(recs["n_leaves"])[:n_trees]
+            counters.incr("grow_lanes_live",
+                          int(np.sum(np.maximum(leaves, 1) - 1)))
+            counters.incr("grow_lanes_offered",
+                          waves * min(p.speculate, p.num_leaves))
+        return arm + n_trees
+
     def _fetch_records(self, rec):
         """ONE device->host transfer per tree: every split record except
         the (N,) leaf assignment (which stays on device for the score

@@ -66,7 +66,19 @@ from .split import (NEG_INF, SplitParams, choose_window,
                     leaf_output, split_lane_scalars)
 
 __all__ = ["DistConfig", "GrowParams", "build_tree", "build_tree_impl",
-           "collective_bytes_per_pass"]
+           "collective_bytes_per_pass", "GROW_COUNTERS"]
+
+# What the growth loop counts of its own work, as int32 scalars in the
+# loop state (wave and speculative tiers); they return with the tree's
+# records and become process counters at the tree's or block's commit
+# (models/gbdt.py ``_count_growth``).  ``n_arm_passes`` is every
+# batched histogram pass after the root's, ``n_waves`` the waves (the
+# arming passes on the speculative tier).  A wave's first pass routes
+# the rows (coarse bins under c2f); the windowed refine passes of c2f
+# are ``n_arm_passes - n_waves``, and on the wave tiers the lanes of
+# ``W_spec`` that the waves filled are the tree's splits, so the host
+# derives both.
+GROW_COUNTERS = ("n_arm_passes", "n_waves")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1002,7 +1014,8 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         state["armed_hist"] = jnp.zeros((L + 1, F_hist, B, 3),
                                         jnp.float32)
     if do_spec:
-        state["n_arm_passes"] = jnp.int32(0)
+        for k in GROW_COUNTERS:
+            state[k] = jnp.int32(0)
     if has_mono:
         # per-leaf inherited output bounds (LeafSplits min/max
         # constraint propagation, leaf_splits.hpp:16)
@@ -1063,6 +1076,7 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         st["armed"] = st["armed"].at[ids_safe].set(valid_w) \
                                  .at[L].set(False)
         st["n_arm_passes"] = st["n_arm_passes"] + 1
+        st["n_waves"] = st["n_waves"] + 1
         return st
 
     def body(t, st):
@@ -1348,6 +1362,7 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         st["n_leaves"] = st["n_leaves"] + \
             jnp.sum(valid_w.astype(jnp.int32))
         st["n_arm_passes"] = st["n_arm_passes"] + 1
+        st["n_waves"] = st["n_waves"] + 1
         return st
 
     def child_best(h, s, mn, mx):
@@ -1574,12 +1589,6 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         # DIFFERENT recomputations — leaf stats then disagree with the
         # recorded mask.  The barrier pins `bests` to single values.
         bests = jax.lax.optimization_barrier(bests)
-        import os as _os
-        if _os.environ.get("LTPU_DEBUG_GROW"):
-            st = dict(st)
-            st["dbg_bests_left_stats"] = bests["left_stats"]
-            st["dbg_bests_dl"] = bests["default_left"]
-
         st = dict(st)
         st["leaf_idx"] = leaf_idx
         st["hist"] = st["hist"].at[ids_leaf].set(hist_l, mode="drop") \
@@ -1710,12 +1719,6 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         bests["gain"] = jnp.where(allowed, bests["gain"], NEG_INF)
         # same materialization fence as wave_body
         bests = jax.lax.optimization_barrier(bests)
-        import os as _os
-        if _os.environ.get("LTPU_DEBUG_GROW"):
-            st = dict(st)
-            st["dbg_bests_left_stats"] = bests["left_stats"]
-            st["dbg_bests_dl"] = bests["default_left"]
-
         st = dict(st)
         st["leaf_idx"] = leaf_idx
         st["hist_c"] = st["hist_c"].at[ch_ids].set(ch_hist_c,
@@ -1736,12 +1739,6 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         return st
 
     if use_wave:
-        import os as _os
-        if _os.environ.get("LTPU_DEBUG_GROW"):
-            n_dbg = 2 * W_spec
-            state["dbg_bests_left_stats"] = jnp.zeros((n_dbg, 3),
-                                                      jnp.float32)
-            state["dbg_bests_dl"] = jnp.zeros(n_dbg, bool)
         state = jax.lax.while_loop(
             wave_cond, wave_body_c2f if use_c2f else wave_body, state)
     else:
@@ -1767,19 +1764,8 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
                  ("rec_left_min", "rec_left_max",
                   "rec_right_min", "rec_right_max")}
     if do_spec:
-        extra["n_arm_passes"] = state["n_arm_passes"]
-    import os as _os
-    if _os.environ.get("LTPU_DEBUG_GROW"):
-        # debug-only: expose the per-leaf best-split cache
-        for k in ("best_gain", "best_feature", "best_threshold",
-                  "best_default_left", "best_left_mask",
-                  "best_left_stats"):
-            extra["dbg_" + k] = state[k]
-        if "hist" in state:
-            extra["dbg_hist"] = state["hist"]
-        for k in state:
-            if k.startswith("dbg_"):
-                extra[k] = state[k]
+        for k in GROW_COUNTERS:
+            extra[k] = state[k]
     if p.quantize:
         # leaf-output renewal from FULL-PRECISION gradient sums — the
         # quantized-training leaf refit (RenewIntGradTreeOutput,

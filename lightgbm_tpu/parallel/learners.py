@@ -18,7 +18,8 @@ from typing import Optional
 
 import numpy as np
 
-from ..ops.grow import DistConfig, GrowParams, build_tree
+from ..ops.grow import (GROW_COUNTERS, DistConfig, GrowParams,
+                        build_tree)
 from ..utils.log import Log
 
 AXIS_NAME = "shard"
@@ -251,7 +252,8 @@ class DistributedBuilder:
                    kind in ("data", "feature", "voting") and
                    self.params.wave)
         if do_spec:
-            out_specs["n_arm_passes"] = R
+            for k in GROW_COUNTERS:
+                out_specs[k] = R
         if self.params.quantize:
             out_specs["leaf_stats_exact"] = R
         out_specs["leaf_idx"] = leaf_idx_spec

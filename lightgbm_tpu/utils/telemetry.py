@@ -17,11 +17,13 @@ Record stream (all records carry ``schema``/``type``/``seq``/``wall_time``):
   for the booster (two_col vs wave vs routed vs exact, with the gate
   that rejected each higher tier), config subset, device memory stats
   when the backend exposes them.
-- ``iteration``  — per boosting iteration: phase-timer deltas from
-  ``profiling.py``, XLA compile/retrace counter deltas (hooked via
+- ``iteration``  — per boosting iteration: phase deltas
+  (``phases_ms``; the phases of ``profiling.py`` are profiler
+  annotations and ``phase_secs/*`` counters), XLA compile/retrace
+  counter deltas, total and by program (hooked via
   ``jax.monitoring``, so a silent retrace storm becomes a visible
-  number), histogram passes + pool hit rate, per-learner collective
-  payload bytes, trees added.
+  number and names its program), histogram passes + pool hit rate,
+  per-learner collective payload bytes, trees added.
 - ``superstep``  — one record per fused K-iteration block
   (``fused_iters`` > 1, ``models/gbdt.py``): the block's first
   iteration, K, and the AMORTIZED phase/counter deltas — per-iteration
@@ -329,6 +331,8 @@ class _Counters:
 
 
 counters = _Counters()
+# where the phases of utils/profiling.py keep their seconds and calls
+PHASE_SECS, PHASE_CALLS = "phase_secs/", "phase_calls/"
 
 
 def counters_snapshot() -> Dict[str, float]:
@@ -349,7 +353,13 @@ def install_jax_hooks() -> None:
     separately — and ``.../jaxpr_trace_duration`` fires per abstract
     trace: a flat compile counter with a climbing trace counter is the
     signature of a retrace storm served from the compile cache, both
-    climbing is new-shape compilation."""
+    climbing is new-shape compilation.  Both events carry the
+    program's ``fun_name`` (a jitted ``f`` arrives as ``jit(f)`` at the
+    compile event and as ``f`` at the trace event), kept beside the
+    totals as ``xla_compiles/<fun_name>``,
+    ``xla_compile_secs/<fun_name>`` and ``jax_trace_secs/<fun_name>``,
+    so a recompile inside a run names its program in the record's
+    ``counters``."""
     global _HOOKS_INSTALLED
     with _HOOKS_LOCK:
         if _HOOKS_INSTALLED:
@@ -357,12 +367,18 @@ def install_jax_hooks() -> None:
         import jax.monitoring as monitoring
 
         def _on_duration(name, secs, **kw):
+            fun = kw.get("fun_name")
             if name.endswith("backend_compile_duration"):
                 counters.incr("xla_compiles")
                 counters.incr("xla_compile_secs", secs)
+                if fun:
+                    counters.incr(f"xla_compiles/{fun}")
+                    counters.incr(f"xla_compile_secs/{fun}", secs)
             elif name.endswith("jaxpr_trace_duration"):
                 counters.incr("jax_traces")
                 counters.incr("jax_trace_secs", secs)
+                if fun:
+                    counters.incr(f"jax_trace_secs/{fun}", secs)
 
         def _on_event(name, **kw):
             if name.endswith("compilation_cache/cache_misses"):
@@ -493,10 +509,12 @@ class RunRecorder:
                        ) -> Tuple[Dict[str, float], Dict[str, float]]:
         """(delta since ``last``, fresh snapshot).  The caller owns the
         snapshot so concurrent iteration/predict streams don't steal
-        each other's deltas."""
+        each other's deltas.  The phases stay out of it: a record
+        carries them once, as ``phases_ms``."""
         now = counters.snapshot()
         delta = {k: round(v - last.get(k, 0.0), 6)
-                 for k, v in now.items() if v != last.get(k, 0.0)}
+                 for k, v in now.items() if v != last.get(k, 0.0)
+                 and not k.startswith((PHASE_SECS, PHASE_CALLS))}
         return delta, now
 
     def emit(self, rtype: str, **fields) -> Dict[str, Any]:

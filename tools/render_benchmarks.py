@@ -1135,7 +1135,14 @@ def render(root=ROOT):
         "(`telemetry_file=`",
         "  param, `tools/triage_run.py`) attributing time to phases, "
         "compiles and",
-        "  collectives.",
+        "  collectives.  A phase is a profiler annotation "
+        "(`ltpu.<phase>`, on the",
+        "  device trace's clock) and a pair of process counters "
+        "(`phase_secs/<phase>`,",
+        "  `phase_calls/<phase>`); `phases_ms` is their diff over the "
+        "record, and compile",
+        "  and trace seconds are kept by program "
+        "(`xla_compile_secs/jit(superstep)`).",
         "- `tools/check_routed_kernels.py` and "
         "`tools/check_tpu_integration.py`",
         "  validate the Pallas kernels against their segsum oracles "
