@@ -955,6 +955,36 @@ class GBDT:
         if requested != "serial" and not dist_active:
             gates["learner"] = (f"tree_learner={requested} needs more "
                                 f"than one device; found {num_shards}")
+        # how each kind of histogram pass the booster runs tiles the
+        # stored bin matrix (ops/histogram.py BinTiling): worked out
+        # here, once, from the tiler — ``xt_copied`` says whether a
+        # pass copies the matrix in HBM before its kernel starts.
+        # Mirrors build_tree: c2f runs a coarse and a windowed refine
+        # pass (the root too); otherwise the batched full-resolution
+        # pass, and off the wave path the single-leaf pass ("root":
+        # the root and every leaf no batched pass armed)
+        hist_tiling = {}
+        if use_pallas:
+            from ..ops.grow import c2f_bins
+            from ..ops.histogram import bin_tiling
+            rpb = int(config.tpu_rows_per_block)
+            f_local = G_cols
+            if dist_active and learner in ("feature", "data2d"):
+                f_local = G_cols // (self._mesh_shape2d[1] if
+                                     self._mesh_shape2d else num_shards)
+            passes = {}
+            if refine_shift:
+                coarse, window = c2f_bins(self.max_bin, refine_shift,
+                                          any_missing)
+                passes["coarse"] = (coarse, 128)
+                passes["refine"] = (window, 128)
+            elif min(speculate, config.num_leaves) > 1:
+                passes["full"] = (self.max_bin, 128)
+            if not wave_on:
+                passes["root"] = (self.max_bin, 3 if quantize else 6)
+            hist_tiling = {
+                kind: bin_tiling(bins, f_local, cols, rpb).record()
+                for kind, (bins, cols) in passes.items()}
         if two_col:
             tier = "two_col"
         elif wave_on:
@@ -975,6 +1005,7 @@ class GBDT:
             "speculate": speculate,
             "wave": bool(wave_on),
             "hist_impl": self.grow_params.hist_impl,
+            "hist_tiling": hist_tiling,
             "use_hist_pool": bool(use_pool),
             "efb_groups": (int(self._bundles.num_groups)
                            if self._bundles is not None else 0),

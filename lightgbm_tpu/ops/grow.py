@@ -295,6 +295,16 @@ def collective_bytes_per_pass(params: GrowParams, num_features: int,
     return out
 
 
+def c2f_bins(max_bin: int, shift: int, any_missing: bool):
+    """(coarse bins, window bins) of the coarse-to-fine passes at
+    ``refine_shift`` = ``shift``.  +1 coarse slot with missing values:
+    the last one is RESERVED for the per-feature missing bin.  Value
+    bins can never alias it: they run to nv-1 <= B-2, so their coarse
+    ids stay below the unreserved slot count (ops/split.py:_c2f_miss).
+    The window is 2 coarse bins at fine resolution."""
+    return ((max_bin - 1) >> shift) + 1 + int(any_missing), 2 << shift
+
+
 def _hist(xt, vals, p: GrowParams):
     if isinstance(xt, PagedXt):
         # paged lane: the SAME accumulation as histogram_segsum, as a
@@ -719,13 +729,7 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
 
     if use_c2f:
         c2f_shift = p.refine_shift
-        # +1 with missing values: the last coarse slot is RESERVED for
-        # the per-feature missing bin.  Value bins can never alias it:
-        # they run to nv-1 <= B-2, so their coarse ids stay < the
-        # unreserved slot count (ops/split.py:_c2f_miss)
-        Bc_c2f = ((B - 1) >> c2f_shift) + 1 + \
-            (1 if sp.any_missing else 0)
-        R_c2f = 2 << c2f_shift       # 2 coarse bins at fine resolution
+        Bc_c2f, R_c2f = c2f_bins(B, c2f_shift, sp.any_missing)
         routed_coarse_ok = routed_ok and routed_chunk_ok(
             Bc_c2f, G_cols, 128, p.rows_per_block)
 

@@ -18,7 +18,13 @@ one-hot × values matmul on the MXU.  Two implementations:
 Tiling notes (measured on v5e):
 - The accumulator's row count FC*B must be a multiple of the 128-lane
   MXU tile or the streamed matmul pays ~40% — bins are padded to
-  ``_pad_bins`` and sliced off on exit.
+  ``_pad_bins`` and sliced off on exit, and ``_tile`` adds a feature
+  tail.  The one-hot rows STREAMED into it need not be on that grid:
+  the tail is never built (``_accumulate``), and a dot over 28*16 =
+  448 or 67*16 = 1072 rows runs 3.5-4% faster than over the 512 or
+  1152 of the whole block (PERF.md, PR 26).
+- The bin matrix is every kernel's operand as stored: no pass copies
+  it in HBM to add the tail (``BinTiling``).
 - FC=32 features per chunk with 512-row tiles beats 16×1024 by ~25%
   (fewer, larger one-hot builds against the same accumulator traffic).
 
@@ -35,7 +41,7 @@ Value columns:
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +155,79 @@ def _tile(b_pad: int, f: int, cols: int, rows_per_block: int
     return f_pad, fc, rows_per_block
 
 
+class BinTiling(NamedTuple):
+    """How one kind of pass tiles the stored (F, N) bin matrix.
+
+    The matrix is the kernel's operand AS STORED: no pass pads it in
+    HBM.  ``f_pad - f`` is the FEATURE TAIL the tiler asks for (it puts
+    ``fc * b_pad`` on the 128-lane grid by adding features); the kernel
+    makes it in VMEM (:func:`_accumulate`):
+
+    - one chunk (``fc == f_pad``): the block is the stored feature
+      dimension whole, ``(f, t)`` — legal because it is the array's
+      full dimension — and the kernel works on ``f`` rows; the tail's
+      accumulator rows are zeroed once and never touched;
+    - several chunks: blocks are ``(fc, t)`` and the last one overhangs
+      the array; the kernel masks it by feature index.
+    """
+    f: int
+    f_pad: int
+    fc: int
+    t: int
+
+    @property
+    def one_chunk(self) -> bool:
+        return self.fc == self.f_pad
+
+    @property
+    def rows(self) -> int:
+        """Feature rows the kernel sees across the grid: what the small
+        per-feature operands (window starts, missing bins) pad to."""
+        return self.f if self.one_chunk else self.f_pad
+
+    @property
+    def block_rows(self) -> int:
+        return self.f if self.one_chunk else self.fc
+
+    @property
+    def f_mask(self) -> int:
+        """``f`` where the last feature block overhangs the stored
+        matrix and the kernel has to mask it, else 0."""
+        return self.f if not self.one_chunk and self.f_pad != self.f else 0
+
+    def record(self) -> dict:
+        """The engagement record (``GBDT.tier_decision["hist_tiling"]``).
+        ``xt_copied``: whether the pass copies the matrix in HBM before
+        its kernel starts.  No shape does: Mosaic takes both blocks
+        above (on the chip: tools/check_routed_kernels.py)."""
+        return {"f": self.f, "f_pad": self.f_pad, "fc": self.fc,
+                "t": self.t, "xt_copied": False}
+
+
+def bin_tiling(max_bin: int, f: int, cols: int = 128,
+               rows_per_block: int = 1024) -> BinTiling:
+    """The tiling a pass over ``f`` stored features at ``max_bin`` bins
+    runs with (``cols``: 128 for the batched passes, the value columns
+    for the single-leaf one)."""
+    return BinTiling(f, *_tile(_pad_bins(max_bin), f, cols,
+                               rows_per_block))
+
+
+def _miss_operand(miss_bin: jax.Array, til: BinTiling) -> jax.Array:
+    """(F,) per-feature missing bins -> the (til.rows, 1) kernel operand
+    (-1: no missing bin)."""
+    return jnp.pad(miss_bin.astype(jnp.int32), (0, til.rows - til.f),
+                   constant_values=-1)[:, None]
+
+
+def _win_lo_operand(win_lo: jax.Array, til: BinTiling) -> jax.Array:
+    """(W, F) window starts -> the (til.rows, W) kernel operand: W on
+    the lane axis is always a full dimension, F on it is not a legal
+    block whenever features chunk."""
+    return jnp.pad(win_lo.astype(jnp.int32).T,
+                   ((0, til.rows - til.f), (0, 0)))
+
+
 def _compiler_params():
     """Raise Mosaic's scoped-VMEM ceiling (default ~16-32 MB) so the
     large one-hot row tiles the tiler picks actually compile; v5e has
@@ -193,20 +272,54 @@ def _rhs_from(sel_oh: jax.Array, valsc: jax.Array) -> jax.Array:
     return jnp.pad(rhs, ((0, _rhs_cols(W, C) - W * C), (0, 0)))
 
 
+def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
+                f_mask: int = 0, row0=0) -> None:
+    """Last stage of every histogram kernel: the one-hot x values MXU
+    contraction of one tile, added into the accumulator block.
+
+    xb (R, T) int32: the bin each row counts in, per feature (a value
+    outside [0, b_pad) counts nowhere); rhs (128 or 256, T) bf16;
+    out_ref (>= R * b_pad, lanes) f32.  The one-hot is laid out
+    (R*B, T) so the dot STREAMS R*B rows through the MXU while the
+    tiny (T, lanes) value matrix sits stationary as weights; the
+    reverse orientation reloads K x B weight tiles to stream only a
+    few rows and is ~100x slower.
+
+    The FEATURE TAIL is made here (see :class:`BinTiling`).  One
+    chunk: ``xb`` has the stored features' rows only, fewer than the
+    accumulator block; their one-hot rows alone are built, streamed
+    and added into the block's head, and the tail's rows keep the
+    zeros ``_init`` wrote.  Several chunks (``f_mask`` > 0): the last
+    block overhangs the stored matrix, and rows whose feature index
+    ``row0 + i`` is not below ``f_mask`` hold whatever VMEM held:
+    they are sent to bin -1."""
+    R, T = xb.shape
+    if f_mask:
+        feat = row0 + jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
+        xb = jnp.where(feat < f_mask, xb, -1)
+    onehot = (xb[:, None, :] ==
+              jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
+              ).astype(jnp.bfloat16)
+    acc = jax.lax.dot_general(
+        onehot.reshape(R * b_pad, T), rhs.T, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)            # (R*B, lanes)
+    if R * b_pad == out_ref.shape[0]:
+        out_ref[...] += acc
+    else:
+        out_ref[:R * b_pad, :] += acc
+
+
 def _hist_kernel(x_ref, v_ref, out_ref, *, b_pad: int, cols: int,
-                 exact: bool):
+                 exact: bool, f_mask: int = 0):
     """One grid step: accumulate one (feature-chunk × row-tile) into the
     shared accumulator.
 
-    x_ref: (FC, T) int32 bins; v_ref: (3, T) f32 [grad, hess, count];
+    x_ref: (FC, T) stored bins; v_ref: (3, T) f32 [grad, hess, count];
     out_ref: (FC*B, cols) f32 accumulated over the row-tile grid dim.
 
     Design: the scatter-add of the reference's CPU/OpenCL histogram
-    kernels becomes one one-hot × values MXU contraction per tile.  The
-    one-hot is laid out (FC*B, T) so the dot STREAMS FC·B rows through
-    the MXU while the tiny (T, cols) value matrix sits stationary as
-    weights; the reverse orientation reloads K×B weight tiles to stream
-    only a few rows and is ~100x slower.
+    kernels becomes one one-hot × values MXU contraction per tile
+    (:func:`_accumulate`).
     """
     import jax.experimental.pallas as pl
 
@@ -217,17 +330,11 @@ def _hist_kernel(x_ref, v_ref, out_ref, *, b_pad: int, cols: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    FC, T = x_ref.shape
     x = x_ref[...].astype(jnp.int32)  # (FC, T); widen narrow storage
     v = v_ref[...]  # (3, T) f32
     rhs = (v if exact else _split_hi_lo(v)).astype(jnp.bfloat16)
-    onehot = (x[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (FC, b_pad, T), 1)
-              ).astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        onehot.reshape(FC * b_pad, T), rhs.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (FC*B, cols)
-    out_ref[...] += acc
+    _accumulate(out_ref, x, rhs, b_pad, f_mask,
+                pl.program_id(0) * x.shape[0])
 
 
 @functools.partial(jax.jit,
@@ -245,28 +352,27 @@ def histogram_pallas(bins_t: jax.Array, vals: jax.Array, max_bin: int,
     f, n = bins_t.shape
     b_pad = _pad_bins(max_bin)
     cols = 3 if exact else 6
-    f_pad, fc, t = _tile(b_pad, f, cols, rows_per_block)
+    til = bin_tiling(max_bin, f, cols, rows_per_block)
+    _, f_pad, fc, t = til
     assert n % t == 0, (n, t)
-    # keep the device matrix in its NARROW storage dtype (uint8 at
-    # <=256 bins: 4x less HBM than int32); the kernel widens per tile
-    xt = bins_t
-    if f_pad != f:
-        xt = jnp.pad(xt, ((0, f_pad - f), (0, 0)))
     vt = vals.astype(jnp.float32).T  # (3, N)
 
+    # bins_t goes in AS STORED, in its NARROW dtype (uint8 at <=256
+    # bins: 4x less HBM than int32); the kernel widens per tile and
+    # makes the feature tail in VMEM (BinTiling)
     out = pl.pallas_call(
         functools.partial(_hist_kernel, b_pad=b_pad, cols=cols,
-                          exact=exact),
+                          exact=exact, f_mask=til.f_mask),
         grid=(f_pad // fc, n // t),  # (feature chunks, row tiles)
         in_specs=[
-            pl.BlockSpec((fc, t), lambda j, i: (j, i)),
+            pl.BlockSpec((til.block_rows, t), lambda j, i: (j, i)),
             pl.BlockSpec((3, t), lambda j, i: (0, i)),
         ],
         out_specs=pl.BlockSpec((fc * b_pad, cols), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((f_pad * b_pad, cols), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
-    )(xt, vt)
+    )(bins_t, vt)
     if not exact:
         out = out[:, :3] + out[:, 3:]  # hi + lo passes
     return out.reshape(f_pad, b_pad, 3)[:f, :max_bin]
@@ -299,7 +405,7 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
                        shift: int = 0, miss_idx: int = -1,
                        split_params=None, split_has_mono: bool = False,
                        split_has_pen: bool = False,
-                       split_has_bounds: bool = False):
+                       split_has_bounds: bool = False, f_mask: int = 0):
     """Multi-leaf variant: one pass accumulates histograms for up to
     ``width`` row-disjoint subsets (the speculative child-arming pass).
 
@@ -363,13 +469,7 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
     sel_oh = (sel == jax.lax.broadcasted_iota(
         jnp.int32, (width, T), 0)).astype(jnp.bfloat16)  # (W, T)
     rhs = _rhs_from(sel_oh, valsc)                     # (128, T) bf16
-    onehot = (x[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (FC, b_pad, T), 1)
-              ).astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        onehot.reshape(FC * b_pad, T), rhs.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (FC*B, 128)
-    out_ref[...] += acc
+    _accumulate(out_ref, x, rhs, b_pad, f_mask, pl.program_id(0) * FC)
 
     if fused_split:
         # row tiles are the minor grid dim, so the LAST step holds the
@@ -429,11 +529,9 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
     cols = 2 if two_col else (3 if exact else 6)
     W = width
     assert W * cols <= 128, (W, cols)
-    f_pad, fc, t = _tile(b_pad, f, 128, rows_per_block)
+    til = bin_tiling(max_bin, f, 128, rows_per_block)
+    _, f_pad, fc, t = til
     assert n % t == 0, (n, t)
-    xt = bins_t                              # narrow storage dtype
-    if f_pad != f:
-        xt = jnp.pad(xt, ((0, f_pad - f), (0, 0)))
     # narrow value operand: quantized gradients are small ints, exact
     # in int8/bf16 — keep the (3, N) operand at 1 byte/entry (it is
     # re-read from HBM EVERY pass; f32 costs ~4.8 ms/pass at bench
@@ -447,18 +545,17 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
     st = sel.astype(jnp.int32)[None, :]      # (1, N)
 
     in_specs = [
-        pl.BlockSpec((fc, t), lambda j, i: (j, i)),
+        pl.BlockSpec((til.block_rows, t), lambda j, i: (j, i)),
         pl.BlockSpec((3, t), lambda j, i: (0, i)),
         pl.BlockSpec((1, t), lambda j, i: (0, i)),
     ]
-    operands = [xt, vt, st]
+    operands = [bins_t, vt, st]              # bins as stored, narrow
     miss_idx = -1
     if miss_bin is not None and shift:
         miss_idx = max_bin - 1
-        mb = jnp.pad(miss_bin.astype(jnp.int32), (0, f_pad - f),
-                     constant_values=-1)[:, None]       # (f_pad, 1)
-        in_specs.append(pl.BlockSpec((fc, 1), lambda j, i: (j, 0)))
-        operands.append(mb)
+        in_specs.append(pl.BlockSpec((til.block_rows, 1),
+                                     lambda j, i: (j, 0)))
+        operands.append(_miss_operand(miss_bin, til))
     split_has_mono = split_has_pen = False
     if fused_split:
         assert shift == 0 and miss_bin is None, \
@@ -498,7 +595,8 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
                           split_has_mono=split_has_mono,
                           split_has_pen=split_has_pen,
                           split_has_bounds=fused_split and
-                          split_params.has_monotone),
+                          split_params.has_monotone,
+                          f_mask=til.f_mask),
         grid=(f_pad // fc, n // t),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -566,7 +664,8 @@ def histogram_segsum_multi(bins_t: jax.Array, vals: jax.Array,
 
 def _hist_kernel_multi_win(x_ref, v_ref, s_ref, lo_ref, *rest,
                            r_pad: int, width: int, exact: bool,
-                           two_col: bool, with_miss: bool = False):
+                           two_col: bool, with_miss: bool = False,
+                           f_mask: int = 0):
     """Windowed refine step: accumulate (leaf, feature)-windowed fine
     histograms.  x_ref (FC, T) bins; v_ref (3, T); s_ref (1, T) subset
     selector in [-1, width); lo_ref (width, FC) per-(subset, feature)
@@ -610,13 +709,7 @@ def _hist_kernel_multi_win(x_ref, v_ref, s_ref, lo_ref, *rest,
     rbin = x - lo_pr.astype(jnp.int32)
     rhs = _rhs_from(sel_oh, valsc)
     # out-of-window rows (rbin outside [0, r_pad)) match no iota column
-    onehot = (rbin[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (FC, r_pad, T), 1)
-              ).astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        onehot.reshape(FC * r_pad, T), rhs.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    out_ref[...] += acc
+    _accumulate(out_ref, rbin, rhs, r_pad, f_mask, pl.program_id(0) * FC)
 
 
 @functools.partial(jax.jit, static_argnames=("r_bins", "width",
@@ -640,37 +733,32 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
     cols = 2 if two_col else (3 if exact else 6)
     W = width
     assert W * cols <= 128, (W, cols)
-    f_pad, fc, t = _tile(r_pad, f, 128, rows_per_block)
+    til = bin_tiling(r_bins, f, 128, rows_per_block)
+    _, f_pad, fc, t = til
     assert n % t == 0, (n, t)
-    xt = bins_t
-    if f_pad != f:
-        xt = jnp.pad(xt, ((0, f_pad - f), (0, 0)))
     if vals.dtype == jnp.int8:               # see histogram_pallas_multi
         assert exact or two_col, "int8 values need exact/two_col"
         vt = vals.T                          # (3, N) int8
     else:
         vt = vals.astype(jnp.float32).T      # (3, N)
     st = sel.astype(jnp.int32)[None, :]      # (1, N)
-    lo = win_lo.astype(jnp.int32).T          # (F, W): W on the lane
-    if f_pad != f:                           # axis is always full
-        lo = jnp.pad(lo, ((0, f_pad - f), (0, 0)))
 
     in_specs = [
-        pl.BlockSpec((fc, t), lambda j, i: (j, i)),
+        pl.BlockSpec((til.block_rows, t), lambda j, i: (j, i)),
         pl.BlockSpec((3, t), lambda j, i: (0, i)),
         pl.BlockSpec((1, t), lambda j, i: (0, i)),
-        pl.BlockSpec((fc, W), lambda j, i: (j, 0)),
+        pl.BlockSpec((til.block_rows, W), lambda j, i: (j, 0)),
     ]
-    operands = [xt, vt, st, lo]
+    operands = [bins_t, vt, st, _win_lo_operand(win_lo, til)]
     if miss_bin is not None:
-        mb = jnp.pad(miss_bin.astype(jnp.int32), (0, f_pad - f),
-                     constant_values=-1)[:, None]
-        in_specs.append(pl.BlockSpec((fc, 1), lambda j, i: (j, 0)))
-        operands.append(mb)
+        in_specs.append(pl.BlockSpec((til.block_rows, 1),
+                                     lambda j, i: (j, 0)))
+        operands.append(_miss_operand(miss_bin, til))
     out = pl.pallas_call(
         functools.partial(_hist_kernel_multi_win, r_pad=r_pad, width=W,
                           exact=exact, two_col=two_col,
-                          with_miss=miss_bin is not None),
+                          with_miss=miss_bin is not None,
+                          f_mask=til.f_mask),
         grid=(f_pad // fc, n // t),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((fc * r_pad, 128), lambda j, i: (j, 0)),
@@ -808,7 +896,6 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    FC, T = x_ref.shape
     x = x_ref[...].astype(jnp.int32)
     v = v_ref[...]
     li = li_ref[...].astype(jnp.int32)
@@ -833,13 +920,7 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
             xb = jnp.where(x == mb, miss_idx, xb)
     else:
         xb = x
-    onehot = (xb[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (FC, b_pad, T), 1)
-              ).astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        onehot.reshape(FC * b_pad, T), rhs.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    out_ref[...] += acc
+    _accumulate(out_ref, xb, rhs, b_pad)    # one chunk: nothing to mask
 
     if fused_split:
         @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
@@ -858,9 +939,7 @@ def routed_chunk_ok(max_bin: int, f: int, cols: int = 128,
                     rows_per_block: int = 1024) -> bool:
     """True when the tiler keeps the whole feature dimension in one
     chunk — the routed kernel's requirement."""
-    b_pad = _pad_bins(max_bin)
-    f_pad, fc, _ = _tile(b_pad, f, cols, rows_per_block)
-    return fc == f_pad
+    return bin_tiling(max_bin, f, cols, rows_per_block).one_chunk
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -901,12 +980,10 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     cols = 2 if two_col else (3 if exact else 6)
     Wl = width
     assert Wl * cols <= 128, (Wl, cols)
-    f_pad, fc, t = _tile(b_pad, f, 128, rows_per_block)
-    assert fc == f_pad, "routed kernel needs a single feature chunk"
+    til = bin_tiling(max_bin, f, 128, rows_per_block)
+    _, f_pad, fc, t = til
+    assert til.one_chunk, "routed kernel needs a single feature chunk"
     assert n % t == 0, (n, t)
-    xt = bins_t
-    if f_pad != f:
-        xt = jnp.pad(xt, ((0, f_pad - f), (0, 0)))
     if vals.dtype == jnp.int8:               # see histogram_pallas_multi
         assert exact or two_col, "int8 values need exact/two_col"
         vt = vals.T
@@ -919,21 +996,20 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     R_tbl = tables.shape[0]
 
     in_specs = [
-        pl.BlockSpec((fc, t), lambda i: (0, i)),
+        pl.BlockSpec((til.block_rows, t), lambda i: (0, i)),
         pl.BlockSpec((3, t), lambda i: (0, i)),
         pl.BlockSpec((1, t), lambda i: (0, i)),
         pl.BlockSpec((R_tbl, W_tbl), lambda i: (0, 0)),
     ]
-    operands = [xt, vt, lt, tables]
+    operands = [bins_t, vt, lt, tables]
     miss_idx = -1
     if miss_bin is not None:
         assert R_tbl >= 6, "missing routing needs the default-left row"
         if shift:
             miss_idx = max_bin - 1
-        mb = jnp.pad(miss_bin.astype(jnp.int32), (0, f_pad - f),
-                     constant_values=-1)[:, None]
-        in_specs.append(pl.BlockSpec((fc, 1), lambda i: (0, 0)))
-        operands.append(mb)
+        in_specs.append(pl.BlockSpec((til.block_rows, 1),
+                                     lambda i: (0, 0)))
+        operands.append(_miss_operand(miss_bin, til))
     fused_split = split_params is not None
     split_has_mono = split_has_pen = False
     if fused_split:
@@ -1062,7 +1138,8 @@ def histogram_segsum_multi_routed(bins_t, vals, leaf_idx, tables,
 def _hist_kernel_multi_win_lanes(x_ref, v_ref, li_ref, ids_ref, lo_ref,
                                  *rest, r_pad: int, width: int,
                                  exact: bool, two_col: bool,
-                                 with_miss: bool = False):
+                                 with_miss: bool = False,
+                                 f_mask: int = 0):
     import jax.experimental.pallas as pl
 
     if with_miss:
@@ -1074,7 +1151,7 @@ def _hist_kernel_multi_win_lanes(x_ref, v_ref, li_ref, ids_ref, lo_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    FC, T = x_ref.shape
+    FC = x_ref.shape[0]
     x = x_ref[...].astype(jnp.int32)
     if with_miss:
         mb = mb_ref[...].astype(jnp.int32)              # (FC, 1)
@@ -1095,13 +1172,7 @@ def _hist_kernel_multi_win_lanes(x_ref, v_ref, li_ref, ids_ref, lo_ref,
     in_lane = jnp.sum(sel_oh_f, axis=0, keepdims=True) > 0.5
     rbin = jnp.where(in_lane, rbin, -1)
     rhs = _rhs_from(sel_oh_f.astype(jnp.bfloat16), valsc)
-    onehot = (rbin[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (FC, r_pad, T), 1)
-              ).astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        onehot.reshape(FC * r_pad, T), rhs.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    out_ref[...] += acc
+    _accumulate(out_ref, rbin, rhs, r_pad, f_mask, pl.program_id(0) * FC)
 
 
 @functools.partial(jax.jit, static_argnames=("r_bins", "width",
@@ -1131,11 +1202,9 @@ def histogram_pallas_multi_win_lanes(bins_t: jax.Array, vals: jax.Array,
     cols = 2 if two_col else (3 if exact else 6)
     W = width
     assert W * cols <= 128, (W, cols)
-    f_pad, fc, t = _tile(r_pad, f, 128, rows_per_block)
+    til = bin_tiling(r_bins, f, 128, rows_per_block)
+    _, f_pad, fc, t = til
     assert n % t == 0, (n, t)
-    xt = bins_t
-    if f_pad != f:
-        xt = jnp.pad(xt, ((0, f_pad - f), (0, 0)))
     if vals.dtype == jnp.int8:
         assert exact or two_col, "int8 values need exact/two_col"
         vt = vals.T
@@ -1143,27 +1212,24 @@ def histogram_pallas_multi_win_lanes(bins_t: jax.Array, vals: jax.Array,
         vt = vals.astype(jnp.float32).T
     lt = leaf_idx[None, :]                   # narrow storage dtype
     it = lane_ids.astype(jnp.int32)[None, :]  # (1, W)
-    lo = win_lo.astype(jnp.int32).T          # (F, W): W on the lanes
-    if f_pad != f:
-        lo = jnp.pad(lo, ((0, f_pad - f), (0, 0)))
 
     in_specs = [
-        pl.BlockSpec((fc, t), lambda j, i: (j, i)),
+        pl.BlockSpec((til.block_rows, t), lambda j, i: (j, i)),
         pl.BlockSpec((3, t), lambda j, i: (0, i)),
         pl.BlockSpec((1, t), lambda j, i: (0, i)),
         pl.BlockSpec((1, W), lambda j, i: (0, 0)),
-        pl.BlockSpec((fc, W), lambda j, i: (j, 0)),
+        pl.BlockSpec((til.block_rows, W), lambda j, i: (j, 0)),
     ]
-    operands = [xt, vt, lt, it, lo]
+    operands = [bins_t, vt, lt, it, _win_lo_operand(win_lo, til)]
     if miss_bin is not None:
-        mb = jnp.pad(miss_bin.astype(jnp.int32), (0, f_pad - f),
-                     constant_values=-1)[:, None]
-        in_specs.append(pl.BlockSpec((fc, 1), lambda j, i: (j, 0)))
-        operands.append(mb)
+        in_specs.append(pl.BlockSpec((til.block_rows, 1),
+                                     lambda j, i: (j, 0)))
+        operands.append(_miss_operand(miss_bin, til))
     out = pl.pallas_call(
         functools.partial(_hist_kernel_multi_win_lanes, r_pad=r_pad,
                           width=W, exact=exact, two_col=two_col,
-                          with_miss=miss_bin is not None),
+                          with_miss=miss_bin is not None,
+                          f_mask=til.f_mask),
         grid=(f_pad // fc, n // t),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((fc * r_pad, 128), lambda j, i: (j, 0)),

@@ -8,7 +8,14 @@ oracle at 63 bins and at 255 bins (the primary shape: 28 features x
 the routed kernel in all three modes (small / children /
 children+shift) with and without missing-value routing, the int8 value
 operand, the windowed and lane-routed windowed passes, and the
-leaf-stats renewal kernel.  Every integer diff must be 0; the script
+leaf-stats renewal kernel.  It does so at both benchmark widths, each
+at its ``fast`` job's lanes: 28 features on the two-column tier (64
+lanes) and 67 on the count-carrying one (42 lanes).  The bin matrix is
+the kernels' operand as stored and the feature tail is made in VMEM
+(``ops/histogram.BinTiling``): 28 features have a tail at 16 bins (32
+rows) and none at 32; 67 have one everywhere (72 at 16 bins, 68 at
+32), and at 8 coarse bins they run in five chunks of 16 whose last
+block overhangs the matrix.  Every integer diff must be 0; the script
 exits non-zero otherwise, and it refuses to run anywhere but on a TPU
 with Pallas compiled (``chip_smoke.acquire_chip``): on a CPU backend
 the kernels would run interpreted and prove nothing about Mosaic.  The
@@ -29,9 +36,9 @@ from lightgbm_tpu.ops.histogram import (  # noqa: E402
     histogram_pallas_multi_win, histogram_pallas_multi_win_lanes,
     histogram_segsum_multi, histogram_segsum_multi_routed,
     histogram_segsum_multi_win, histogram_segsum_multi_win_lanes,
-    leaf_stats_pallas)
+    leaf_stats_pallas, routed_chunk_ok)
 
-F, N, RPB, L = 28, 262144, 16384, 255
+N, RPB, L = 262144, 16384, 255
 FAILED = []
 
 
@@ -55,11 +62,13 @@ def report(name, pairs, tol=0.0):
         FAILED.append(name)
 
 
-def check_bins(B: int, shift: int, rng) -> None:
-    """All kernel-vs-oracle pairs at ``B`` fine bins; the c2f stage
-    collapses them ``2^shift``-to-1 and refines a ``2 << shift`` bin
-    window, as ops/grow.py does."""
-    tag = f"[{B} bins]"
+def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
+               rng) -> None:
+    """All kernel-vs-oracle pairs over ``F`` features at ``B`` fine
+    bins, ``W`` lanes a pass; the c2f stage collapses the bins
+    ``2^shift``-to-1 and refines a ``2 << shift`` bin window, as
+    ops/grow.py does."""
+    tag = f"[{F} features, {B} bins]"
     Bc, R = ((B - 1) >> shift) + 1, 2 << shift
     bins = rng.randint(0, B, size=(F, N)).astype(np.uint8)
     g = rng.randint(-120, 121, size=N).astype(np.float32)
@@ -68,7 +77,7 @@ def check_bins(B: int, shift: int, rng) -> None:
     li = rng.randint(0, 200, size=N).astype(np.int32)
     xb, vb, lb = jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(li)
     v8 = jnp.asarray(vals.astype(np.int8))
-    sel64 = jnp.asarray(li % 64)
+    selw = jnp.asarray(li % W)
 
     def tables(W, n_ids=200, new_lo=200, new_hi=255, rows=5):
         ids = rng.choice(n_ids, size=W, replace=False).astype(np.int32)
@@ -78,17 +87,23 @@ def check_bins(B: int, shift: int, rng) -> None:
         return np.stack(t).astype(np.int32)
 
     def routed_pair(name, vals_k, leaf, tbl, max_bin, **kw):
+        if not routed_chunk_ok(max_bin, F, 128, RPB):
+            # ops/grow.py routes in XLA at such a shape
+            print(f"{tag} {name}: features chunk at {max_bin} bins, "
+                  f"no routed pass", flush=True)
+            return
+
         def pairs():
             hp, lp, sp_ = histogram_pallas_multi_routed(
-                xb, vals_k, leaf, jnp.asarray(tbl), max_bin, 64, RPB,
-                exact=True, two_col=True, **kw)
+                xb, vals_k, leaf, jnp.asarray(tbl), max_bin, W, RPB,
+                exact=True, two_col=two_col, **kw)
             hs, ls, ss = histogram_segsum_multi_routed(
-                xb, vb, leaf, jnp.asarray(tbl), max_bin, 64,
-                two_col=True, **kw)
+                xb, vb, leaf, jnp.asarray(tbl), max_bin, W,
+                two_col=two_col, **kw)
             return {"hist": (hp, hs), "li": (lp, ls), "sel": (sp_, ss)}
         report(f"{tag} {name}", pairs)
 
-    for mode, Wt in (("small", 64), ("children", 32)):
+    for mode, Wt in (("small", W), ("children", W // 2)):
         tbl = tables(Wt)
         tbl[0, Wt - 2:] = L                  # two invalid lanes
         routed_pair(f"routed {mode}", vb, lb, tbl, B, mode=mode)
@@ -99,37 +114,38 @@ def check_bins(B: int, shift: int, rng) -> None:
     # new-leaf contraction (silent corruption at num_leaves>257 otherwise)
     routed_pair("routed L>256 ids", vb,
                 jnp.asarray(rng.randint(0, 500, size=N).astype(np.int32)),
-                tables(64, n_ids=500, new_lo=257, new_hi=511), B,
+                tables(W, n_ids=500, new_lo=257, new_hi=511), B,
                 mode="small")
 
     # int8 value operand (quantized ints exact in int8/bf16)
     report(f"{tag} int8 multi", lambda: {"hist": (
-        histogram_pallas_multi(xb, v8, sel64, B, 64, RPB, exact=True,
-                               two_col=True),
-        histogram_segsum_multi(xb, vb, sel64, B, 64, two_col=True))})
+        histogram_pallas_multi(xb, v8, selw, B, W, RPB, exact=True,
+                               two_col=two_col),
+        histogram_segsum_multi(xb, vb, selw, B, W, two_col=two_col))})
     # coarse pass (bins collapsed in-kernel)
     report(f"{tag} int8 multi coarse", lambda: {"hist": (
-        histogram_pallas_multi(xb, v8, sel64, Bc, 64, RPB, exact=True,
-                               two_col=True, shift=shift),
-        histogram_segsum_multi(xb, vb, sel64, Bc, 64, two_col=True,
+        histogram_pallas_multi(xb, v8, selw, Bc, W, RPB, exact=True,
+                               two_col=two_col, shift=shift),
+        histogram_segsum_multi(xb, vb, selw, Bc, W, two_col=two_col,
                                shift=shift))})
 
     # lane-routed windowed pass (li + child-id tables, no (N,) selector)
-    ids_w = jnp.asarray(rng.choice(200, size=64, replace=False)
+    ids_w = jnp.asarray(rng.choice(200, size=W, replace=False)
                         .astype(np.int32))
-    lo_w = jnp.asarray(rng.randint(0, B - R, size=(64, F))
+    lo_w = jnp.asarray(rng.randint(0, B - R, size=(W, F))
                        .astype(np.int32))
     report(f"{tag} win_lanes", lambda: {"hist": (
-        histogram_pallas_multi_win_lanes(xb, v8, lb, ids_w, lo_w, R, 64,
-                                         RPB, exact=True, two_col=True),
-        histogram_segsum_multi_win_lanes(xb, vb, lb, ids_w, lo_w, R, 64,
-                                         two_col=True))})
+        histogram_pallas_multi_win_lanes(xb, v8, lb, ids_w, lo_w, R, W,
+                                         RPB, exact=True,
+                                         two_col=two_col),
+        histogram_segsum_multi_win_lanes(xb, vb, lb, ids_w, lo_w, R, W,
+                                         two_col=two_col))})
 
     # missing-value variants: 6-row tables + per-feature miss bins
     mb = np.full(F, B - 1, np.int32)
     mb[::3] = -1                             # some without missing
     mbj = jnp.asarray(mb)
-    tbl6 = tables(64, rows=6)
+    tbl6 = tables(W, rows=6)
     routed_pair("routed+miss", v8, lb, tbl6, B, mode="small",
                 miss_bin=mbj)
     # routed coarse with the reserved missing slot (Bc value bins + 1)
@@ -137,10 +153,19 @@ def check_bins(B: int, shift: int, rng) -> None:
                 mode="small", miss_bin=mbj)
     # windowed with missing exclusion
     report(f"{tag} win+miss", lambda: {"hist": (
-        histogram_pallas_multi_win(xb, v8, sel64, lo_w, R, 64, RPB,
-                                   exact=True, two_col=True, miss_bin=mbj),
-        histogram_segsum_multi_win(xb, vb, sel64, lo_w, R, 64,
-                                   two_col=True, miss_bin=mbj))})
+        histogram_pallas_multi_win(xb, v8, selw, lo_w, R, W, RPB,
+                                   exact=True, two_col=two_col,
+                                   miss_bin=mbj),
+        histogram_segsum_multi_win(xb, vb, selw, lo_w, R, W,
+                                   two_col=two_col, miss_bin=mbj))})
+    # coarse pass with the reserved missing slot, no routing: at 67
+    # features and 17 slots it runs chunked (five blocks of 16)
+    report(f"{tag} multi coarse+miss", lambda: {"hist": (
+        histogram_pallas_multi(xb, v8, selw, Bc + 1, W, RPB, exact=True,
+                               two_col=two_col, shift=shift,
+                               miss_bin=mbj),
+        histogram_segsum_multi(xb, vb, selw, Bc + 1, W, two_col=two_col,
+                               shift=shift, miss_bin=mbj))})
 
 
 def check_leaf_stats(rng) -> None:
@@ -167,8 +192,10 @@ def check_leaf_stats(rng) -> None:
 def main() -> int:
     acquire_chip()
     rng = np.random.RandomState(0)
-    check_bins(63, 3, rng)
-    check_bins(255, 4, rng)
+    # (features, lanes, two-column): the two cells' `fast` jobs
+    for F, W, two_col in ((28, 64, True), (67, 42, False)):
+        check_bins(F, W, two_col, 63, 3, rng)
+        check_bins(F, W, two_col, 255, 4, rng)
     check_leaf_stats(rng)
     print("FAILED: " + ", ".join(FAILED) if FAILED
           else "ALL KERNEL CHECKS PASS")
