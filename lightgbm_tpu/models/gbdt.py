@@ -982,8 +982,11 @@ class GBDT:
                 passes["full"] = (self.max_bin, 128)
             if not wave_on:
                 passes["root"] = (self.max_bin, 3 if quantize else 6)
+            # the batched passes contract in int8 where their values
+            # are int8; the single-leaf pass ("root") takes float32
             hist_tiling = {
-                kind: bin_tiling(bins, f_local, cols, rpb).record()
+                kind: bin_tiling(bins, f_local, cols, rpb).record(
+                    int8=self.grow_params.int8_values and kind != "root")
                 for kind, (bins, cols) in passes.items()}
         if two_col:
             tier = "two_col"

@@ -210,6 +210,15 @@ class GrowParams:
     # best-first (default).
     spec_tolerance: float = 0.0
 
+    @property
+    def int8_values(self) -> bool:
+        """The batched passes take their values as int8 (``vals_i8``),
+        and the kernels then contract in int8 on the MXU
+        (ops/histogram.py ``_accumulate``): what the tier record's
+        ``mxu`` says."""
+        return (self.vals_i8 and self.hist_impl == "pallas" and
+                0 < self.quantize <= 127)
+
 
 def collective_bytes_per_pass(params: GrowParams, num_features: int,
                               num_rows: int) -> dict:
@@ -647,9 +656,8 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         # 63 bins interleaved; sub-8-sublane bf16 blocks don't pay.
         # int8 is different: quantized ints are EXACT in int8 and cut
         # the per-pass value read 4x)
-        use_i8 = (p.vals_i8 and p.hist_impl == "pallas" and
-                  0 < p.quantize <= 127)
-        kvals = base_vals.astype(jnp.int8) if use_i8 else base_vals
+        kvals = (base_vals.astype(jnp.int8) if p.int8_values
+                 else base_vals)
 
         def _wave_hist_finish(h):
             """Strategy collective + unit policy for batched passes:

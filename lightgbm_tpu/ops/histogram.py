@@ -37,10 +37,20 @@ Value columns:
   |v| ≤ 256 (quantized gradients) — exactly representable in bf16, so
   3 columns suffice.  This doubles the leaf width of the speculative
   multi-leaf pass (21 → 42 histograms per matmul) for free.
+- int8 values (``exact`` or ``two_col``; ops/grow.py hands them over
+  where ``GrowParams.int8_values``): the batched kernels build the
+  one-hot and the rhs as int8 and contract int8 x int8 -> int32, the
+  MXU's faster mode (393 TOP/s against 197 TFLOP/s bf16 on a v5e);
+  the tile's int32 partial is converted to float32 and added into the
+  float32 accumulator, every output bit the bf16 contraction's
+  (``_accumulate``, ``_onehot_int8``, ``_rhs_int8``).  The operand's
+  dtype alone decides: float32 values keep the bf16 path, and the
+  tier record's ``mxu`` says which a booster runs.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Tuple
 
 import jax
@@ -114,10 +124,12 @@ def _tile(b_pad: int, f: int, cols: int, rows_per_block: int
     """(padded features, features-per-chunk, rows-per-tile).
 
     The pass is MXU-STREAM bound: cost ∝ f_pad * b_pad * N (the one-hot
-    rows fed through the systolic array), so the FIRST objective is the
-    smallest f_pad with a legal chunking (fc divides f_pad, fc*b_pad a
-    multiple of the 128-lane tile) — e.g. 28 features stay 28 at 64
-    bins (28*64 = 14*128) instead of padding to 32 and paying +14%.
+    rows fed through the systolic array, at the bf16 peak, or at the
+    int8 peak where the values are int8: ``_accumulate``), so the
+    FIRST objective is the smallest f_pad with a legal chunking (fc
+    divides f_pad, fc*b_pad a multiple of the 128-lane tile) — e.g.
+    28 features stay 28 at 64 bins (28*64 = 14*128) instead of
+    padding to 32 and paying +14%.
     Then prefer large row tiles (fewer grid steps / accumulator
     revisits) under a VMEM budget of one-hot (FC, B, T) bf16 +
     accumulator (FC*B, cols) f32 + double-buffered inputs."""
@@ -146,7 +158,6 @@ def _tile(b_pad: int, f: int, cols: int, rows_per_block: int
             return f_pad, best[2], best[1]
     # fallback: smallest legal chunk — fc*b_pad on the 128-lane grid
     # AND fc on the 8-sublane grid (lcm of both constraints)
-    import math
     fc = 128 // math.gcd(b_pad, 128)
     fc = fc * 8 // math.gcd(fc, 8)
     f_pad = (f + fc - 1) // fc * fc
@@ -195,13 +206,17 @@ class BinTiling(NamedTuple):
         matrix and the kernel has to mask it, else 0."""
         return self.f if not self.one_chunk and self.f_pad != self.f else 0
 
-    def record(self) -> dict:
+    def record(self, int8: bool = False) -> dict:
         """The engagement record (``GBDT.tier_decision["hist_tiling"]``).
         ``xt_copied``: whether the pass copies the matrix in HBM before
         its kernel starts.  No shape does: Mosaic takes both blocks
-        above (on the chip: tools/check_routed_kernels.py)."""
+        above (on the chip: tools/check_routed_kernels.py).  ``mxu``:
+        the type the pass contracts in (:func:`_accumulate`); the
+        caller says whether the pass is given int8 values (ops/grow.py
+        ``GrowParams.int8_values``)."""
         return {"f": self.f, "f_pad": self.f_pad, "fc": self.fc,
-                "t": self.t, "xt_copied": False}
+                "t": self.t, "xt_copied": False,
+                "mxu": "int8" if int8 else "bf16"}
 
 
 def bin_tiling(max_bin: int, f: int, cols: int = 128,
@@ -272,18 +287,87 @@ def _rhs_from(sel_oh: jax.Array, valsc: jax.Array) -> jax.Array:
     return jnp.pad(rhs, ((0, _rhs_cols(W, C) - W * C), (0, 0)))
 
 
+def _rhs_int8(on: jax.Array, valsc: jax.Array) -> jax.Array:
+    """The rhs of the int8 contraction (:func:`_accumulate`): row
+    ``k`` is ``on[k] ? valsc[k % C] : 0``, the quantized integers as
+    they are.  on (128 or 256, T) bool: the rows of the subset that
+    rhs row ``k`` belongs to (:func:`_rhs_row_lane`); valsc (C, T)
+    int8.  Built row by row in two dimensions: the (W, C, T) ->
+    (W * C, T) regrouping of :func:`_rhs_from` is what Mosaic takes
+    longest to compile in a pass (46 s of an int32 one at T = 16384,
+    against 1.4 s of this)."""
+    lanes = on.shape[0]
+    C = valsc.shape[0]
+    c = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0), C)
+    v = valsc.astype(jnp.int32)
+    row = v[0:1]
+    for i in range(1, C):
+        row = jnp.where(c == i, v[i:i + 1], row)       # (lanes, T)
+    return jnp.where(on, row, 0).astype(jnp.int8)
+
+
+def _rhs_row_lane(width: int, cols: int) -> jax.Array:
+    """(128 or 256, 1) int32: the subset that rhs row ``k`` belongs to,
+    ``k // cols``; -2, which no selector holds, beyond ``width *
+    cols``."""
+    k = jax.lax.broadcasted_iota(jnp.int32, (_rhs_cols(width, cols), 1), 0)
+    return jnp.where(k < width * cols, jax.lax.div(k, cols), -2)
+
+
+def _onehot_int8(xb: jax.Array, b_pad: int) -> jax.Array:
+    """(R, T) int32 bins -> the (R * b_pad, T) int8 one-hot, row
+    ``r * b_pad + b`` holding ``xb[r] == b``.  ``R * b_pad`` is on the
+    (32, 128) int8 tile grid (:func:`_accumulate`).
+
+    Where ``b_pad`` is a multiple of 32 the one-hot is made four rows
+    to a 32-bit word with no narrowing: int8 rows ``4j .. 4j + 3`` are
+    the bytes of int32 row ``j`` (``pltpu.bitcast``), so word ``q`` of
+    a feature is ``1 << 8 * (x & 3)`` where ``x >> 2 == q`` and 0
+    elsewhere (a bin outside ``[0, b_pad)`` meets no ``q``): a compare
+    and a select a WORD, against a compare, a select and two
+    narrowing packs an ELEMENT.  The words regroup ``(R, b_pad / 4,
+    T) -> (R * b_pad / 4, T)`` for nothing only where ``b_pad / 4``
+    fills the 8 sublanes; at 16 bins it is a relayout, and the plain
+    form (compare in int32, regroup, narrow) is the faster one: 30.2
+    against 52.6 ms a routed coarse pass of 20M x 67, where at 32 bins
+    the words take a refine pass from 40.2 to 34.8 (PERF.md, PR 29)."""
+    R, T = xb.shape
+    if b_pad % 32 == 0:
+        from jax.experimental.pallas import tpu as pltpu
+        q = b_pad // 4
+        byte = jnp.left_shift(1, (xb & 3) << 3)              # (R, T)
+        words = jnp.where(
+            (xb >> 2)[:, None, :] ==
+            jax.lax.broadcasted_iota(jnp.int32, (R, q, T), 1),
+            byte[:, None, :], 0)
+        return pltpu.bitcast(words.reshape(R * q, T), jnp.int8)
+    onehot = (xb[:, None, :] ==
+              jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
+              ).astype(jnp.int32)
+    return onehot.reshape(R * b_pad, T).astype(jnp.int8)
+
+
 def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
                 f_mask: int = 0, row0=0) -> None:
     """Last stage of every histogram kernel: the one-hot x values MXU
     contraction of one tile, added into the accumulator block.
 
     xb (R, T) int32: the bin each row counts in, per feature (a value
-    outside [0, b_pad) counts nowhere); rhs (128 or 256, T) bf16;
-    out_ref (>= R * b_pad, lanes) f32.  The one-hot is laid out
+    outside [0, b_pad) counts nowhere); rhs (128 or 256, T) bf16 or
+    int8; out_ref (>= R * b_pad, lanes) f32.  The one-hot is laid out
     (R*B, T) so the dot STREAMS R*B rows through the MXU while the
     tiny (T, lanes) value matrix sits stationary as weights; the
     reverse orientation reloads K x B weight tiles to stream only a
     few rows and is ~100x slower.
+
+    The contraction's type follows the rhs, which follows the values
+    the kernel was given.  int8 (quantized gradients: integers within
+    +-127 against a 0/1 one-hot): int8 x int8 -> int32, the MXU's
+    faster mode; a tile's partial sum is at most ``T`` x 127 = 2.08M
+    at ``T`` = 16384, exact in int32 and in the float32 it is
+    converted to and added into, so every output bit is what the bf16
+    contraction of the same integers gives.  Anything else: bf16 x
+    bf16 -> f32.
 
     The FEATURE TAIL is made here (see :class:`BinTiling`).  One
     chunk: ``xb`` has the stored features' rows only, fewer than the
@@ -297,12 +381,27 @@ def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
     if f_mask:
         feat = row0 + jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
         xb = jnp.where(feat < f_mask, xb, -1)
-    onehot = (xb[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
-              ).astype(jnp.bfloat16)
-    acc = jax.lax.dot_general(
-        onehot.reshape(R * b_pad, T), rhs.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (R*B, lanes)
+    if rhs.dtype == jnp.int8:
+        # int8 tiles are (32, 128): one-hot rows off that grid (67
+        # features x 16 bins = 33.5 tiles) go up to the next multiple
+        # on feature rows of bin -1, which match nothing (1088 rows,
+        # not the 1152 of the whole accumulator block)
+        extra = -R % (32 // math.gcd(b_pad, 32))
+        if extra:
+            xb = jnp.concatenate(
+                [xb, jnp.full((extra, T), -1, jnp.int32)], axis=0)
+            R += extra
+        acc = jax.lax.dot_general(
+            _onehot_int8(xb, b_pad), rhs, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+    else:
+        onehot = (xb[:, None, :] ==
+                  jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
+                  ).astype(jnp.bfloat16)
+        acc = jax.lax.dot_general(
+            onehot.reshape(R * b_pad, T), rhs.T,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (R*B, lanes)
     if R * b_pad == out_ref.shape[0]:
         out_ref[...] += acc
     else:
@@ -466,9 +565,12 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
     else:
         cols = 3 if exact else 6
         valsc = v if exact else _split_hi_lo(v)        # (cols, T) f32
-    sel_oh = (sel == jax.lax.broadcasted_iota(
-        jnp.int32, (width, T), 0)).astype(jnp.bfloat16)  # (W, T)
-    rhs = _rhs_from(sel_oh, valsc)                     # (128, T) bf16
+    if v.dtype == jnp.int8:
+        rhs = _rhs_int8(sel == _rhs_row_lane(width, cols), valsc)
+    else:
+        sel_oh = (sel == jax.lax.broadcasted_iota(
+            jnp.int32, (width, T), 0)).astype(jnp.bfloat16)  # (W, T)
+        rhs = _rhs_from(sel_oh, valsc)                 # (128, T) bf16
     _accumulate(out_ref, x, rhs, b_pad, f_mask, pl.program_id(0) * FC)
 
     if fused_split:
@@ -707,7 +809,10 @@ def _hist_kernel_multi_win(x_ref, v_ref, s_ref, lo_ref, *rest,
         lo, sel_oh, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)             # (FC, T)
     rbin = x - lo_pr.astype(jnp.int32)
-    rhs = _rhs_from(sel_oh, valsc)
+    if v.dtype == jnp.int8:
+        rhs = _rhs_int8(sel == _rhs_row_lane(width, cols), valsc)
+    else:
+        rhs = _rhs_from(sel_oh, valsc)
     # out-of-window rows (rbin outside [0, r_pad)) match no iota column
     _accumulate(out_ref, rbin, rhs, r_pad, f_mask, pl.program_id(0) * FC)
 
@@ -911,7 +1016,11 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
     else:
         cols = 3 if exact else 6
         valsc = v if exact else _split_hi_lo(v)
-    rhs = _rhs_from(sel_oh, valsc)
+    if v.dtype == jnp.int8:
+        rhs = _rhs_int8(sel_out == _rhs_row_lane(sel_oh.shape[0], cols),
+                        valsc)
+    else:
+        rhs = _rhs_from(sel_oh, valsc)
     if shift:
         xb = x >> shift
         if with_miss and miss_idx >= 0:
@@ -1160,8 +1269,10 @@ def _hist_kernel_multi_win_lanes(x_ref, v_ref, li_ref, ids_ref, lo_ref,
     li = li_ref[...].astype(jnp.int32)                  # (1, T)
     ids = ids_ref[...]                                  # (1, W)
     if two_col:
+        cols = 2
         valsc = v[:2]
     else:
+        cols = 3 if exact else 6
         valsc = v if exact else _split_hi_lo(v)
     sel_oh_f = (li == ids.T).astype(jnp.float32)        # (W, T)
     lo = lo_ref[...].astype(jnp.float32)                # (FC, W)
@@ -1171,7 +1282,15 @@ def _hist_kernel_multi_win_lanes(x_ref, v_ref, li_ref, ids_ref, lo_ref,
     rbin = x - lo_pr.astype(jnp.int32)
     in_lane = jnp.sum(sel_oh_f, axis=0, keepdims=True) > 0.5
     rbin = jnp.where(in_lane, rbin, -1)
-    rhs = _rhs_from(sel_oh_f.astype(jnp.bfloat16), valsc)
+    if v.dtype == jnp.int8:
+        # the lane ids, one a rhs row (-1, no leaf's id, beyond them)
+        row_ids = jnp.repeat(ids.T, cols, axis=0)       # (W * C, 1)
+        row_ids = jnp.pad(
+            row_ids, ((0, _rhs_cols(width, cols) - width * cols), (0, 0)),
+            constant_values=-1)
+        rhs = _rhs_int8(li == row_ids, valsc)
+    else:
+        rhs = _rhs_from(sel_oh_f.astype(jnp.bfloat16), valsc)
     _accumulate(out_ref, rbin, rhs, r_pad, f_mask, pl.program_id(0) * FC)
 
 

@@ -83,12 +83,16 @@ def _data(f, bins, miss, seed):
              rng.randint(0, 2, size=W)]).astype(np.int32)))
 
 
-def _run(wrapper, d, bins, pallas: bool):
-    """One pass through the Pallas wrapper or its segsum twin, as a
-    tuple of arrays."""
+def _run(wrapper, d, bins, pallas: bool, two_col: bool = False,
+         int8: bool = True):
+    """One pass through the Pallas wrapper (its values int8, or the
+    same integers as float32) or its segsum twin, as a tuple of
+    arrays."""
     shift = (FINE // bins).bit_length() - 1      # 256 fine -> `bins`
-    vals = d["v8"] if pallas else d["vf"]
+    vals = d["v8"] if pallas and int8 else d["vf"]
     kw = dict(exact=True) if pallas else {}
+    if wrapper != "single":
+        kw["two_col"] = two_col
     if wrapper == "single":
         # fine bins collapsed on the host: this pass takes no shift
         x = (d["x"] >> shift).astype(jnp.uint8)
@@ -183,7 +187,9 @@ def test_no_copy_of_the_bin_matrix(wrapper, f):
 def test_fast_job_records_its_tiling(monkeypatch, f, extra, kinds):
     """The engagement record: a booster with the benchmark's ``fast``
     parameters at the cells' widths says, for each kind of pass it
-    runs, how the matrix is tiled and that no pass copies it."""
+    runs, how the matrix is tiled, that no pass copies it, and that it
+    contracts in int8 (a booster without ``use_quantized_grad`` reads
+    ``bf16``: tests/test_hist_int8.py)."""
     import lightgbm_tpu as lgb
     monkeypatch.setenv("LTPU_PALLAS_INTERPRET", "1")
     rng = np.random.RandomState(0)
@@ -201,3 +207,4 @@ def test_fast_job_records_its_tiling(monkeypatch, f, extra, kinds):
         assert (rec["f"], rec["f_pad"], rec["fc"]) == (f_, f_pad, fc)
         assert rec["xt_copied"] is False
         assert rec["t"] == 1024
+        assert rec["mxu"] == "int8"     # quantized: int8 values

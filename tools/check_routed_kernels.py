@@ -21,12 +21,22 @@ with Pallas compiled (``chip_smoke.acquire_chip``): on a CPU backend
 the kernels would run interpreted and prove nothing about Mosaic.  The
 oracles themselves are pinned on the CPU by tests/test_routed.py and
 this script closes the kernel half.
+
+Last, the contraction's type (``ops/histogram._accumulate``): the four
+batched kernels at both cells' shapes, rows and lanes (21M x 28 on 64
+two-column lanes, 20M x 67 on 42), int8 values (the int8 x int8 ->
+int32 contraction) against the same integers as float32 (the bf16
+one); every diff must be 0, and the ms a pass of each is printed
+(medians of 6): the kernel-alone table of PERF.md, by one command.
 """
 import os
+import statistics
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
@@ -168,6 +178,63 @@ def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
                                shift=shift, miss_bin=mbj))})
 
 
+def check_int8_contraction(cell: str, F: int, rows: int, W: int,
+                           two_col: bool, rng) -> None:
+    """The four batched kernels as the ``fast`` job of ``cell`` runs
+    them (c2f shift 4: 16 coarse bins, a 32-bin window), int8 values
+    against the same integers as float32: equal bit for bit, and the
+    ms a pass of each."""
+    n = -(-rows // RPB) * RPB
+    xb = jnp.asarray(np.random.default_rng(F).integers(
+        0, 255, size=(F, n), dtype=np.uint8))
+    g = rng.randint(-120, 121, size=n).astype(np.int8)
+    h = rng.randint(0, 121, size=n).astype(np.int8)
+    v8 = jnp.asarray(np.stack([g, h, np.ones(n, np.int8)], -1))
+    vf = v8.astype(jnp.float32)
+    li = rng.randint(0, 200, size=n).astype(np.uint8)
+    lb, selw = jnp.asarray(li), jnp.asarray(li.astype(np.int32) % W)
+    ids = rng.choice(200, size=W, replace=False).astype(np.int32)
+    tbl = jnp.asarray(np.stack(
+        [ids, rng.randint(0, F, size=W), rng.randint(0, 254, size=W),
+         rng.randint(200, 255, size=W), rng.randint(0, 2, size=W)]
+    ).astype(np.int32))
+    ids_w = jnp.asarray(ids)
+    lo_w = jnp.asarray(rng.randint(0, 255 - 32, size=(W, F))
+                       .astype(np.int32))
+    kw = dict(exact=True, two_col=two_col)
+    passes = {
+        "routed coarse": lambda v: histogram_pallas_multi_routed(
+            xb, v, lb, tbl, 16, W, RPB, shift=4, mode="small", **kw),
+        "win_lanes refine": lambda v: histogram_pallas_multi_win_lanes(
+            xb, v, lb, ids_w, lo_w, 32, W, RPB, **kw),
+        "multi coarse": lambda v: histogram_pallas_multi(
+            xb, v, selw, 16, W, RPB, shift=4, **kw),
+        "multi_win refine": lambda v: histogram_pallas_multi_win(
+            xb, v, selw, lo_w, 32, W, RPB, **kw),
+    }
+
+    def timed(fn, v):
+        out = jax.block_until_ready(fn(v))      # compiles
+        ms = []
+        for _ in range(6):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(v))
+            ms.append((time.perf_counter() - t0) * 1e3)
+        return jax.tree_util.tree_leaves(out), statistics.median(ms)
+
+    for name, fn in passes.items():
+        took = {}
+
+        def pairs(fn=fn, took=took):
+            o8, took["int8"] = timed(fn, v8)
+            of, took["float32"] = timed(fn, vf)
+            return {f"out{i}": p for i, p in enumerate(zip(o8, of))}
+        report(f"[{cell}: {rows} x {F}, {W} lanes] {name}, int8 against "
+               f"float32 values", pairs)
+        print("    ms a pass: " + ", ".join(
+            f"{k} {v:.2f}" for k, v in took.items()), flush=True)
+
+
 def check_leaf_stats(rng) -> None:
     """leaf-stats (renewal) kernel vs numpy: the hi/lo bf16 split
     carries ~2^-16 of each summand's magnitude."""
@@ -197,6 +264,10 @@ def main() -> int:
         check_bins(F, W, two_col, 63, 3, rng)
         check_bins(F, W, two_col, 255, 4, rng)
     check_leaf_stats(rng)
+    # (cell, features, rows, lanes, two-column)
+    for cell in (("higgs28.fast", 28, 21_000_000, 64, True),
+                 ("criteo67.fast", 67, 20_000_000, 42, False)):
+        check_int8_contraction(*cell, rng)
     print("FAILED: " + ", ".join(FAILED) if FAILED
           else "ALL KERNEL CHECKS PASS")
     return 1 if FAILED else 0
