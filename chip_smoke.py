@@ -52,18 +52,18 @@ BASE = {
 HEADLINE = {"wave_splits": True, "use_quantized_grad": True,
             "fused_iters": FUSED_K}
 
-# what the gates of models/gbdt.py give this shape on one chip
+# what the plan of models/tier.py gives this shape on one chip
 EXPECT_DEFAULTS = {
     "hist_impl": "pallas", "tier": "speculative", "wave": False,
     "quantize": 0, "c2f": False, "routed": True,
-    "split_kernel": "pallas", "split_fused": False,
+    "split_kernel": "pallas",
     "learner": "serial", "num_shards": 1,
 }
 EXPECT_HEADLINE = {
     "hist_impl": "pallas", "tier": "two_col", "wave": True,
     "c2f": True, "refine_shift": 4, "routed": True,
     # c2f scans coarse + window in XLA; the gate is in the record
-    "split_kernel": "xla", "split_fused": False,
+    "split_kernel": "xla",
     "learner": "serial", "num_shards": 1,
 }
 

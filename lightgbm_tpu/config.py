@@ -525,10 +525,7 @@ PARAMS: List[Param] = [
     _p("split_kernel", "auto", str, ("best_split_kernel",),
        "best-split search engine: auto, pallas, xla.  pallas runs the "
        "split scan as a standalone per-(leaf, feature-tile) Pallas "
-       "kernel with a two-stage tile-then-global argmax.  (The "
-       "epilogue fused into the batched histogram kernels does not "
-       "lower under Mosaic and runs in the interpret lane only; the "
-       "tier record carries gates.split_fused.)  "
+       "kernel with a two-stage tile-then-global argmax.  "
        "auto = pallas on an accelerator backend, xla elsewhere.  "
        "Numerical features with the serial tree learner only; "
        "categorical features, EFB bundles, forced splits, c2f "

@@ -28,16 +28,6 @@ from .tree import Tree, cat_bitset
 
 _KEPS = 1e-15
 
-# why the fused histogram→split epilogue (ops/split.py
-# split_epilogue_rows) never runs compiled: it regroups the
-# accumulator's 128 lanes into (leaf, channel) with a reshape that
-# Mosaic's layout inference rejects (jax 0.9.0, libtpu 0.0.34)
-SPLIT_FUSED_GATE = (
-    "Mosaic: infer-vector-layout: unsupported shape cast "
-    "(tpu.reshape 768x62xf32 -> 12x64x31x2xf32 in the fused split "
-    "epilogue); every child scans through the standalone kernel")
-
-
 def _threshold_l1(s, l1):
     if l1 <= 0:
         return np.asarray(s, np.float64)
@@ -193,9 +183,9 @@ class GBDT:
                  metrics: Sequence[Metric] = (), mesh=None):
         import jax
         import jax.numpy as jnp
-        from ..ops.grow import DistConfig, GrowParams, build_tree
-        from ..ops.histogram import _pad_bins, multi_width
-        from ..ops.split import SplitParams
+        from ..ops.grow import build_tree
+        from ..ops.histogram import _pad_bins
+        from .tier import TierFacts, plan_tier
 
         self.config = config
         self.train_set = train_set
@@ -377,155 +367,22 @@ class GBDT:
         any_cat = bool(any(m.bin_type == BIN_CATEGORICAL
                            for m in mappers))
         any_missing = bool(any(m.missing_type != 0 for m in mappers))
-        # wave growth composes with ALL parallel learners the way the
-        # reference's GPU learner composes by template parameter
-        # (data_parallel_tree_learner.cpp:258-259, tree_learner.cpp:
-        # 9-33): data psums whole-wave histograms, feature merges
-        # children bests by a batched all-gather arg-max, voting
-        # psums only the elected features' histograms (grow.py)
-        # data2d runs the non-wave loop: its per-axis collective
-        # schedule (row-axis hist psum, feature-axis merge) is defined
-        # on the per-leaf passes, and the wave path's whole-tensor
-        # psum would forfeit the O(1/F_axis) histogram-byte cut
-        wave_on = bool(config.wave_splits and use_pool and not forced
-                       and learner != "data2d")
-        # two-column quantized passes (W=64): legal only when the count
-        # channel is provably redundant (GrowParams.two_col contract).
-        # With missing values the default-direction "any missing data
-        # here?" test reads the hess-copy channel instead of a count —
-        # a row whose quantized hess rounds to 0 is then treated as
-        # absent for direction choice only (both directions tie in
-        # gain in that case; quality is pinned by the NaN-injection
-        # oracle test).  Categorical features still gate it off: their
-        # scans read REAL counts (cnt_ok, min_data_per_group).
-        two_col = bool(
-            config.use_quantized_grad and wave_on and
-            self._bundles is None and not any_cat and
-            config.min_data_in_leaf <= 1 and
-            config.min_sum_hessian_in_leaf > 0)
-        self._counts_proxy = two_col
-        # coarse-to-fine refinement (hist_refinement): wave passes
-        # stream Bc + R one-hot rows instead of the full padded bin
-        # count; exactness caveat documented at GrowParams.refine_shift.
-        # Measured on v5e: every pass carries ~25 ms of fixed cost
-        # (~11 ms bins-matrix HBM read + kernel fixed work), so paying
-        # it twice per wave only wins where the STREAM term dominates
-        # the floor.  Stream ∝ F x padded(B): at 28 x 256 (7168 units,
-        # the 255-bin bench) c2f measured 2x faster; at 28 x 64 it
-        # measured slower (52 vs 45 ms/wave); wide-and-shallow shapes
-        # (e.g. 2000 features x 63 bins = 128k units) are stream-bound
-        # again — hence the stream-size gate rather than a pure
-        # bin-count one.
-        refine_shift = 0
-        if (config.hist_refinement and wave_on and
-                (not dist_active or learner == "data") and
-                self._bundles is None and not any_cat and
-                self.max_bin >= 48 and
-                F * _pad_bins(self.max_bin) >= 7000):
-            # missing values ride a RESERVED last coarse slot (grow.py
-            # Bc_c2f) and a default-left row in the routed lane tables
-            refine_shift = 4 if self.max_bin > 64 else 3
-        # best-split engine (split_kernel=auto|pallas|xla): the Pallas
-        # kernel family scans histograms on-chip (fused epilogue in
-        # the batched passes + the standalone per-(leaf, feature-tile)
-        # kernel), eliminating the histogram→split HBM round-trip.
-        # Numerical serial configs only; every rejection records the
-        # gate (tier telemetry) so a TPU run silently landing on the
-        # XLA scan is triageable (tools/triage_run.py MED anomaly).
-        split_req = str(config.split_kernel).lower() or "auto"
-        if split_req not in ("auto", "pallas", "xla"):
-            # an unrecognized value must NOT silently land on the
-            # interpreter lane (pallas-on-cpu is orders of magnitude
-            # slower than the XLA scan it would replace)
-            Log.warning("unknown split_kernel=%r; using auto",
-                        config.split_kernel)
-            split_req = "auto"
-        split_kernel, split_gate = "xla", None
-        if split_req == "xla":
-            split_gate = "split_kernel=xla"
-        elif any_cat:
-            split_gate = ("categorical scans (one-vs-other / sorted "
-                          "many-vs-many) read the XLA path")
-        elif self._bundles is not None:
-            split_gate = "EFB bundles active (histogram expansion)"
-        elif dist_active:
-            split_gate = f"tree_learner={learner}"
-        elif forced:
-            split_gate = "forced splits"
-        elif refine_shift:
-            split_gate = ("c2f refinement scans coarse+window "
-                          "(hist_refinement)")
-        elif split_req == "auto" and not use_pallas:
-            split_gate = ("cpu backend (split_kernel=pallas or "
-                          "LTPU_PALLAS_INTERPRET=1 runs the "
-                          "interpret lane)")
-        else:
-            # split_req "pallas" on a CPU backend is honored via the
-            # interpret lane (ops/split.py pallas_interpret)
-            split_kernel = "pallas"
-        # the fused split epilogue runs where Pallas is interpreted
-        # only (SPLIT_FUSED_GATE); decided here, once: build_tree and
-        # the tier record both read GrowParams.split_fused
-        from ..utils.env import pallas_interpret
-        split_fused = (split_kernel == "pallas" and wave_on and
-                       use_pallas and pallas_interpret())
-        self.grow_params = GrowParams(
-            split=SplitParams(
-                max_bin=self.max_bin,
-                lambda_l1=config.lambda_l1,
-                lambda_l2=config.lambda_l2,
-                min_data_in_leaf=config.min_data_in_leaf,
-                min_sum_hessian_in_leaf=config.min_sum_hessian_in_leaf,
-                min_gain_to_split=config.min_gain_to_split,
-                max_delta_step=config.max_delta_step,
-                max_cat_to_onehot=config.max_cat_to_onehot,
-                max_cat_threshold=config.max_cat_threshold,
-                cat_l2=config.cat_l2,
-                cat_smooth=config.cat_smooth,
-                min_data_per_group=config.min_data_per_group,
-                monotone=monotone,
-                penalty=penalty,
-                # static dataset facts: trace-time dead-branch removal
-                # in the split scan (no cat -> no bin sorts, no missing
-                # -> one threshold direction)
-                any_cat=any_cat,
-                any_missing=any_missing,
-                counts_proxy=two_col),
-            num_leaves=config.num_leaves,
-            max_depth=config.max_depth,
-            hist_impl="pallas" if use_pallas else "segsum",
-            rows_per_block=rpb,
-            dist=DistConfig(top_k=config.top_k),
-            forced=forced,
-            bundled=self._bundles is not None,
-            use_hist_pool=use_pool,
-            # quantized-gradient histograms: small ints are exact in
-            # bf16, halving the value columns; serial learner, or any
-            # parallel learner under wave growth (shard-consistent
-            # scale via pmax; noise hashed from global row index)
-            quantize=(config.num_grad_quant_bins
-                      if (config.use_quantized_grad and
-                          (not dist_active or wave_on or
-                           learner == "data2d"))
-                      else 0),
-            spec_tolerance=float(config.speculative_tolerance),
-            # wave growth (wave_splits): top-W splits applied per loop
-            # step from one batched pass; rides the speculative kernel
-            wave=wave_on,
-            two_col=two_col,
-            refine_shift=refine_shift,
-            split_kernel=split_kernel,
-            split_fused=split_fused,
-            # speculative child arming fills the MXU lanes (21 leaves x
-            # 6 value columns, 42 x 3 quantized, 64 x 2 two-column);
-            # enabled on the accelerator path where the batched pallas
-            # kernel exists, or anywhere when wave growth asks for it
-            speculate=(min(multi_width(config.use_quantized_grad,
-                                       two_col), config.num_leaves)
-                       if ((use_pallas or config.wave_splits) and
-                           (not dist_active or wave_on) and
-                           use_pool and not forced)
-                       else 0))
+        # the growth tier and its kernels, decided once with their
+        # reasons (models/tier.py): the one GrowParams and the tier
+        # record (utils/telemetry.py: why a run landed on its tier,
+        # readable without a profiler)
+        plan = plan_tier(config, TierFacts(
+            use_pallas=use_pallas, learner=learner, num_shards=num_shards,
+            mesh_shape2d=mesh_shape2d, features=F, g_cols=G_cols,
+            max_bin=self.max_bin, any_cat=any_cat,
+            any_missing=any_missing,
+            efb_groups=(int(self._bundles.num_groups)
+                        if self._bundles is not None else 0),
+            forced=forced, use_pool=use_pool, rows_per_block=rpb,
+            monotone=monotone, penalty=penalty))
+        self.grow_params = plan.grow_params
+        self.tier_decision = plan.record
+        self._counts_proxy = plan.grow_params.two_col
 
         # ---- device-block pager (io/pager.py, docs/Streaming.md
         # "Out-of-core on device"): decide whether the binned matrix
@@ -570,7 +427,7 @@ class GBDT:
             elif gp.wave or gp.speculate > 1:
                 pg_gate = ("wave/speculative growth batches "
                            "multi-leaf passes over the resident matrix")
-            elif split_kernel == "pallas":
+            elif gp.split_kernel == "pallas":
                 pg_gate = "split_kernel=pallas reads resident tiles"
             else:
                 pg_gate = None
@@ -760,17 +617,10 @@ class GBDT:
             objective.init(train_set.metadata, n)
 
         # ---- observability -------------------------------------------
-        # tier/gate decision record: which fast tier every tree of this
-        # booster runs on, and the gate that rejected each higher tier
-        # (utils/telemetry.py; the round-4/5 regressions were all
-        # invisible because this was only derivable from profiler runs)
-        self.tier_decision = self._tier_gates(
-            config, use_pallas=use_pallas, dist_active=dist_active,
-            learner=learner, num_shards=num_shards, wave_on=wave_on,
-            two_col=two_col, refine_shift=refine_shift, any_cat=any_cat,
-            any_missing=any_missing, use_pool=use_pool,
-            forced=bool(forced), G_cols=G_cols,
-            split_kernel=split_kernel, split_gate=split_gate)
+        if self._dist is not None:
+            # the built mesh's shape, which only the builder knows
+            self.tier_decision["mesh_shape"] = [
+                int(s) for s in self._dist.mesh.devices.shape]
         self._collective_per_pass = 0
         self._collective_ops_per_pass = 0
         self._collective_per_axis = {}
@@ -868,158 +718,6 @@ class GBDT:
                 queue.append((node["right"], t + 1))
             t += 1
         return tuple(out)
-
-    # ------------------------------------------------------------------
-    def _tier_gates(self, config, use_pallas, dist_active, learner,
-                    num_shards, wave_on, two_col, refine_shift, any_cat,
-                    any_missing, use_pool, forced, G_cols,
-                    split_kernel="xla", split_gate=None):
-        """The histogram-tier decision for this booster, with the gate
-        that rejected each higher tier.  Mirrors the driver gates above
-        and the routed-kernel feasibility in ``ops/grow.py`` — the
-        telemetry contract is that a reader can tell WHY a run landed
-        on a slower tier without rerunning it under a profiler."""
-        from ..ops.histogram import routed_chunk_ok
-        gates = {}
-        quantize = int(self.grow_params.quantize)
-        speculate = int(self.grow_params.speculate)
-        if not two_col:
-            if not config.use_quantized_grad:
-                gates["two_col"] = "use_quantized_grad=false"
-            elif not wave_on:
-                gates["two_col"] = "wave growth off"
-            elif self._bundles is not None:
-                gates["two_col"] = ("EFB bundles active "
-                                    "(FixHistogram reads counts)")
-            elif any_cat:
-                gates["two_col"] = ("categorical scans read real counts "
-                                    "(cnt_ok, min_data_per_group)")
-            elif config.min_data_in_leaf > 1:
-                gates["two_col"] = "min_data_in_leaf > 1 needs counts"
-            else:
-                gates["two_col"] = "min_sum_hessian_in_leaf <= 0"
-        if not wave_on:
-            if not config.wave_splits:
-                gates["wave"] = "wave_splits=false"
-            elif learner == "data2d":
-                gates["wave"] = ("data2d runs the non-wave per-axis "
-                                 "collective schedule")
-            elif not use_pool:
-                gates["wave"] = ("histogram pool over budget "
-                                 "(histogram_pool_size)")
-            else:
-                gates["wave"] = "forced splits"
-        if refine_shift == 0:
-            if not config.hist_refinement:
-                gates["c2f"] = "hist_refinement=false"
-            elif not wave_on:
-                gates["c2f"] = "wave growth off"
-            elif dist_active and learner != "data":
-                gates["c2f"] = f"tree_learner={learner}"
-            elif self._bundles is not None:
-                gates["c2f"] = "EFB bundles active"
-            elif any_cat:
-                gates["c2f"] = "categorical features"
-            elif self.max_bin < 48:
-                gates["c2f"] = f"max_bin={self.max_bin} < 48"
-            else:
-                gates["c2f"] = ("stream below the per-pass fixed-cost "
-                                "break-even (features x bins < ~7000)")
-        # routed-kernel feasibility (ops/grow.py routed_full_ok /
-        # routed_coarse_ok — the in-pass routing tier)
-        if not use_pallas:
-            gates["routed"] = "cpu backend (segsum histograms)"
-        elif self._bundles is not None:
-            gates["routed"] = "EFB bundles active"
-        elif any_cat:
-            gates["routed"] = "categorical splits need bin masks"
-        elif learner == "feature":
-            gates["routed"] = ("feature-parallel: split column lives "
-                               "on one shard")
-        routed = "routed" not in gates and routed_chunk_ok(
-            self.max_bin, G_cols, 128,
-            int(config.tpu_rows_per_block))
-        if "routed" not in gates and not routed:
-            gates["routed"] = "feature block exceeds one kernel chunk"
-        # best-split engine gate (split_kernel): why a run scans splits
-        # in XLA instead of the fused/standalone Pallas kernels —
-        # triage_run.py flags the silent-fallback-on-TPU case
-        if split_kernel != "pallas" and split_gate:
-            gates["split"] = split_gate
-        if (split_kernel == "pallas" and wave_on and use_pallas and
-                not self.grow_params.split_fused):
-            gates["split_fused"] = SPLIT_FUSED_GATE
-        # a parallel learner asked for on one device trains serial (the
-        # driver warns); the record carries both so a smoke can assert
-        requested = config.tree_learner or "serial"
-        if requested != "serial" and not dist_active:
-            gates["learner"] = (f"tree_learner={requested} needs more "
-                                f"than one device; found {num_shards}")
-        # how each kind of histogram pass the booster runs tiles the
-        # stored bin matrix (ops/histogram.py BinTiling): worked out
-        # here, once, from the tiler — ``xt_copied`` says whether a
-        # pass copies the matrix in HBM before its kernel starts.
-        # Mirrors build_tree: c2f runs a coarse and a windowed refine
-        # pass (the root too); otherwise the batched full-resolution
-        # pass, and off the wave path the single-leaf pass ("root":
-        # the root and every leaf no batched pass armed)
-        hist_tiling = {}
-        if use_pallas:
-            from ..ops.grow import c2f_bins
-            from ..ops.histogram import bin_tiling
-            rpb = int(config.tpu_rows_per_block)
-            f_local = G_cols
-            if dist_active and learner in ("feature", "data2d"):
-                f_local = G_cols // (self._mesh_shape2d[1] if
-                                     self._mesh_shape2d else num_shards)
-            passes = {}
-            if refine_shift:
-                coarse, window = c2f_bins(self.max_bin, refine_shift,
-                                          any_missing)
-                passes["coarse"] = (coarse, 128)
-                passes["refine"] = (window, 128)
-            elif min(speculate, config.num_leaves) > 1:
-                passes["full"] = (self.max_bin, 128)
-            if not wave_on:
-                passes["root"] = (self.max_bin, 3 if quantize else 6)
-            # the batched passes contract in int8 where their values
-            # are int8; the single-leaf pass ("root") takes float32
-            hist_tiling = {
-                kind: bin_tiling(bins, f_local, cols, rpb).record(
-                    int8=self.grow_params.int8_values and kind != "root")
-                for kind, (bins, cols) in passes.items()}
-        if two_col:
-            tier = "two_col"
-        elif wave_on:
-            tier = "wave_quant" if quantize else "wave"
-        elif speculate:
-            tier = "speculative"
-        else:
-            tier = "exact"
-        return {
-            "tier": tier,
-            "gates": gates,
-            "split_kernel": split_kernel,
-            "split_fused": bool(self.grow_params.split_fused),
-            "routed": bool(routed),
-            "c2f": bool(refine_shift),
-            "refine_shift": int(refine_shift),
-            "quantize": quantize,
-            "speculate": speculate,
-            "wave": bool(wave_on),
-            "hist_impl": self.grow_params.hist_impl,
-            "hist_tiling": hist_tiling,
-            "use_hist_pool": bool(use_pool),
-            "efb_groups": (int(self._bundles.num_groups)
-                           if self._bundles is not None else 0),
-            "learner": learner if dist_active else "serial",
-            "learner_requested": requested,
-            "num_shards": int(num_shards) if dist_active else 1,
-            "mesh_shape": ([int(s) for s in
-                            self._dist.mesh.devices.shape]
-                           if dist_active and self._dist is not None
-                           else [1]),
-        }
 
     # ------------------------------------------------------------------
     def attach_telemetry(self, target):

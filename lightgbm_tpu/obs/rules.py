@@ -651,9 +651,8 @@ class OnlineScanner:
                     "split_kernel=xla" not in reason:
                 out.append(("MED", f"split kernel fell back to XLA on a "
                                    f"{backend} backend: {reason} — the "
-                                   f"fused histogram→split pass is "
-                                   f"disabled, every grow level "
-                                   f"round-trips the full histogram "
-                                   f"through HBM"))
+                                   f"on-chip split scan is off, every "
+                                   f"grow level scans its histograms "
+                                   f"in XLA"))
                 break
         return out

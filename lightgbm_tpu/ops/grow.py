@@ -63,7 +63,7 @@ from ..io.pager import PagedXt
 from .split import (NEG_INF, SplitParams, choose_window,
                     eval_forced_split, find_best_split,
                     find_best_split_c2f, find_best_split_pallas,
-                    leaf_output, split_lane_scalars)
+                    leaf_output)
 
 __all__ = ["DistConfig", "GrowParams", "build_tree", "build_tree_impl",
            "collective_bytes_per_pass", "GROW_COUNTERS"]
@@ -159,7 +159,7 @@ class GrowParams:
     # direction test reads the missing bin's count, and a hess copy
     # can quantize to zero there).  Real per-leaf counts are restored
     # on the host from the full-precision renewal stats.  Requires
-    # quantize>0 and the wave path; the driver gates all of this.
+    # quantize>0 and the wave path; the plan gates all of this.
     two_col: bool = False
     # >0: coarse-to-fine histogram refinement on the wave path.  Each
     # wave runs one COARSE pass (fine bins collapsed 2^refine_shift-
@@ -169,8 +169,8 @@ class GrowParams:
     # — then 1-2 WINDOWED passes resolving only the 2 coarse bins
     # straddling each (child, feature)'s best coarse boundary at fine
     # resolution (~0.21x the MXU stream of a full 255-bin pass; the
-    # driver only enables it where the stream saving beats the extra
-    # per-pass fixed cost — see models/gbdt.py).  The fine-resolution
+    # plan only enables it where the stream saving beats the extra
+    # per-pass fixed cost — see models/tier.py).  The fine-resolution
     # pool is dropped.  Split choice is exact whenever the best fine
     # threshold lies in the chosen window (see ops/split.py).
     # Missing values ARE supported: the per-feature missing bin maps
@@ -178,28 +178,13 @@ class GrowParams:
     # scanned.  Requires the wave path, numerical (non-categorical)
     # features, no bundling.
     refine_shift: int = 0
-    # store the batched-pass value operand as int8 — quantized
-    # gradients are small ints (|v| <= quantize <= 127), exact in
-    # int8/bf16, and the (3, N) operand is re-read from HBM every
-    # pass: 1 byte/entry instead of 4 (pallas + quantize only; the
-    # float hi/lo path needs f32)
-    vals_i8: bool = True
     # best-split engine: "xla" = the vectorized jnp scans in
-    # ops/split.py (every tier); "pallas" = the on-chip kernel family
-    # (find_best_split_pallas and, with split_fused, the histogram→split
-    # epilogue in the batched passes) — numerical features, serial
-    # learner, no EFB/forced/c2f; the DRIVER gates this (models/gbdt.py
-    # records the gate that rejected it), build_tree only falls back
-    # silently for the sub-paths the kernel cannot serve
+    # ops/split.py (every tier); "pallas" = the on-chip kernel
+    # (find_best_split_pallas, every child) — numerical features,
+    # serial learner, no EFB/forced/c2f; the PLAN gates this
+    # (models/tier.py records the gate that rejected it), build_tree
+    # only falls back silently for the sub-paths the kernel cannot serve
     split_kernel: str = "xla"
-    # the batched wave passes also scan their own accumulated tile in
-    # VMEM for the smaller children (the fused histogram→split
-    # epilogue); off, every child goes through the standalone kernel.
-    # Needs split_kernel=pallas, wave growth and hist_impl=pallas.
-    # The DRIVER decides (models/gbdt.py): Mosaic refuses the epilogue,
-    # so it is off wherever Pallas is compiled and the tier record
-    # carries gates.split_fused
-    split_fused: bool = False
     # >0: relative gain tolerance for preferring an already-ARMED leaf
     # over a fresh unarmed one when their best gains are within
     # tol*|best|.  Late boosting iterations have near-flat gains and
@@ -212,12 +197,59 @@ class GrowParams:
 
     @property
     def int8_values(self) -> bool:
-        """The batched passes take their values as int8 (``vals_i8``),
+        """The batched passes take their values as int8 — quantized
+        gradients are small ints (|v| <= quantize <= 127), exact in
+        int8, and the (3, N) operand is re-read from HBM every pass at
+        1 byte an entry instead of 4 (the float hi/lo path needs f32) —
         and the kernels then contract in int8 on the MXU
         (ops/histogram.py ``_accumulate``): what the tier record's
         ``mxu`` says."""
-        return (self.vals_i8 and self.hist_impl == "pallas" and
-                0 < self.quantize <= 127)
+        return self.hist_impl == "pallas" and 0 < self.quantize <= 127
+
+
+def batched_width(params: GrowParams, kind: str) -> int:
+    """Lanes of the batched (speculative or wave) histogram pass under
+    learner ``kind``; 0 where the growth loop runs single-leaf passes
+    only.  Serial always batches; a parallel learner under wave growth."""
+    p = params
+    wave_par = p.wave and kind in ("data", "feature", "voting")
+    if (kind == "serial" or wave_par) and p.use_hist_pool \
+            and not p.forced and p.speculate > 1:
+        return min(p.speculate, p.num_leaves)
+    return 0
+
+
+def routed_gate(params: GrowParams, kind: str, max_bin: int,
+                g_cols: int) -> Optional[str]:
+    """Why the batched pass at ``max_bin`` bins (the coarse count under
+    c2f) over ``g_cols`` stored columns cannot route its rows inside
+    the kernel (ops/histogram.py routed kernels), or None where it
+    does.  The one statement of routed feasibility: ``build_tree_impl``
+    branches on it and the tier record (models/tier.py) reports it.
+
+    The wave's row-routing select chain re-reads leaf_idx + every xt
+    row from HBM; when every feature fits one kernel chunk and splits
+    are plain threshold compares, the pass itself resolves
+    lanes/goes-left and emits the new leaf vector.  Feature-parallel
+    is excluded: the lane's split column lives on one shard only, so
+    goes-left needs a cross-shard psum the kernel cannot do.  Missing
+    values ARE supported: the lane tables carry a default-left row and
+    the kernel resolves the per-row missing bin by a feature
+    contraction."""
+    p = params
+    if p.hist_impl != "pallas":
+        return "cpu backend (segsum histograms)"
+    if p.bundled:
+        return "EFB bundles active"
+    if p.split.any_cat:
+        return "categorical splits need bin masks"
+    if kind == "feature":
+        return "feature-parallel: split column lives on one shard"
+    if not routed_chunk_ok(max_bin, g_cols, 128, p.rows_per_block):
+        return "feature block exceeds one kernel chunk"
+    if batched_width(p, kind) <= 1:
+        return "no batched pass (single-leaf passes route nothing)"
+    return None
 
 
 def collective_bytes_per_pass(params: GrowParams, num_features: int,
@@ -602,9 +634,7 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
     # with up to `speculate` smaller-child histograms (serial always;
     # parallel learners under wave growth)
     wave_par = wave_dist or wave_feat or wave_vote
-    W_spec = min(p.speculate, L) if (
-        (kind == "serial" or wave_par) and p.use_hist_pool
-        and not p.forced and p.speculate > 1) else 0
+    W_spec = batched_width(p, kind)
     do_spec = W_spec > 1
     use_wave = p.wave and do_spec and (kind == "serial" or wave_par) \
         and not p.forced
@@ -616,8 +646,8 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         assert kind in ("serial", "data"), \
             "coarse-to-fine runs under the serial/data learners only"
     # Pallas best-split tier (GrowParams.split_kernel): the numerical
-    # scan runs as the on-chip kernel family instead of the XLA scan.
-    # The driver (models/gbdt.py) gates eligibility and records why a
+    # scan runs as the on-chip kernel instead of the XLA scan.
+    # The plan (models/tier.py) gates eligibility and records why a
     # config fell back; the asserts here are the backstop for direct
     # build_tree users.
     paged = isinstance(xt, PagedXt)
@@ -638,16 +668,6 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
             and not p.forced and not use_c2f, \
             "split_kernel=pallas: serial learner, numerical features, " \
             "no EFB/forced splits/c2f refinement (driver-gated)"
-    # fused histogram→split epilogue: the batched pass scans its own
-    # accumulated tile in VMEM for the smaller children (the larger,
-    # subtraction-trick children go through the standalone kernel on
-    # the pool histogram).  Driver-gated (GrowParams.split_fused).
-    use_split_fused = p.split_fused
-    if use_split_fused:
-        assert use_split_pallas and use_wave and \
-            p.hist_impl == "pallas", \
-            "split_fused: split_kernel=pallas, wave growth and " \
-            "hist_impl=pallas (driver-gated)"
     if do_spec:
         base_vals = jnp.stack([grad * sample_mask, hess * sample_mask,
                                sample_mask], axis=-1)
@@ -670,56 +690,25 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
                 return h
             return h if hist_scale is None else h * hist_scale
 
-        def multi_hist(sel, split_args=None):
+        def multi_hist(sel):
             if p.hist_impl == "pallas":
-                if split_args is not None:
-                    # fused histogram→split epilogue: the pass scans
-                    # its own accumulated tile in VMEM (serial only —
-                    # gated with use_split_fused)
-                    h, srec = histogram_pallas_multi(
-                        xt, kvals, sel, B, W_spec, p.rows_per_block,
-                        exact=p.quantize > 0, two_col=p.two_col,
-                        split_params=sp, split_args=split_args)
-                    return _wave_hist_finish(h), srec
                 h = histogram_pallas_multi(xt, kvals, sel, B, W_spec,
                                            p.rows_per_block,
                                            exact=p.quantize > 0,
                                            two_col=p.two_col)
             else:
-                assert split_args is None
                 h = histogram_segsum_multi(xt, base_vals, sel, B, W_spec,
                                            two_col=p.two_col)
             return _wave_hist_finish(h)
-    # in-kernel routing (ops/histogram.py routed kernels): the wave's
-    # row-routing select chain re-reads leaf_idx + every xt row from
-    # HBM (~13 ms/wave at bench shape); when every feature fits one
-    # kernel chunk and splits are plain threshold compares, the pass
-    # itself resolves lanes/goes-left and emits the new leaf vector
-    # (feature-parallel excluded: the lane's split column lives on one
-    # shard only, so goes-left needs a cross-shard psum the kernel
-    # cannot do.  Missing values ARE supported: the lane tables carry
-    # a default-left row and the kernel resolves the per-row missing
-    # bin by a feature contraction)
-    routed_ok = (do_spec and p.hist_impl == "pallas" and
-                 not p.bundled and not sp.any_cat and
-                 kind != "feature")
-    routed_full_ok = routed_ok and routed_chunk_ok(
-        B, G_cols, 128, p.rows_per_block)
+    # in-kernel routing of the batched full-resolution pass
+    # (:func:`routed_gate`; the coarse pass of c2f asks again below)
+    routed_full_ok = routed_gate(p, kind, B, G_cols) is None
     # leaf vector in uint8 when every pass goes through the routed
     # kernel and ids fit (dummy id L included): it is re-read per pass
     # and per score-update, 4x less HBM than int32
     li_narrow = L <= 255
 
-    def routed_call(li, tbl, max_bin_r, shift_r, mode,
-                    split_args=None):
-        if split_args is not None:
-            # route + histogram + best-split scan in ONE kernel
-            hist, li_new, sel, srec = histogram_pallas_multi_routed(
-                xt, kvals, li, tbl, max_bin_r, W_spec,
-                p.rows_per_block, exact=p.quantize > 0,
-                two_col=p.two_col, shift=shift_r, mode=mode,
-                miss_bin=mb_l, split_params=sp, split_args=split_args)
-            return _wave_hist_finish(hist), li_new, sel, srec
+    def routed_call(li, tbl, max_bin_r, shift_r, mode):
         hist, li_new, sel = histogram_pallas_multi_routed(
             xt, kvals, li, tbl, max_bin_r, W_spec,
             p.rows_per_block, exact=p.quantize > 0, two_col=p.two_col,
@@ -738,8 +727,7 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
     if use_c2f:
         c2f_shift = p.refine_shift
         Bc_c2f, R_c2f = c2f_bins(B, c2f_shift, sp.any_missing)
-        routed_coarse_ok = routed_ok and routed_chunk_ok(
-            Bc_c2f, G_cols, 128, p.rows_per_block)
+        routed_coarse_ok = routed_gate(p, kind, Bc_c2f, G_cols) is None
 
         def multi_hist_coarse(sel):
             if p.hist_impl == "pallas":
@@ -1493,9 +1481,6 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
         rstat_w = pstat_w - lstat_w
         small_left_w = lstat_w[:, 2] <= rstat_w[:, 2]
 
-        # depth/bounds hoisted above the pass: the fused epilogue's
-        # per-lane scalars (child stats + monotone bounds) must exist
-        # BEFORE the histogram kernel is launched
         depth_w = st["leaf_depth"][ids] + 1
         if has_mono:
             l_min, l_max, r_min, r_max = child_bounds(
@@ -1503,36 +1488,14 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
                 st["leaf_max"][ids], feat_w, cat_w)
             ch_mn = jnp.concatenate([l_min, r_min])
             ch_mx = jnp.concatenate([l_max, r_max])
-        sargs = None
-        if use_split_fused:
-            small_stats = jnp.where(small_left_w[:, None], lstat_w,
-                                    rstat_w)
-            if has_mono:
-                small_mn = jnp.where(small_left_w, l_min, r_min)
-                small_mx = jnp.where(small_left_w, l_max, r_max)
-            else:
-                small_mn = small_mx = None
-            lane_scal = split_lane_scalars(small_stats, sp, small_mn,
-                                           small_mx)
-            scale3 = hist_scale if hist_scale is not None \
-                else jnp.ones(3, jnp.float32)
-            sargs = (lane_scal, scale3, nb_l, mt_l, fmask_l, mono_l,
-                     pen_l)
 
         li = st["leaf_idx"]
-        bests_small = None
         if routed_full_ok:
             # routing resolved inside the pass itself; the kernel
-            # also emits the updated leaf vector (and, fused, the
-            # smaller children's best splits)
+            # also emits the updated leaf vector
             tbl = lane_tables(ids_leaf, feat_w, thr_w, new_ids,
                               small_left_w, dl_w)
-            if sargs is not None:
-                hist_small, leaf_idx, _, bests_small = routed_call(
-                    li, tbl, B, 0, "small", split_args=sargs)
-            else:
-                hist_small, leaf_idx, _ = routed_call(li, tbl, B, 0,
-                                                      "small")
+            hist_small, leaf_idx, _ = routed_call(li, tbl, B, 0, "small")
         else:
             # route every in-wave row through ITS leaf's split
             if p.bundled:
@@ -1547,10 +1510,7 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
                            extras=(small_left_w, new_ids))
             to_small = goes_left == small_left_row
             sel = jnp.where(in_wave & to_small, w_row, jnp.int32(-1))
-            if sargs is not None:
-                hist_small, bests_small = multi_hist(sel, sargs)
-            else:
-                hist_small = multi_hist(sel)        # (W, F_hist, B, 3)
+            hist_small = multi_hist(sel)            # (W, F_hist, B, 3)
             leaf_idx = jnp.where(in_wave & ~goes_left, new_id_row, li)
 
         hist_parent = st["hist"][ids]
@@ -1561,38 +1521,11 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
 
         ch_stats = jnp.concatenate([lstat_w, rstat_w], axis=0)
         ch_depth = jnp.concatenate([depth_w, depth_w])
-        if bests_small is not None:
-            # fused path: the smaller children's scans already ran in
-            # the histogram kernel; only the subtraction-trick larger
-            # children go through the standalone kernel, then the two
-            # halves stitch back into [left(W), right(W)] lane order
-            large_stats = jnp.where(small_left_w[:, None], rstat_w,
-                                    lstat_w)
-            if has_mono:
-                large_mn = jnp.where(small_left_w, r_min, l_min)
-                large_mx = jnp.where(small_left_w, r_max, l_max)
-            else:
-                large_mn = large_mx = None
-            bests_large = find_best_split_pallas(
-                hist_large, large_stats, nb_l, mt_l, fmask_l, sp,
-                monotone=mono_l, penalty=pen_l, min_output=large_mn,
-                max_output=large_mx)
-            bests = {}
-            for k in ("gain", "feature", "threshold", "default_left",
-                      "is_cat", "left_mask", "left_stats"):
-                sm, lg = bests_small[k], bests_large[k]
-                cnd = small_left_w.reshape((W,) + (1,) * (sm.ndim - 1))
-                bests[k] = jnp.concatenate(
-                    [jnp.where(cnd, sm, lg), jnp.where(cnd, lg, sm)],
-                    axis=0)
-            ch_hist = jnp.concatenate([hist_l, hist_r], axis=0)
-        else:
-            # children best splits: ONE batched scan over all 2W
-            # children
-            ch_hist = jnp.concatenate([hist_l, hist_r], axis=0)
-            bests = children_bests(ch_hist, ch_stats,
-                                   ch_mn if has_mono else None,
-                                   ch_mx if has_mono else None)
+        # children best splits: ONE batched scan over all 2W children
+        ch_hist = jnp.concatenate([hist_l, hist_r], axis=0)
+        bests = children_bests(ch_hist, ch_stats,
+                               ch_mn if has_mono else None,
+                               ch_mx if has_mono else None)
         allowed = (p.max_depth <= 0) | (ch_depth < p.max_depth)
         bests["gain"] = jnp.where(allowed, bests["gain"], NEG_INF)
         # materialization fence: without it XLA fuses the vmapped scan's

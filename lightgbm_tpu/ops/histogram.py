@@ -57,8 +57,6 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.env import pallas_interpret
-from .split import (_PART_LANES, finish_split_partials,
-                    split_epilogue_rows, split_scan_descriptors)
 
 __all__ = ["histogram", "histogram_segsum", "histogram_segsum_into",
            "histogram_pallas",
@@ -502,9 +500,7 @@ def histogram(bins_t: jax.Array, vals: jax.Array, max_bin: int,
 def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
                        width: int, exact: bool, two_col: bool = False,
                        shift: int = 0, miss_idx: int = -1,
-                       split_params=None, split_has_mono: bool = False,
-                       split_has_pen: bool = False,
-                       split_has_bounds: bool = False, f_mask: int = 0):
+                       f_mask: int = 0):
     """Multi-leaf variant: one pass accumulates histograms for up to
     ``width`` row-disjoint subsets (the speculative child-arming pass).
 
@@ -519,27 +515,11 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
     dimension (126/128 at width 21×6 or 42×3, 128/128 at 64×2) that the
     single-leaf pass leaves ~95% idle — a batched pass costs barely
     more than a single-leaf one.
-
-    With ``split_params`` the FUSED BEST-SPLIT EPILOGUE is armed: the
-    last row-tile grid step scans the fully-accumulated out_ref tile
-    (still VMEM-resident) through the numerical split search
-    (ops/split.py) and writes per-(lane, feature-chunk) partial rows
-    to an extra output — the histogram→split HBM round-trip the
-    two-pass path pays is gone.  Extra refs ride between the base
-    inputs and out_ref: nb/mt/fm [mono] [pen] descriptors (FC, 1),
-    lane scalars (W, 8), dequantization scale (1, 8).
     """
     import jax.experimental.pallas as pl
 
-    fused_split = split_params is not None
-    rest = list(rest)
-    mb_ref = rest.pop(0) if miss_idx >= 0 else None
-    if fused_split:
-        nb_ref, mt_ref, fm_ref = rest[:3]
-        rest = rest[3:]
-        mono_ref = rest.pop(0) if split_has_mono else None
-        pen_ref = rest.pop(0) if split_has_pen else None
-        lane_ref, sc_ref, out_ref, part_ref = rest
+    if miss_idx >= 0:
+        mb_ref, out_ref = rest
     else:
         (out_ref,) = rest
 
@@ -573,32 +553,16 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
         rhs = _rhs_from(sel_oh, valsc)                 # (128, T) bf16
     _accumulate(out_ref, x, rhs, b_pad, f_mask, pl.program_id(0) * FC)
 
-    if fused_split:
-        # row tiles are the minor grid dim, so the LAST step holds the
-        # complete accumulated histogram for this feature chunk
-        @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
-        def _split_epilogue():
-            part_ref[...] = split_epilogue_rows(
-                out_ref[...], lane_ref[...], nb_ref[...], mt_ref[...],
-                fm_ref[...],
-                mono_ref[...] if split_has_mono else None,
-                pen_ref[...] if split_has_pen else None,
-                sc_ref[...], width=width, exact=exact,
-                two_col=two_col, b_pad=b_pad, params=split_params,
-                has_bounds=split_has_bounds)[None]
-
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "width",
                                              "rows_per_block", "exact",
-                                             "two_col", "shift",
-                                             "split_params"))
+                                             "two_col", "shift"))
 def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
                            sel: jax.Array, max_bin: int, width: int,
                            rows_per_block: int = 1024,
                            exact: bool = False,
                            two_col: bool = False,
-                           shift: int = 0, miss_bin=None,
-                           split_params=None, split_args=None):
+                           shift: int = 0, miss_bin=None):
     """Batched histogram over ``width`` disjoint row subsets.
 
     bins_t (F, N) ints; vals (N, 3) f32; sel (N,) int32 subset id per
@@ -612,21 +576,10 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
     then the COARSE bin count.  ``miss_bin`` (F,) int32 (with shift):
     rows at their feature's missing bin map to the reserved last
     coarse slot instead (see the segsum reference).
-
-    With ``split_params`` (a static SplitParams) the FUSED BEST-SPLIT
-    EPILOGUE runs per (lane, feature chunk) on the last row tile —
-    the histogram tile is consumed in VMEM, never re-read from HBM
-    for the scan.  ``split_args`` = (lane_scalars (W, 8), scale (3,),
-    num_bins (F,), missing_type (F,), feature_mask (F,), monotone
-    (F,) or None, penalty (F,) or None); the return value becomes
-    ``(hist, split_record)`` with the per-lane record pieces of
-    ops/split.py's ``finish_split_partials``.  Full-resolution
-    numerical passes only (shift == 0, no miss_bin).
     """
     import jax.experimental.pallas as pl
 
     f, n = bins_t.shape
-    fused_split = split_params is not None
     b_pad = _pad_bins(max_bin)
     cols = 2 if two_col else (3 if exact else 6)
     W = width
@@ -636,9 +589,8 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
     assert n % t == 0, (n, t)
     # narrow value operand: quantized gradients are small ints, exact
     # in int8/bf16 — keep the (3, N) operand at 1 byte/entry (it is
-    # re-read from HBM EVERY pass; f32 costs ~4.8 ms/pass at bench
-    # shape on a ~26 GB/s chip, int8 ~1.2 ms).  Only the exact/two_col
-    # kernels may take it (the hi/lo float split needs f32).
+    # re-read from HBM EVERY pass).  Only the exact/two_col kernels
+    # may take it (the hi/lo float split needs f32).
     if vals.dtype == jnp.int8:
         assert exact or two_col, "int8 values need exact/two_col"
         vt = vals.T                          # (3, N) int8
@@ -658,55 +610,17 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
         in_specs.append(pl.BlockSpec((til.block_rows, 1),
                                      lambda j, i: (j, 0)))
         operands.append(_miss_operand(miss_bin, til))
-    split_has_mono = split_has_pen = False
-    if fused_split:
-        assert shift == 0 and miss_bin is None, \
-            "fused split epilogue needs a full-resolution pass"
-        lane, scale3, s_nb, s_mt, s_fm, s_mono, s_pen = split_args
-        split_has_mono = s_mono is not None
-        split_has_pen = s_pen is not None
-        nb_p, mt_p, fm_p, mono_p, pen_p = split_scan_descriptors(
-            s_nb, s_mt, s_fm, s_mono, s_pen, f_pad)
-        dspec = pl.BlockSpec((fc, 1), lambda j, i: (j, 0))
-        in_specs += [dspec, dspec, dspec]
-        operands += [nb_p, mt_p, fm_p]
-        if split_has_mono:
-            in_specs.append(dspec)
-            operands.append(mono_p)
-        if split_has_pen:
-            in_specs.append(dspec)
-            operands.append(pen_p)
-        in_specs += [pl.BlockSpec((W, 8), lambda j, i: (0, 0)),
-                     pl.BlockSpec((1, 8), lambda j, i: (0, 0))]
-        operands += [jnp.asarray(lane, jnp.float32),
-                     jnp.pad(jnp.asarray(scale3, jnp.float32)[None, :],
-                             ((0, 0), (0, 5)))]
-    out_specs = pl.BlockSpec((fc * b_pad, 128), lambda j, i: (j, 0))
-    out_shape = jax.ShapeDtypeStruct((f_pad * b_pad, 128), jnp.float32)
-    if fused_split:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, W, _PART_LANES),
-                                  lambda j, i: (j, 0, 0))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((f_pad // fc, W, _PART_LANES),
-                                          jnp.float32)]
-    res = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_hist_kernel_multi, b_pad=b_pad, width=W,
                           exact=exact, two_col=two_col, shift=shift,
-                          miss_idx=miss_idx, split_params=split_params,
-                          split_has_mono=split_has_mono,
-                          split_has_pen=split_has_pen,
-                          split_has_bounds=fused_split and
-                          split_params.has_monotone,
-                          f_mask=til.f_mask),
+                          miss_idx=miss_idx, f_mask=til.f_mask),
         grid=(f_pad // fc, n // t),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((fc * b_pad, 128), lambda j, i: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((f_pad * b_pad, 128), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
-    out, part = res if fused_split else (res, None)
     out = out[:, :cols * W].reshape(f_pad, b_pad, W, cols)
     if two_col:
         # count := hess copy keeps every downstream shape at (..., 3);
@@ -714,12 +628,7 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
         out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
     elif not exact:
         out = out[..., :3] + out[..., 3:]    # hi + lo
-    hist = jnp.moveaxis(out[:f, :max_bin], 2, 0)   # (W, F, B, 3)
-    if fused_split:
-        rec = finish_split_partials(jnp.moveaxis(part, 0, 1), fc,
-                                    s_nb, s_mt, split_params, max_bin)
-        return hist, rec
-    return hist
+    return jnp.moveaxis(out[:f, :max_bin], 2, 0)   # (W, F, B, 3)
 
 
 def histogram_segsum_multi(bins_t: jax.Array, vals: jax.Array,
@@ -883,10 +792,9 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
 # ---- routed multi-leaf pass ----------------------------------------
 #
 # The wave bodies used to route rows in XLA-land: an unrolled
-# select-chain reading leaf_idx plus EVERY xt row (~340 MB per wave at
-# bench shape — ~13 ms of pure HBM re-read on a ~26 GB/s chip).  The
-# histogram pass already streams the bins matrix, so this variant does
-# the routing IN the kernel: per row it resolves its wave lane (a
+# select-chain reading leaf_idx plus EVERY xt row from HBM, once more
+# a wave.  The histogram pass already streams the bins matrix, so this
+# variant does the routing IN the kernel: per row it resolves its wave lane (a
 # table compare against the lane leaf-ids), its split column value (a
 # feature-one-hot contraction over the resident x tile), the
 # goes-left compare, and the subset selector — and writes the NEW leaf
@@ -974,28 +882,12 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
                               b_pad: int, width: int, exact: bool,
                               two_col: bool, shift: int, mode: str,
                               miss_idx: int = -1,
-                              with_miss: bool = False,
-                              split_params=None,
-                              split_has_mono: bool = False,
-                              split_has_pen: bool = False,
-                              split_has_bounds: bool = False):
+                              with_miss: bool = False):
     import jax.experimental.pallas as pl
 
-    fused_split = split_params is not None
     rest = list(rest)
     mb_ref = rest.pop(0) if with_miss else None
-    if fused_split:
-        # fused best-split epilogue refs (same layout as
-        # _hist_kernel_multi): descriptors, lane scalars, scale
-        nb_ref, mt_ref, fm_ref = rest[:3]
-        rest = rest[3:]
-        mono_ref = rest.pop(0) if split_has_mono else None
-        pen_ref = rest.pop(0) if split_has_pen else None
-        lane_ref, sc_ref = rest[:2]
-        rest = rest[2:]
-        out_ref, li_out_ref, sel_out_ref, part_ref = rest
-    else:
-        out_ref, li_out_ref, sel_out_ref = rest
+    out_ref, li_out_ref, sel_out_ref = rest
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -1031,18 +923,6 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
         xb = x
     _accumulate(out_ref, xb, rhs, b_pad)    # one chunk: nothing to mask
 
-    if fused_split:
-        @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
-        def _split_epilogue():
-            part_ref[...] = split_epilogue_rows(
-                out_ref[...], lane_ref[...], nb_ref[...], mt_ref[...],
-                fm_ref[...],
-                mono_ref[...] if split_has_mono else None,
-                pen_ref[...] if split_has_pen else None,
-                sc_ref[...], width=width, exact=exact,
-                two_col=two_col, b_pad=b_pad, params=split_params,
-                has_bounds=split_has_bounds)[None]
-
 
 def routed_chunk_ok(max_bin: int, f: int, cols: int = 128,
                     rows_per_block: int = 1024) -> bool:
@@ -1053,7 +933,7 @@ def routed_chunk_ok(max_bin: int, f: int, cols: int = 128,
 
 @functools.partial(jax.jit, static_argnames=(
     "max_bin", "width", "rows_per_block", "exact", "two_col", "shift",
-    "mode", "split_params"))
+    "mode"))
 def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
                                   leaf_idx: jax.Array,
                                   tables: jax.Array, max_bin: int,
@@ -1063,8 +943,7 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
                                   two_col: bool = False,
                                   shift: int = 0,
                                   mode: str = "small",
-                                  miss_bin=None,
-                                  split_params=None, split_args=None):
+                                  miss_bin=None):
     """Multi-subset histogram with IN-KERNEL row routing.
 
     bins_t (F, N); vals (N, 3) f32; leaf_idx (N,) int32; tables
@@ -1076,11 +955,6 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     missing bin route by the default direction, and with ``shift``
     they land in the reserved last coarse slot.
     Returns (hist (width, F, B, 3), new_leaf_idx (N,), sel (N,)).
-
-    ``split_params``/``split_args`` arm the fused best-split epilogue
-    (see :func:`histogram_pallas_multi`): route + histogram + scan in
-    ONE kernel, returning ``(hist, new_leaf_idx, sel, split_record)``.
-    Full-resolution ``mode="small"`` passes only.
     """
     import jax.experimental.pallas as pl
 
@@ -1119,30 +993,6 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
         in_specs.append(pl.BlockSpec((til.block_rows, 1),
                                      lambda i: (0, 0)))
         operands.append(_miss_operand(miss_bin, til))
-    fused_split = split_params is not None
-    split_has_mono = split_has_pen = False
-    if fused_split:
-        assert shift == 0 and mode == "small", \
-            "fused split epilogue: full-resolution smaller-child pass"
-        lane, scale3, s_nb, s_mt, s_fm, s_mono, s_pen = split_args
-        split_has_mono = s_mono is not None
-        split_has_pen = s_pen is not None
-        nb_p, mt_p, fm_p, mono_p, pen_p = split_scan_descriptors(
-            s_nb, s_mt, s_fm, s_mono, s_pen, f_pad)
-        dspec = pl.BlockSpec((fc, 1), lambda i: (0, 0))
-        in_specs += [dspec, dspec, dspec]
-        operands += [nb_p, mt_p, fm_p]
-        if split_has_mono:
-            in_specs.append(dspec)
-            operands.append(mono_p)
-        if split_has_pen:
-            in_specs.append(dspec)
-            operands.append(pen_p)
-        in_specs += [pl.BlockSpec((Wl, 8), lambda i: (0, 0)),
-                     pl.BlockSpec((1, 8), lambda i: (0, 0))]
-        operands += [jnp.asarray(lane, jnp.float32),
-                     jnp.pad(jnp.asarray(scale3, jnp.float32)[None, :],
-                             ((0, 0), (0, 5)))]
     out_specs = [
         pl.BlockSpec((fc * b_pad, 128), lambda i: (0, 0)),
         pl.BlockSpec((1, t), lambda i: (0, i)),
@@ -1153,21 +1003,11 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
         jax.ShapeDtypeStruct((1, n), leaf_idx.dtype),
         jax.ShapeDtypeStruct((1, n), jnp.int32),
     ]
-    if fused_split:
-        out_specs.append(pl.BlockSpec((1, Wl, _PART_LANES),
-                                      lambda i: (0, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((1, Wl, _PART_LANES),
-                                              jnp.float32))
-    res = pl.pallas_call(
+    out, li_new, sel = pl.pallas_call(
         functools.partial(_hist_kernel_multi_routed, b_pad=b_pad,
                           width=Wl, exact=exact, two_col=two_col,
                           shift=shift, mode=mode, miss_idx=miss_idx,
-                          with_miss=miss_bin is not None,
-                          split_params=split_params,
-                          split_has_mono=split_has_mono,
-                          split_has_pen=split_has_pen,
-                          split_has_bounds=fused_split and
-                          split_params.has_monotone),
+                          with_miss=miss_bin is not None),
         grid=(n // t,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1175,20 +1015,12 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
-    if fused_split:
-        out, li_new, sel, part = res
-    else:
-        out, li_new, sel = res
     out = out[:, :cols * Wl].reshape(f_pad, b_pad, Wl, cols)
     if two_col:
         out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
     elif not exact:
         out = out[..., :3] + out[..., 3:]
     hist = jnp.moveaxis(out[:f, :max_bin], 2, 0)
-    if fused_split:
-        rec = finish_split_partials(jnp.moveaxis(part, 0, 1), fc,
-                                    s_nb, s_mt, split_params, max_bin)
-        return hist, li_new[0], sel[0], rec
     return hist, li_new[0], sel[0]
 
 

@@ -21,6 +21,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .histogram import _compiler_params
+
 EPS = 1e-15
 NEG_INF = -1e30
 
@@ -364,8 +366,8 @@ def find_best_split(hist: jax.Array, parent: jax.Array,
 # the best fine threshold falls inside the chosen window; the window
 # heuristic (2 coarse bins straddling the best coarse boundary) is
 # validated empirically in tests/test_c2f.py and by the bench AUC
-# anchor.  Numerical (non-categorical) features only — the driver
-# gates it (models/gbdt.py).  Missing values are supported: the
+# anchor.  Numerical (non-categorical) features only — the plan
+# gates it (models/tier.py).  Missing values are supported: the
 # per-feature missing bin rides a RESERVED last coarse slot
 # (:func:`_c2f_miss`) and both default directions are scanned.
 
@@ -550,31 +552,24 @@ def find_best_split_c2f(coarse: jax.Array, win: jax.Array,
     }
 
 
-# ---- Pallas best-split kernel family --------------------------------
+# ---- Pallas best-split kernel ---------------------------------------
 #
 # The XLA split scan above reads the full (leaves x F x B x 3)
 # histogram back from HBM after the histogram pass wrote it — a pure
 # producer/consumer round-trip (the same memory-bound pairing the GPU
 # boosting systems fuse, arXiv:1706.08359 §4, arXiv:1806.11248 §3).
-# This kernel family runs the NUMERICAL threshold scan on-chip:
+# ``find_best_split_pallas`` runs the NUMERICAL threshold scan on-chip:
+# a standalone per-(leaf, feature-tile) kernel over an
+# already-materialized histogram (every child of a wave, the root, the
+# exact/speculative tiers): grid (leaf-lane, feature-tile), each step
+# cumsums its (FC, B) tile in VMEM, evaluates both default directions
+# + constraints, and reduces to ONE 16-lane partial row; a tiny
+# second-stage argmax over tiles (XLA, O(tiles) work) picks the global
+# winner.
 #
-# - ``find_best_split_pallas``: a standalone per-(leaf, feature-tile)
-#   kernel over an already-materialized histogram (the subtraction-
-#   trick children, the root, the exact/speculative tiers): grid
-#   (leaf-lane, feature-tile), each step cumsums its (FC, B) tile in
-#   VMEM, evaluates both default directions + constraints, and
-#   reduces to ONE 16-lane partial row; a tiny second-stage argmax
-#   over tiles (XLA, O(tiles) work) picks the global winner.
-# - ``split_epilogue_rows``: the FUSED form — called by
-#   ``histogram_pallas_multi``/``_routed`` on their LAST row-tile grid
-#   step, consuming the accumulated histogram tile while it is still
-#   VMEM-resident (dequantization + hi/lo fold + two_col count proxy
-#   applied in-kernel), so the smaller-child scan never re-reads the
-#   histogram from HBM at all.
-#
-# Parity contract: numerical features only (the driver gates
+# Parity contract: numerical features only (the plan gates
 # categorical/EFB/c2f/forced to the XLA scan and records why —
-# models/gbdt.py tier gates); on identical inputs the same (feature,
+# models/tier.py); on identical inputs the same (feature,
 # bin, default_left) choice as :func:`find_best_split` with first-max
 # tie order (lowest bin within a feature, lowest feature globally),
 # and gains within 1e-4 relative (measured worst 5.9e-5).  Never
@@ -588,13 +583,6 @@ def find_best_split_c2f(coarse: jax.Array, win: jax.Array,
 # segsum + XLA twin) under Mosaic.
 
 _PART_LANES = 16  # partial-row width: [gain, f_loc, j, dir, Lg, Lh, Lc, pad]
-
-
-def _split_compiler_params():
-    """Same scoped-VMEM raise as ops/histogram.py (the two modules
-    cannot share it without an import cycle)."""
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
 def _prefix_sum(x):
@@ -729,7 +717,7 @@ def _tile_best(gain, dirl, Lg, Lh, Lc):
 
 def split_lane_scalars(parent, params: SplitParams, min_output=None,
                        max_output=None) -> jax.Array:
-    """(W, 8) f32 per-lane scalar operand for the split-scan kernels:
+    """(W, 8) f32 per-lane scalar operand for the split-scan kernel:
     [parent_g, parent_h, parent_c, gain_shift, min_out, max_out, 0, 0].
     Neutral ±inf bounds reproduce the unconstrained XLA scan exactly
     (clip against ±inf is the identity on the finite leaf outputs)."""
@@ -770,61 +758,11 @@ def split_scan_descriptors(num_bins, missing_type, feature_mask,
     return nb, mt, fm, mono, pen
 
 
-def split_epilogue_rows(acc, lane, nb, mt, fm, mono, pen, scale, *,
-                        width: int, exact: bool, two_col: bool,
-                        b_pad: int, params: SplitParams,
-                        has_bounds: bool = False) -> jax.Array:
-    """Fused best-split epilogue over one accumulated multi-pass tile.
-
-    Called INSIDE ``histogram_pallas_multi``/``_routed`` on the last
-    row-tile grid step: ``acc`` is the (FC*b_pad, 128) raw-unit
-    accumulator, fully accumulated and still VMEM-resident.  The lane
-    extraction (column slice + hi/lo fold + two_col count proxy) and
-    the dequantization (``scale`` (1, 8) = [sg, sh, sc, ...]; ones on
-    the float path) replicate the XLA post-processing bit-for-bit, so
-    the scan sees exactly the values :func:`find_best_split` would
-    have read back from HBM.  ``lane`` is (W, 8) per-lane scalars
-    (:func:`split_lane_scalars` of the CHILD each lane measures);
-    descriptors are (FC, 1).  Returns (W, 16) partial rows in the
-    :func:`_tile_best` layout.
-    """
-    W = width
-    cols = 2 if two_col else (3 if exact else 6)
-    FC = acc.shape[0] // b_pad
-    a = acc[:, :cols * W].reshape(FC, b_pad, W, cols)
-    a = jnp.moveaxis(a, 2, 0)                    # (W, FC, Bp, cols)
-    if two_col:
-        g_r, h_r = a[..., 0], a[..., 1]
-        c_r = h_r                                # count := hess copy
-    elif not exact:
-        s = a[..., :3] + a[..., 3:]              # hi + lo passes
-        g_r, h_r, c_r = s[..., 0], s[..., 1], s[..., 2]
-    else:
-        g_r, h_r, c_r = a[..., 0], a[..., 1], a[..., 2]
-    sg = scale[:, 0:1][..., None]                # (1, 1, 1)
-    sh = scale[:, 1:2][..., None]
-    sc = scale[:, 2:3][..., None]
-    g, h, c = g_r * sg, h_r * sh, c_r * sc
-    pg = lane[:, 0:1][..., None]                 # (W, 1, 1)
-    ph = lane[:, 1:2][..., None]
-    pc = lane[:, 2:3][..., None]
-    gs = lane[:, 3:4][..., None]
-    mn = lane[:, 4:5][..., None] if has_bounds else None
-    mx = lane[:, 5:6][..., None] if has_bounds else None
-    gain, dirl, Lg, Lh, Lc = _scan_tile(
-        g, h, c, nb[None], mt[None], fm[None] > 0,
-        mono[None] if mono is not None else None,
-        pen[None].astype(jnp.float32) if pen is not None else None,
-        pg, ph, pc, gs, mn, mx, params)
-    row, _ = _tile_best(gain, dirl, Lg, Lh, Lc)  # (W, 16)
-    return row
-
-
 def finish_split_partials(part, fc: int, num_bins, missing_type,
                           params: SplitParams, max_bin: int):
     """Global stage of the two-stage reduction: (W, T, 16) per-tile
     partial rows -> per-lane split records.  O(W*T) XLA work —
-    the only part of the fused path that is not in-kernel.  First-max
+    the only part of the scan that is not in-kernel.  First-max
     over tiles preserves the feature-major tie order (tiles are
     contiguous feature ranges)."""
     p = params
@@ -993,7 +931,7 @@ def find_best_split_pallas(hist: jax.Array, parent: jax.Array,
         in_specs=in_specs,
         out_specs=out_specs if with_per_feature_gain else out_specs[0],
         out_shape=out_shape if with_per_feature_gain else out_shape[0],
-        compiler_params=_split_compiler_params(),
+        compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
 

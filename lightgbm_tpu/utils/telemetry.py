@@ -98,7 +98,7 @@ _TYPE_FIELDS: Dict[str, Tuple[Tuple[str, Any], ...]] = {
     # device compute overlapped host work); triage_run.py flags
     # depth > 0 with ~zero overlap as pipelining silently disabled.
     # ``split_kernel`` records the best-split engine that ran inside
-    # the block (pallas = the fused histogram→split kernel tier, xla
+    # the block (pallas = the on-chip split-scan kernel, xla
     # = the vectorized scans) and ``split_fallback`` the tier gate
     # that rejected the kernel tier when it did; triage_run.py flags
     # an XLA fallback on a TPU backend as MED.

@@ -1,7 +1,6 @@
-"""split_kernel=pallas parity: the Pallas best-split kernel family.
+"""split_kernel=pallas parity: the Pallas best-split kernel.
 
-The kernel family (``ops/split.py``: ``find_best_split_pallas`` +
-the fused epilogue in ``ops/histogram.py``'s batched passes) must
+The kernel (``ops/split.py``: ``find_best_split_pallas``) must
 select the SAME splits as the XLA scan ``find_best_split`` — same
 (feature, bin, default_left) under first-max tie order, same
 left_mask — with gains within ``GAIN_RTOL``.  The kernel takes its
@@ -31,8 +30,7 @@ import jax.numpy as jnp
 import pytest
 
 from lightgbm_tpu.ops.split import (SplitParams, find_best_split,
-                                    find_best_split_pallas,
-                                    split_lane_scalars)
+                                    find_best_split_pallas)
 
 # written tolerances (the choice itself is always exact): see the
 # module docstring for where the drift comes from and what was measured
@@ -192,86 +190,14 @@ def test_kernel_batched_lanes():
     assert np.isfinite(np.asarray(batch["left_stats"])).all()
 
 
-# ---- fused epilogue (histogram kernels) -----------------------------
+# ---- build_tree wave parity (standalone kernel for every child) ----
 
-@pytest.mark.parametrize("routed", [False, True])
-def test_fused_epilogue_matches_scan(routed):
-    """The epilogue rows written by the batched histogram kernels
-    match find_best_split over the SAME pass's histogram output."""
-    from lightgbm_tpu.ops.histogram import (histogram_pallas_multi,
-                                            histogram_pallas_multi_routed)
-    rng = np.random.RandomState(5)
-    F, N, W, B = 6, 2048, 4, 16
-    nb = np.full(F, B, np.int32)
-    mt = np.full(F, 2, np.int32)
-    bins = rng.randint(0, B - 1, size=(F, N)).astype(np.uint8)
-    bins[rng.random_sample((F, N)) < 0.08] = B - 1
-    vals = np.stack([rng.randn(N), np.abs(rng.randn(N)) + 0.1,
-                     np.ones(N)], -1).astype(np.float32)
-    sp = SplitParams(max_bin=B, min_data_in_leaf=5, any_cat=False,
-                     any_missing=True)
-    fm = jnp.ones(F, bool)
-    if routed:
-        li = rng.randint(0, 8, size=N).astype(np.int32)
-        ids = np.arange(W, dtype=np.int32)
-        tbl = np.stack([ids,
-                        rng.randint(0, F, size=W).astype(np.int32),
-                        rng.randint(0, B - 2, size=W).astype(np.int32),
-                        np.arange(8, 8 + W, dtype=np.int32),
-                        rng.randint(0, 2, size=W).astype(np.int32),
-                        rng.randint(0, 2, size=W).astype(np.int32)])
-        # parents from the oracle-routed subsets
-        from lightgbm_tpu.ops.histogram import \
-            histogram_segsum_multi_routed
-        h_ref, _, _ = histogram_segsum_multi_routed(
-            jnp.asarray(bins.astype(np.int32)), jnp.asarray(vals),
-            jnp.asarray(li), jnp.asarray(tbl), B, W,
-            miss_bin=jnp.asarray(nb - 1))
-        parents = np.asarray(h_ref).sum(axis=2)[:, 0, :]
-        lane = split_lane_scalars(jnp.asarray(parents), sp)
-        sargs = (lane, jnp.ones(3, jnp.float32), jnp.asarray(nb),
-                 jnp.asarray(mt), fm, None, None)
-        hist, _, _, rec = histogram_pallas_multi_routed(
-            jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(li),
-            jnp.asarray(tbl), B, W, rows_per_block=1024,
-            miss_bin=jnp.asarray(nb - 1), split_params=sp,
-            split_args=sargs)
-    else:
-        sel = rng.randint(-1, W, size=N).astype(np.int32)
-        parents = np.zeros((W, 3), np.float32)
-        for w in range(W):
-            m = sel == w
-            parents[w] = [vals[m, 0].sum(), vals[m, 1].sum(), m.sum()]
-        lane = split_lane_scalars(jnp.asarray(parents), sp)
-        sargs = (lane, jnp.ones(3, jnp.float32), jnp.asarray(nb),
-                 jnp.asarray(mt), fm, None, None)
-        hist, rec = histogram_pallas_multi(
-            jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(sel), B,
-            W, rows_per_block=1024, split_params=sp, split_args=sargs)
-    for w in range(W):
-        a = find_best_split(hist[w], jnp.asarray(parents[w]),
-                            jnp.asarray(nb), jnp.asarray(mt),
-                            jnp.zeros(F, bool), fm, sp)
-        one = {k: v[w] for k, v in rec.items()}
-        _assert_same_record(a, one, f"routed={routed} lane{w}")
-
-
-# ---- build_tree wave parity (fused epilogue + standalone kernel) ----
-
-@pytest.mark.parametrize("hist_impl,split_fused", [
-    ("segsum", False),      # standalone kernel for every child
-    ("pallas", False),      # the same on the pallas hist tier: what
-                            # the chip runs (gates.split_fused)
-    ("pallas", True),       # fused epilogue for the smaller children:
-                            # the interpret lane only
-], ids=["segsum", "pallas", "pallas-fused"])
+@pytest.mark.parametrize("hist_impl", ["segsum", "pallas"])
 @pytest.mark.parametrize("with_missing", [False, True])
-def test_build_tree_wave_parity(hist_impl, split_fused, with_missing):
-    """Wave growth with split_kernel=pallas (with split_fused the
-    epilogue for the smaller children + the standalone kernel for the
-    subtraction-trick children; without it the standalone kernel for
-    all children) grows the same tree as the XLA scan, leaf values
-    within LEAF_RTOL."""
+def test_build_tree_wave_parity(hist_impl, with_missing):
+    """Wave growth with split_kernel=pallas (the standalone kernel for
+    all children, on either histogram tier) grows the same tree as the
+    XLA scan, leaf values within LEAF_RTOL."""
     from lightgbm_tpu.ops.grow import GrowParams, build_tree
     rng = np.random.RandomState(1)
     N, F = 2048, 6
@@ -292,8 +218,7 @@ def test_build_tree_wave_parity(hist_impl, split_fused, with_missing):
     for sk in ("xla", "pallas"):
         p = GrowParams(split=sp, num_leaves=15, hist_impl=hist_impl,
                        rows_per_block=1024, wave=True, speculate=8,
-                       split_kernel=sk,
-                       split_fused=split_fused and sk == "pallas")
+                       split_kernel=sk)
         recs[sk] = {k: np.asarray(v) for k, v in
                     build_tree(*args, p).items()}
     a, b = recs["xla"], recs["pallas"]
@@ -501,9 +426,8 @@ def test_triage_flags_tpu_fallback():
      "min_sum_hessian_in_leaf": 1e-3},
 ], ids=["quantized", "two_col"])
 def test_interpret_lane_quantized_tiers(monkeypatch, tier_params):
-    """The fused epilogue's exact (cols=3) and two-column (cols=2,
-    count := hess copy) lane extraction + in-kernel dequantization
-    match the XLA scan on the same quantized histograms."""
+    """On the exact (cols=3) and two-column (cols=2, count := hess
+    copy) quantized histograms the kernel matches the XLA scan."""
     import lightgbm_tpu as lgb
     monkeypatch.setenv("LTPU_PALLAS_INTERPRET", "1")
     rng = np.random.RandomState(1)
@@ -529,7 +453,7 @@ def test_interpret_lane_quantized_tiers(monkeypatch, tier_params):
 @pytest.mark.slow
 def test_interpret_lane_e2e(monkeypatch):
     """LTPU_PALLAS_INTERPRET=1: the whole kernel tier (pallas
-    histograms + routed passes + fused split epilogue) runs
+    histograms + routed passes + the split kernel) runs
     interpreted on CPU, and split_kernel=pallas stays structurally
     identical to xla under the SAME histogram tier."""
     import lightgbm_tpu as lgb

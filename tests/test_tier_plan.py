@@ -1,0 +1,126 @@
+"""The growth-tier plan (``models/tier.py``) on plain values: no
+``Booster``, no data set, no device.
+
+``tier_plan_golden.json`` is the parent commit's answer (PR 29, before
+the plan existed) for a table of configurations: the facts
+``GBDT.__init__`` had worked out, ``dataclasses.asdict(grow_params)``
+and ``tier_decision``, dumped from real boosters — the ``@tpu`` rows
+with ``jax.default_backend`` patched for the constructor alone, the
+``@interpret`` rows under ``LTPU_PALLAS_INTERPRET=1`` — less the
+``split_fused`` and ``vals_i8`` fields that went with their code.  The
+plan must give the same, but for the ``routed`` corrections named
+below: there the record now says what ``build_tree`` does.
+"""
+import dataclasses
+import glob
+import json
+import os
+import sys
+
+import pytest
+
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.models.tier import TierFacts, plan_tier
+from lightgbm_tpu.ops.grow import c2f_bins, routed_gate
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.join(os.path.dirname(HERE), "benchmark")
+with open(os.path.join(HERE, "tier_plan_golden.json")) as _f:
+    GOLDEN = json.load(_f)
+
+NO_BATCHED_PASS = "no batched pass (single-leaf passes route nothing)"
+# where the parent's record and build_tree disagreed; build_tree wins.
+# The record asked neither for a batched pass (it said ``routed: true``
+# on tiers whose single-leaf passes route nothing) nor, under c2f,
+# about the coarse pass that is the one routed (it tested the
+# full-resolution bin count, which no c2f pass streams).
+ROUTED_CORRECTIONS = {
+    "fast28.forced@tpu": NO_BATCHED_PASS,
+    "fast28.pool_over@tpu": NO_BATCHED_PASS,
+    "fast28.data2d4@tpu": NO_BATCHED_PASS,
+    "defaults28.data4@tpu": NO_BATCHED_PASS,
+    "fast120.max_bin63@tpu": None,
+    "fast2000.max_bin63@tpu": None,
+}
+
+
+def _tuples(x):
+    return tuple(_tuples(v) for v in x) if isinstance(x, list) else x
+
+
+def _facts(row) -> TierFacts:
+    return TierFacts(**{k: _tuples(v) for k, v in row["facts"].items()})
+
+
+def _plain(x):
+    """A deep copy with tuples as lists, as the fixture's JSON holds
+    them."""
+    return json.loads(json.dumps(x))
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN))
+def test_plan_matches_parent(case):
+    row = GOLDEN[case]
+    plan = plan_tier(Config(dict(row["params"], verbose=-1)), _facts(row))
+    assert _plain(dataclasses.asdict(plan.grow_params)) == \
+        row["grow_params"]
+    want = _plain(row["record"])
+    if case in ROUTED_CORRECTIONS:
+        why = ROUTED_CORRECTIONS[case]
+        want["routed"] = why is None
+        want["gates"].pop("routed", None)
+        if why is not None:
+            want["gates"]["routed"] = why
+    assert _plain(plan.record) == want
+
+
+def test_corrections_are_cases():
+    assert set(ROUTED_CORRECTIONS) <= set(GOLDEN)
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN))
+def test_record_routed_is_build_trees(case):
+    """The record's ``routed`` and ``gates.routed`` are what
+    ``build_tree_impl`` asks of its own predicate for the plan's
+    ``GrowParams``: the coarse pass under c2f, else the full one."""
+    facts = _facts(GOLDEN[case])
+    gp, record = plan_tier(
+        Config(dict(GOLDEN[case]["params"], verbose=-1)), facts)
+    bins = facts.max_bin
+    if gp.wave and gp.refine_shift:
+        bins = c2f_bins(bins, gp.refine_shift, gp.split.any_missing)[0]
+    why = routed_gate(gp, record["learner"], bins, facts.local_cols)
+    assert record["routed"] == (why is None)
+    assert record["gates"].get("routed") == why
+
+
+def _cells():
+    return sorted(os.path.basename(p)[:-len(".json")]
+                  for p in glob.glob(os.path.join(BENCH, "workloads",
+                                                  "*.json")))
+
+
+@pytest.mark.parametrize("cell_name", _cells())
+def test_workload_expect_tier(cell_name):
+    """What ``benchmark/run.py`` ``check_tier`` holds every cell to on
+    the TPU, said here without one: the plan for the cell's parameters
+    with Pallas on and the configuration's feature count contains the
+    workload file's ``expect_tier``."""
+    if BENCH not in sys.path:       # as tests/benchmark/ imports it
+        sys.path.insert(0, BENCH)
+    from harness.cells import load_cell
+    cell = load_cell(cell_name)
+    config = Config(dict(cell.params))
+    features = int(cell.config["features"])
+    # 255 bins a feature at the cells' rows: the device width is the
+    # next power of two
+    max_bin = 1 << (int(config.max_bin) - 1).bit_length()
+    record = plan_tier(config, TierFacts(
+        use_pallas=True, learner="serial", num_shards=1,
+        mesh_shape2d=None, features=features, g_cols=features,
+        max_bin=max_bin, any_cat=False, any_missing=False, efb_groups=0,
+        forced=(), use_pool=True,
+        rows_per_block=int(config.tpu_rows_per_block), monotone=(),
+        penalty=())).record
+    expect = cell.workload["expect_tier"]
+    assert {k: record[k] for k in expect} == expect
