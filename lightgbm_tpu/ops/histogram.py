@@ -46,6 +46,12 @@ Value columns:
   (``_accumulate``, ``_onehot_int8``, ``_rhs_int8``).  The operand's
   dtype alone decides: float32 values keep the bf16 path, and the
   tier record's ``mxu`` says which a booster runs.
+- the per-row prologue, what a tile does before its contraction: each
+  row's subset is ONE (1, T) index (the callers' selector, or the
+  lane the kernel looks up with every lane table in one contraction:
+  ``_lane_lookup``), and the rhs is made from it (``_rhs``): int8
+  values a 32-bit word at a time, float32 ones row by row as bf16
+  (the tier record's ``prologue``).
 """
 from __future__ import annotations
 
@@ -209,12 +215,17 @@ class BinTiling(NamedTuple):
         ``xt_copied``: whether the pass copies the matrix in HBM before
         its kernel starts.  No shape does: Mosaic takes both blocks
         above (on the chip: tools/check_routed_kernels.py).  ``mxu``:
-        the type the pass contracts in (:func:`_accumulate`); the
-        caller says whether the pass is given int8 values (ops/grow.py
+        the type the pass contracts in (:func:`_accumulate`);
+        ``prologue``: how a tile makes its right-hand side, ``words``
+        from each row's subset index a 32-bit word at a time
+        (:func:`_rhs_int8`), ``rows`` from it row by row
+        (:func:`_rhs_bf16`).  Both follow the values: the caller says
+        whether the pass is given int8 ones (ops/grow.py
         ``GrowParams.int8_values``)."""
         return {"f": self.f, "f_pad": self.f_pad, "fc": self.fc,
                 "t": self.t, "xt_copied": False,
-                "mxu": "int8" if int8 else "bf16"}
+                "mxu": "int8" if int8 else "bf16",
+                "prologue": "words" if int8 else "rows"}
 
 
 def bin_tiling(max_bin: int, f: int, cols: int = 128,
@@ -231,14 +242,6 @@ def _miss_operand(miss_bin: jax.Array, til: BinTiling) -> jax.Array:
     (-1: no missing bin)."""
     return jnp.pad(miss_bin.astype(jnp.int32), (0, til.rows - til.f),
                    constant_values=-1)[:, None]
-
-
-def _win_lo_operand(win_lo: jax.Array, til: BinTiling) -> jax.Array:
-    """(W, F) window starts -> the (til.rows, W) kernel operand: W on
-    the lane axis is always a full dimension, F on it is not a legal
-    block whenever features chunk."""
-    return jnp.pad(win_lo.astype(jnp.int32).T,
-                   ((0, til.rows - til.f), (0, 0)))
 
 
 def _compiler_params():
@@ -267,49 +270,80 @@ def _rhs_cols(width: int, cols: int) -> int:
     return 128 if need <= 128 else 256
 
 
-def _rhs_from(sel_oh: jax.Array, valsc: jax.Array) -> jax.Array:
-    """(W, T) subset selector x (C, T) values -> (128 or 256, T) bf16
-    rhs.
+def _rhs(lane: jax.Array, valsc: jax.Array, width: int) -> jax.Array:
+    """The rhs of a batched pass's contraction (:func:`_accumulate`):
+    (128 or 256, T), row ``k`` is ``valsc[k % C]`` where the row's
+    subset is ``k // C`` and 0 elsewhere.  lane (1, T) int32: each
+    row's subset in ``[-1, width)`` (-1: none); valsc (C, T).  The
+    values' type decides the form: int8 by words (:func:`_rhs_int8`),
+    float32 row by row as bf16 (:func:`_rhs_bf16`)."""
+    if valsc.dtype == jnp.int8:
+        return _rhs_int8(lane, valsc, width)
+    return _rhs_bf16(lane, valsc, width)
 
-    Built IN bf16, halving the stage's register traffic vs an f32
-    multiply followed by a cast.  Numerically identical to the old
-    f32-multiply-then-cast: 0/1 selectors and quantized ints are
-    bf16-exact, and for the float path the hi part is bf16-exact by
-    construction while the lo residual was ALREADY rounded to bf16 by
-    the final cast (the hi/lo split reaches ~2^-16 RELATIVE accuracy,
-    not exactness — see the module header)."""
-    W, T = sel_oh.shape
+
+def _rhs_bf16(lane: jax.Array, valsc: jax.Array, width: int
+              ) -> jax.Array:
+    """The bf16 rhs of float32 values, C up to 6 (the hi/lo split:
+    the hi part is bf16-exact by construction and the lo residual is
+    rounded to bf16 here, which reaches ~2^-16 RELATIVE accuracy, not
+    exactness — see the module header).
+
+    Built row by row in two dimensions from the subset index: rhs row
+    ``k`` compares the index with ``k // C`` and selects value row
+    ``k % C``.  A (W, T) selector times the values, regrouped
+    (W, C, T) -> (W * C, T), gives the same values and was what a
+    float32-valued pass spent most of its time on: 41.7 against 18.0
+    ms a routed pass of 21M x 28, 43.3 against 13.7 unrouted
+    (PERF.md, PR 31)."""
     C = valsc.shape[0]
-    rhs = (sel_oh.astype(jnp.bfloat16)[:, None, :] *
-           valsc.astype(jnp.bfloat16)[None, :, :]).reshape(W * C, T)
-    return jnp.pad(rhs, ((0, _rhs_cols(W, C) - W * C), (0, 0)))
-
-
-def _rhs_int8(on: jax.Array, valsc: jax.Array) -> jax.Array:
-    """The rhs of the int8 contraction (:func:`_accumulate`): row
-    ``k`` is ``on[k] ? valsc[k % C] : 0``, the quantized integers as
-    they are.  on (128 or 256, T) bool: the rows of the subset that
-    rhs row ``k`` belongs to (:func:`_rhs_row_lane`); valsc (C, T)
-    int8.  Built row by row in two dimensions: the (W, C, T) ->
-    (W * C, T) regrouping of :func:`_rhs_from` is what Mosaic takes
-    longest to compile in a pass (46 s of an int32 one at T = 16384,
-    against 1.4 s of this)."""
-    lanes = on.shape[0]
-    C = valsc.shape[0]
-    c = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0), C)
-    v = valsc.astype(jnp.int32)
-    row = v[0:1]
+    n = _rhs_cols(width, C)
+    k = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    row_lane = jnp.where(k < width * C, jax.lax.div(k, C), -2)
+    c = jax.lax.rem(k, C)
+    row = valsc[0:1]
     for i in range(1, C):
-        row = jnp.where(c == i, v[i:i + 1], row)       # (lanes, T)
-    return jnp.where(on, row, 0).astype(jnp.int8)
+        row = jnp.where(c == i, valsc[i:i + 1], row)   # (n, T)
+    return jnp.where(lane == row_lane, row, 0.0).astype(jnp.bfloat16)
 
 
-def _rhs_row_lane(width: int, cols: int) -> jax.Array:
-    """(128 or 256, 1) int32: the subset that rhs row ``k`` belongs to,
-    ``k // cols``; -2, which no selector holds, beyond ``width *
-    cols``."""
-    k = jax.lax.broadcasted_iota(jnp.int32, (_rhs_cols(width, cols), 1), 0)
-    return jnp.where(k < width * cols, jax.lax.div(k, cols), -2)
+def _rhs_int8(lane: jax.Array, valsc: jax.Array, width: int
+              ) -> jax.Array:
+    """The int8 rhs (:func:`_rhs`), the quantized integers as they
+    are; valsc (C, T) int8, C 2 or 3.
+
+    Made a 32-bit WORD at a time, as :func:`_onehot_int8` makes the
+    one-hot: int8 rows ``4j .. 4j + 3`` are the bytes of int32 row
+    ``j`` (``pltpu.bitcast``).  A row belongs to one subset at most,
+    so its C values are C adjacent bytes of the column, put together
+    once a tile at (1, T).  Two columns: subset ``L``'s 16 bits are
+    the half ``L & 1`` of word ``L >> 1``.  Three: its 24 bits start
+    at byte ``3L`` and lie in word ``3L >> 2`` and, from byte 2 or 3
+    on, in the next.  A compare and a select (or two) on (32, T)
+    words, where the row-by-row form was a compare, C selects and two
+    narrowing packs on (128, T) elements.  Two-dimensional throughout:
+    a (W, C, T) -> (W * C, T) regrouping is what Mosaic takes longest
+    to compile in a pass (46 s of an int32 one at T = 16384; PERF.md,
+    PR 29)."""
+    from jax.experimental.pallas import tpu as pltpu
+    C, T = valsc.shape
+    assert C in (2, 3), C
+    v = valsc.astype(jnp.int32) & 0xFF                 # the bytes
+    j = jax.lax.broadcasted_iota(
+        jnp.int32, (_rhs_cols(width, C) // 4, T), 0)
+    if C == 2:
+        gh = v[0:1] | (v[1:2] << 8)
+        words = jnp.where(j == (lane >> 1), gh << ((lane & 1) << 4), 0)
+    else:
+        ghc = v[0:1] | (v[1:2] << 8) | (v[2:3] << 16)
+        at = lane * 3                                  # first byte
+        j0, s = at >> 2, (at & 3) << 3
+        # a dead row (-1) starts at byte 1 of word -1: no word holds
+        # it, and its spill into word 0 shifts out to 0
+        words = jnp.where(j == j0, ghc << s,
+                          jnp.where(j == j0 + 1, (ghc >> 8) >> (24 - s),
+                                    0))
+    return pltpu.bitcast(words, jnp.int8)
 
 
 def _onehot_int8(xb: jax.Array, b_pad: int) -> jax.Array:
@@ -527,7 +561,7 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    FC, T = x_ref.shape
+    FC = x_ref.shape[0]
     x = x_ref[...].astype(jnp.int32)
     if shift:
         # coarse pass: bins collapsed 2^shift-to-1 on the fly — the
@@ -540,18 +574,11 @@ def _hist_kernel_multi(x_ref, v_ref, s_ref, *rest, b_pad: int,
     v = v_ref[...]                      # (3, T)
     sel = s_ref[...]                    # (1, T)
     if two_col:
-        cols = 2
         valsc = v[:2]                   # grad, hess only
     else:
-        cols = 3 if exact else 6
         valsc = v if exact else _split_hi_lo(v)        # (cols, T) f32
-    if v.dtype == jnp.int8:
-        rhs = _rhs_int8(sel == _rhs_row_lane(width, cols), valsc)
-    else:
-        sel_oh = (sel == jax.lax.broadcasted_iota(
-            jnp.int32, (width, T), 0)).astype(jnp.bfloat16)  # (W, T)
-        rhs = _rhs_from(sel_oh, valsc)                 # (128, T) bf16
-    _accumulate(out_ref, x, rhs, b_pad, f_mask, pl.program_id(0) * FC)
+    _accumulate(out_ref, x, _rhs(sel, valsc, width), b_pad, f_mask,
+                pl.program_id(0) * FC)
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "width",
@@ -658,6 +685,72 @@ def histogram_segsum_multi(bins_t: jax.Array, vals: jax.Array,
     return out
 
 
+# ---- a row's lane, and what its lane's tables hold ------------------
+#
+# The routed and the lane-routed kernels are handed the leaf vector and
+# per-lane tables (the lane's leaf id, split feature, threshold, ...,
+# window starts); the windowed kernel a ready selector and the window
+# starts.  A tile resolves each row's lane ONCE: the (W, T) one-hot of
+# the leaf ids against the lanes' (of the selector against 0 .. W - 1)
+# is contracted with ONE stacked table, and the result's column ``t``
+# is the table column of the lane row ``t`` is in (zeros where it is
+# in none).  Row 0 holds ``lane + 1``, so the lane index, -1 for none,
+# comes out of the same contraction and everything after it (the
+# routing's selects, the right-hand side: :func:`_rhs`) derives from
+# that (1, T) index.  An (N,)-element gather of any of it is poison
+# (60-90 ms at bench shape).
+
+_LANE_HEAD = 8      # scalar rows ahead of a table's per-feature rows
+
+
+def _lane_operands(lane_ids: jax.Array, scalars, per_feat: jax.Array,
+                   til: BinTiling):
+    """The two operands :func:`_lane_lookup` takes.
+
+    lane_ids (W,) leaf id of each lane; scalars: up to 7 (W,) int
+    rows, which ride as rows 1.. of the head behind ``lane + 1``;
+    per_feat (F, W): the rows a feature block needs (its features'
+    one-hot of the lane's split feature; its window starts).  Returns
+    ids (Wp, 1) int32, lanes padded with -2, which neither a leaf id
+    nor a selector is, and the table, (chunks * (8 + rows), Wp)
+    float32: every feature block finds the head above its own rows
+    (blocks of ``8 + rows``)."""
+    W = lane_ids.shape[0]
+    wp = -W % 16
+    rows = til.fc if not til.one_chunk else -(-til.f // 8) * 8
+    chunks = til.f_pad // til.fc
+    head = jnp.stack([jnp.arange(1, W + 1, dtype=jnp.int32)] +
+                     [r.astype(jnp.int32) for r in scalars])
+    head = jnp.pad(head, ((0, _LANE_HEAD - head.shape[0]), (0, 0)))
+    feat = jnp.pad(per_feat.astype(jnp.int32),
+                   ((0, chunks * rows - per_feat.shape[0]), (0, 0)))
+    tab = jnp.concatenate(
+        [jnp.broadcast_to(head, (chunks, _LANE_HEAD, W)),
+         feat.reshape(chunks, rows, W)], axis=1)
+    tab = jnp.pad(tab.reshape(chunks * (_LANE_HEAD + rows), W),
+                  ((0, 0), (0, wp)))
+    ids = jnp.pad(lane_ids.astype(jnp.int32), (0, wp),
+                  constant_values=-2)
+    return ids[:, None], tab.astype(jnp.float32)
+
+
+def _lane_lookup(li: jax.Array, ids: jax.Array, tab: jax.Array,
+                 narrow: bool):
+    """li (1, T) int32 leaf ids, ids (Wp, 1), tab (8 + R, Wp) float32
+    -> (lane (1, T) int32 in [-1, W), looked-up (8 + R, T) float32).
+
+    ``narrow``: the table's entries are integers of at most 8 bits
+    (the bins are stored as uint8, and the callers split a leaf id
+    into its bytes), exact in ONE bf16 pass of the MXU.  Wider bins
+    take the float32 contraction at HIGHEST, exact below 2^24."""
+    dt = jnp.bfloat16 if narrow else jnp.float32
+    got = jax.lax.dot_general(
+        tab.astype(dt), (li == ids).astype(dt), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=None if narrow else jax.lax.Precision.HIGHEST)
+    return got[0:1].astype(jnp.int32) - 1, got
+
+
 # ---- coarse-to-fine refine stage -----------------------------------
 #
 # The multi-leaf pass is MXU-stream bound: cost ∝ f_pad·b_pad·N
@@ -668,22 +761,25 @@ def histogram_segsum_multi(bins_t: jax.Array, vals: jax.Array,
 # window of R fine bins around the best coarse boundary is resolved,
 # streaming R ≪ b_pad one-hot rows.  The per-row window start
 # ``win_lo[leaf, feature]`` would be an (N,)-element gather (measured
-# 60-90 ms at bench shape — poison); instead the kernel resolves it as
-# a tiny (FC, W) × (W, T) matmul against the already-built subset
-# one-hot — ~3% of the pass FLOPs, on the MXU.
+# 60-90 ms at bench shape — poison); instead the kernel reads it by the
+# lane lookup above (:func:`_lane_lookup`), ~3% of the pass FLOPs, on
+# the MXU.
 
 
-def _hist_kernel_multi_win(x_ref, v_ref, s_ref, lo_ref, *rest,
+def _hist_kernel_multi_win(x_ref, v_ref, l_ref, ids_ref, tab_ref, *rest,
                            r_pad: int, width: int, exact: bool,
                            two_col: bool, with_miss: bool = False,
                            f_mask: int = 0):
     """Windowed refine step: accumulate (leaf, feature)-windowed fine
-    histograms.  x_ref (FC, T) bins; v_ref (3, T); s_ref (1, T) subset
-    selector in [-1, width); lo_ref (width, FC) per-(subset, feature)
-    fine-bin window starts; out_ref (FC*R, 128).  With ``with_miss``
-    an extra (FC, 1) missing-bin ref precedes out_ref and rows at
-    their feature's missing bin are excluded (windowed stats cover
-    VALUE bins only)."""
+    histograms.  x_ref (FC, T) bins; v_ref (3, T); l_ref (1, T) what
+    says a row's subset: a selector in [-1, width)
+    (:func:`histogram_pallas_multi_win`) or the leaf vector
+    (:func:`histogram_pallas_multi_win_lanes`); ids_ref, tab_ref the
+    lane operands (:func:`_lane_operands`: what ``l_ref`` holds for
+    each subset, the table's per-feature rows the fine-bin window
+    starts); out_ref (FC*R, 128).  With ``with_miss`` an extra (FC, 1)
+    missing-bin ref precedes out_ref and rows at their feature's
+    missing bin are excluded (windowed stats cover VALUE bins only)."""
     import jax.experimental.pallas as pl
 
     if with_miss:
@@ -695,35 +791,26 @@ def _hist_kernel_multi_win(x_ref, v_ref, s_ref, lo_ref, *rest,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    FC, T = x_ref.shape
+    FC = x_ref.shape[0]
     x = x_ref[...].astype(jnp.int32)
     if with_miss:
         mb = mb_ref[...].astype(jnp.int32)  # (FC, 1)
         x = jnp.where(x == mb, -1, x)       # miss rows match no window
     v = v_ref[...]                      # (3, T)
-    sel = s_ref[...]                    # (1, T)
     if two_col:
-        cols = 2
         valsc = v[:2]
     else:
-        cols = 3 if exact else 6
         valsc = v if exact else _split_hi_lo(v)
-    sel_oh = (sel == jax.lax.broadcasted_iota(
-        jnp.int32, (width, T), 0)).astype(jnp.float32)  # (W, T)
-    # per-row window start: lo[sel[t], f] via MXU instead of a gather.
-    # lo arrives (FC, W): a (W, FC) block would put FC on the 128-lane
-    # axis, which Mosaic rejects whenever features chunk (FC < F)
-    lo = lo_ref[...].astype(jnp.float32)                # (FC, W)
-    lo_pr = jax.lax.dot_general(
-        lo, sel_oh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)             # (FC, T)
-    rbin = x - lo_pr.astype(jnp.int32)
-    if v.dtype == jnp.int8:
-        rhs = _rhs_int8(sel == _rhs_row_lane(width, cols), valsc)
-    else:
-        rhs = _rhs_from(sel_oh, valsc)
+    # per-row window start lo[lane, f] and the lane itself
+    lane, got = _lane_lookup(
+        l_ref[...].astype(jnp.int32), ids_ref[...], tab_ref[...],
+        x_ref.dtype.itemsize == 1)
+    rbin = x - got[_LANE_HEAD:_LANE_HEAD + FC].astype(jnp.int32)
+    # a row in no subset reads window start 0 and may fall inside it:
+    # its rhs column is all zeros, so it counts nowhere
     # out-of-window rows (rbin outside [0, r_pad)) match no iota column
-    _accumulate(out_ref, rbin, rhs, r_pad, f_mask, pl.program_id(0) * FC)
+    _accumulate(out_ref, rbin, _rhs(lane, valsc, width), r_pad, f_mask,
+                pl.program_id(0) * FC)
 
 
 @functools.partial(jax.jit, static_argnames=("r_bins", "width",
@@ -756,14 +843,18 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
     else:
         vt = vals.astype(jnp.float32).T      # (3, N)
     st = sel.astype(jnp.int32)[None, :]      # (1, N)
+    ids, tab = _lane_operands(jnp.arange(W), [], win_lo.T, til)
 
     in_specs = [
         pl.BlockSpec((til.block_rows, t), lambda j, i: (j, i)),
         pl.BlockSpec((3, t), lambda j, i: (0, i)),
         pl.BlockSpec((1, t), lambda j, i: (0, i)),
-        pl.BlockSpec((til.block_rows, W), lambda j, i: (j, 0)),
+        pl.BlockSpec(ids.shape, lambda j, i: (0, 0)),
+        # feature block j: the head and its own window starts
+        pl.BlockSpec((tab.shape[0] // (f_pad // fc), tab.shape[1]),
+                     lambda j, i: (j, 0)),
     ]
-    operands = [bins_t, vt, st, _win_lo_operand(win_lo, til)]
+    operands = [bins_t, vt, st, ids, tab]
     if miss_bin is not None:
         in_specs.append(pl.BlockSpec((til.block_rows, 1),
                                      lambda j, i: (j, 0)))
@@ -794,94 +885,76 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
 # The wave bodies used to route rows in XLA-land: an unrolled
 # select-chain reading leaf_idx plus EVERY xt row from HBM, once more
 # a wave.  The histogram pass already streams the bins matrix, so this
-# variant does the routing IN the kernel: per row it resolves its wave lane (a
-# table compare against the lane leaf-ids), its split column value (a
-# feature-one-hot contraction over the resident x tile), the
-# goes-left compare, and the subset selector — and writes the NEW leaf
+# variant does the routing IN the kernel: per row it resolves its wave
+# lane and its lane's tables (:func:`_lane_lookup`), its split column
+# value (the lane's feature one-hot against the resident x tile), the
+# threshold compare, and the subset selector — and writes the NEW leaf
 # assignment and selector as side outputs.  Requires the whole feature
 # dimension in one chunk (fc == f_pad, i.e. F <= ~32 at 8 bins) —
 # callers fall back to the XLA routing otherwise.
 #
-# Lane tables ride in a (5, W) int32 operand:
+# The callers' lane tables are a (5-6, W) int32 array, which the
+# wrapper stacks into the kernel's operand (:func:`_lane_operands`):
 #   row 0: lane leaf ids   row 1: lane split column
 #   row 2: lane threshold  row 3: lane new (right-child) leaf id
 #   row 4: smaller-child-is-left flag (mode="small" only)
+#   row 5: default-left flag (with missing values)
 
 
-def _routed_parts(x, li, tbl, width: int, mode: str, mb=None):
-    """Shared routing math: returns (sel_oh, li_new, sel_out).
-    x (FC, T) int32; li (1, T) int32; tbl (5-6, W) int32 (row 5 = the
-    per-lane default-left flag, used with ``mb`` (FC, 1) per-feature
-    missing bins: a row AT its lane feature's missing bin routes by
-    the default direction instead of the threshold compare)."""
-    FC, T = x.shape
+def _routed_parts(x, li, ids, tab, width: int, mode: str, narrow: bool,
+                  li_bytes: int, mb=None):
+    """The routing math: returns (li_new, sel_out), the new leaf
+    vector and the subset selector, both (1, T).
+    x (FC, T) int32; li (1, T) int32, stored in ``li_bytes`` bytes;
+    ids, tab: the lane operands (:func:`_lane_operands`; head rows 1-6
+    are the lane's threshold, its new leaf id in three byte-wide
+    parts, its smaller-child-is-left flag and its default-left flag,
+    the per-feature rows the one-hot of its split feature).  With
+    ``mb`` (FC, 1) per-feature missing bins, a row AT its lane
+    feature's missing bin routes by the default direction instead of
+    the threshold compare.
+
+    A row in no lane reads 0 from every table row, its lane is -1 and
+    its column value 0: it is never above its threshold, so it goes
+    nowhere and is selected nowhere without a mask of its own."""
+    FC = x.shape[0]
     W = width if mode == "small" else width // 2
-    ids = tbl[0:1, :W]                              # (1, W)
-    lane_oh = (li == ids.T).astype(jnp.float32)     # (W, T)
-    in_wave = jnp.sum(lane_oh, axis=0, keepdims=True) > 0.5
-    # per-row split-column value: feature-one-hot contraction against
-    # the resident x tile (an (N,) gather is poison; this is 2 tiny
-    # MXU dots + an FC*T multiply-reduce)
-    featoh = (tbl[1:2, :W].T ==
-              jax.lax.broadcasted_iota(jnp.int32, (W, FC), 1)
-              ).astype(jnp.float32)                 # (W, FC)
-    fsel = jax.lax.dot_general(
-        featoh.T, lane_oh, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (FC, T)
+    lane, got = _lane_lookup(li, ids, tab, narrow)
+    # per-row split-column value: the lane's feature one-hot against
+    # the resident x tile, an FC*T multiply-reduce
+    fsel = got[_LANE_HEAD:_LANE_HEAD + FC]          # (FC, T)
     col = jnp.sum(x.astype(jnp.float32) * fsel, axis=0,
                   keepdims=True)                    # (1, T)
-    thr_pr = jax.lax.dot_general(
-        tbl[2:3, :W].astype(jnp.float32), lane_oh,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (1, T)
-    gl = in_wave & (col <= thr_pr)                  # (1, T)
-    if mb is not None and tbl.shape[0] >= 6:
+    gr = col > got[1:2]                             # goes right
+    if mb is not None:
         # per-row missing bin of the lane's feature + default-left
         mb_pr = jnp.sum(mb.astype(jnp.float32) * fsel, axis=0,
                         keepdims=True)              # (1, T)
-        dl_pr = jax.lax.dot_general(
-            tbl[5:6, :W].astype(jnp.float32), lane_oh,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
         is_miss = (col == mb_pr) & (mb_pr >= 0)
-        gl = gl | (in_wave & (dl_pr > 0.5) & is_miss)
-    glf = gl.astype(jnp.float32)
-    # leaf ids can exceed 256 (num_leaves>257), which is NOT bf16-exact
-    # — TPU f32 dots execute as bf16 passes at default precision, so
-    # this one contraction must run at HIGHEST (exact for ints < 2^24)
-    new_pr = jax.lax.dot_general(
-        tbl[3:4, :W].astype(jnp.float32), lane_oh,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)
-    li_new = jnp.where(in_wave & ~gl, new_pr.astype(jnp.int32), li)
+        gr = gr & ~((got[6:7] > 0.5) & is_miss)
+    # a leaf id above 256 is not bf16-exact: it rides as its bytes in
+    # place (``id & 0xFF``, ``id & 0xFF00``, ``id & 0xFF0000``: eight
+    # bits times a power of two each, exact in bf16), exact below 2^24
+    # as the float32 sum is.  The parts the leaf vector's type cannot
+    # hold are 0 and stay out of the sum: each addition on (1, T) is
+    # 0.13 ms of a 21M-row pass (PERF.md, PR 31)
+    new_pr = got[2:3]
+    for b in range(1, min(li_bytes, 3)):
+        new_pr = new_pr + got[2 + b:3 + b]
+    li_new = jnp.where(gr, new_pr.astype(jnp.int32), li)
     if mode == "small":
-        sl_pr = jax.lax.dot_general(
-            tbl[4:5, :W].astype(jnp.float32), lane_oh,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        to_small = (glf == sl_pr)                   # (1, T)
-        sel_oh = lane_oh * to_small                 # (W, T)
+        # the smaller child: left where the flag is set, else right
+        sel_out = jnp.where(gr != (got[5:6] > 0.5), lane, -1)
     else:
         # children mode: left child of lane w -> slot w, right -> W+w
-        sel_oh = jnp.concatenate(
-            [lane_oh * glf, lane_oh * (1.0 - glf)], axis=0) * \
-            in_wave.astype(jnp.float32)             # (2W, T)
-    lane_idx = jax.lax.dot_general(
-        jnp.arange(sel_oh.shape[0], dtype=jnp.int32)[None, :].astype(
-            jnp.float32), sel_oh,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (1, T)
-    any_sel = jnp.sum(sel_oh, axis=0, keepdims=True) > 0.5
-    sel_out = jnp.where(any_sel, lane_idx.astype(jnp.int32),
-                        jnp.int32(-1))
-    return sel_oh, li_new, sel_out
+        sel_out = lane + jnp.where(gr, W, 0)
+    return li_new, sel_out
 
 
-def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
-                              b_pad: int, width: int, exact: bool,
-                              two_col: bool, shift: int, mode: str,
-                              miss_idx: int = -1,
+def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, ids_ref, tab_ref,
+                              *rest, b_pad: int, width: int,
+                              exact: bool, two_col: bool, shift: int,
+                              mode: str, miss_idx: int = -1,
                               with_miss: bool = False):
     import jax.experimental.pallas as pl
 
@@ -896,23 +969,17 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, tbl_ref, *rest,
     x = x_ref[...].astype(jnp.int32)
     v = v_ref[...]
     li = li_ref[...].astype(jnp.int32)
-    tbl = tbl_ref[...]
     mb = mb_ref[...].astype(jnp.int32) if with_miss else None  # (FC, 1)
-    sel_oh, li_new, sel_out = _routed_parts(x, li, tbl, width, mode,
-                                            mb=mb)
+    li_new, sel_out = _routed_parts(
+        x, li, ids_ref[...], tab_ref[...], width, mode,
+        x_ref.dtype.itemsize == 1, li_ref.dtype.itemsize, mb=mb)
     li_out_ref[...] = li_new.astype(li_out_ref.dtype)
     sel_out_ref[...] = sel_out
     if two_col:
-        cols = 2
         valsc = v[:2]
     else:
-        cols = 3 if exact else 6
         valsc = v if exact else _split_hi_lo(v)
-    if v.dtype == jnp.int8:
-        rhs = _rhs_int8(sel_out == _rhs_row_lane(sel_oh.shape[0], cols),
-                        valsc)
-    else:
-        rhs = _rhs_from(sel_oh, valsc)
+    rhs = _rhs(sel_out, valsc, width)
     if shift:
         xb = x >> shift
         if with_miss and miss_idx >= 0:
@@ -975,19 +1042,25 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     # keep the leaf vector in its NARROW storage dtype (uint8 at
     # num_leaves<=255): it is re-read every pass
     lt = leaf_idx[None, :]
-    W_tbl = tables.shape[1]
-    R_tbl = tables.shape[0]
+    # the tables as the kernel reads them: one stacked operand
+    tbl = tables[:, :Wl if mode == "small" else Wl // 2].astype(jnp.int32)
+    ids, tab = _lane_operands(
+        tbl[0], [tbl[2], tbl[3] & 0xFF, tbl[3] & 0xFF00, tbl[3] & 0xFF0000,
+                 *tbl[4:6]],
+        tbl[1][None, :] == jnp.arange(f, dtype=jnp.int32)[:, None], til)
 
     in_specs = [
         pl.BlockSpec((til.block_rows, t), lambda i: (0, i)),
         pl.BlockSpec((3, t), lambda i: (0, i)),
         pl.BlockSpec((1, t), lambda i: (0, i)),
-        pl.BlockSpec((R_tbl, W_tbl), lambda i: (0, 0)),
+        pl.BlockSpec(ids.shape, lambda i: (0, 0)),
+        pl.BlockSpec(tab.shape, lambda i: (0, 0)),
     ]
-    operands = [bins_t, vt, lt, tables]
+    operands = [bins_t, vt, lt, ids, tab]
     miss_idx = -1
     if miss_bin is not None:
-        assert R_tbl >= 6, "missing routing needs the default-left row"
+        assert tables.shape[0] >= 6, \
+            "missing routing needs the default-left row"
         if shift:
             miss_idx = max_bin - 1
         in_specs.append(pl.BlockSpec((til.block_rows, 1),
@@ -1072,58 +1145,9 @@ def histogram_segsum_multi_routed(bins_t, vals, leaf_idx, tables,
 # leaf vector ALREADY encodes the routing after the coarse pass
 # updated it: each row's leaf id IS its child leaf id.  This variant
 # takes the (uint8/int32) leaf vector plus a per-lane child-leaf-id
-# table and resolves the lane one-hot in-kernel — reading ~10 MB
-# instead of 42, and writing nothing.
-
-
-def _hist_kernel_multi_win_lanes(x_ref, v_ref, li_ref, ids_ref, lo_ref,
-                                 *rest, r_pad: int, width: int,
-                                 exact: bool, two_col: bool,
-                                 with_miss: bool = False,
-                                 f_mask: int = 0):
-    import jax.experimental.pallas as pl
-
-    if with_miss:
-        mb_ref, out_ref = rest
-    else:
-        (out_ref,) = rest
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    FC = x_ref.shape[0]
-    x = x_ref[...].astype(jnp.int32)
-    if with_miss:
-        mb = mb_ref[...].astype(jnp.int32)              # (FC, 1)
-        x = jnp.where(x == mb, -1, x)   # miss rows match no window
-    v = v_ref[...]
-    li = li_ref[...].astype(jnp.int32)                  # (1, T)
-    ids = ids_ref[...]                                  # (1, W)
-    if two_col:
-        cols = 2
-        valsc = v[:2]
-    else:
-        cols = 3 if exact else 6
-        valsc = v if exact else _split_hi_lo(v)
-    sel_oh_f = (li == ids.T).astype(jnp.float32)        # (W, T)
-    lo = lo_ref[...].astype(jnp.float32)                # (FC, W)
-    lo_pr = jax.lax.dot_general(
-        lo, sel_oh_f, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)             # (FC, T)
-    rbin = x - lo_pr.astype(jnp.int32)
-    in_lane = jnp.sum(sel_oh_f, axis=0, keepdims=True) > 0.5
-    rbin = jnp.where(in_lane, rbin, -1)
-    if v.dtype == jnp.int8:
-        # the lane ids, one a rhs row (-1, no leaf's id, beyond them)
-        row_ids = jnp.repeat(ids.T, cols, axis=0)       # (W * C, 1)
-        row_ids = jnp.pad(
-            row_ids, ((0, _rhs_cols(width, cols) - width * cols), (0, 0)),
-            constant_values=-1)
-        rhs = _rhs_int8(li == row_ids, valsc)
-    else:
-        rhs = _rhs_from(sel_oh_f.astype(jnp.bfloat16), valsc)
-    _accumulate(out_ref, rbin, rhs, r_pad, f_mask, pl.program_id(0) * FC)
+# table and resolves the lane one-hot in-kernel (the windowed kernel,
+# :func:`_hist_kernel_multi_win`, given the leaf vector) — reading
+# ~10 MB instead of 42, and writing nothing.
 
 
 @functools.partial(jax.jit, static_argnames=("r_bins", "width",
@@ -1162,22 +1186,24 @@ def histogram_pallas_multi_win_lanes(bins_t: jax.Array, vals: jax.Array,
     else:
         vt = vals.astype(jnp.float32).T
     lt = leaf_idx[None, :]                   # narrow storage dtype
-    it = lane_ids.astype(jnp.int32)[None, :]  # (1, W)
+    ids, tab = _lane_operands(lane_ids, [], win_lo.T, til)
 
     in_specs = [
         pl.BlockSpec((til.block_rows, t), lambda j, i: (j, i)),
         pl.BlockSpec((3, t), lambda j, i: (0, i)),
         pl.BlockSpec((1, t), lambda j, i: (0, i)),
-        pl.BlockSpec((1, W), lambda j, i: (0, 0)),
-        pl.BlockSpec((til.block_rows, W), lambda j, i: (j, 0)),
+        pl.BlockSpec(ids.shape, lambda j, i: (0, 0)),
+        # feature block j: the head and its own window starts
+        pl.BlockSpec((tab.shape[0] // (f_pad // fc), tab.shape[1]),
+                     lambda j, i: (j, 0)),
     ]
-    operands = [bins_t, vt, lt, it, _win_lo_operand(win_lo, til)]
+    operands = [bins_t, vt, lt, ids, tab]
     if miss_bin is not None:
         in_specs.append(pl.BlockSpec((til.block_rows, 1),
                                      lambda j, i: (j, 0)))
         operands.append(_miss_operand(miss_bin, til))
     out = pl.pallas_call(
-        functools.partial(_hist_kernel_multi_win_lanes, r_pad=r_pad,
+        functools.partial(_hist_kernel_multi_win, r_pad=r_pad,
                           width=W, exact=exact, two_col=two_col,
                           with_miss=miss_bin is not None,
                           f_mask=til.f_mask),
@@ -1238,10 +1264,7 @@ def _leaf_stats_kernel(li_ref, g_ref, h_ref, m_ref, out_ref):
     h = h_ref[...] * m
     T = li.shape[1]
     v = jnp.concatenate([g, h, m], axis=0)      # (3, T) f32
-    valsc = _split_hi_lo(v)                     # (6, T)
-    sel_oh = ((li >> 4) == jax.lax.broadcasted_iota(
-        jnp.int32, (16, T), 0)).astype(jnp.bfloat16)     # (16, T)
-    rhs = _rhs_from(sel_oh, valsc)              # (128, T) bf16
+    rhs = _rhs_bf16(li >> 4, _split_hi_lo(v), 16)        # (128, T)
     onehot = ((li & 15) == jax.lax.broadcasted_iota(
         jnp.int32, (16, T), 0)).astype(jnp.bfloat16)     # (16, T)
     out_ref[...] += jax.lax.dot_general(

@@ -17,8 +17,15 @@ mode on the CPU:
 - the largest partial sum of a tile (16384 rows of +127 or -127 in one
   bin) is exact;
 - the kernel's jaxpr: an int8 x int8 -> int32 ``dot_general`` and
-  nothing in bfloat16 where the values are int8; the bf16 contraction
-  and no int8 ``dot_general`` where they are float32.
+  nothing of the one-hot's or the right-hand side's size in bfloat16
+  where the values are int8 (the lane lookup's two small operands
+  are); the bf16 contraction and no int8 ``dot_general`` where they
+  are float32;
+- the right-hand side made a 32-bit word at a time
+  (``ops/histogram._rhs_int8``) equals the row-by-row form it
+  replaced, kept here as the reference; the float32-valued one made
+  row by row from the subset index (``_rhs_bf16``) equals the
+  selector-times-values form it replaced, kept here too.
 
 Mosaic's lowering of the same kernels, and their time a pass, is
 proven on the chip by ``tools/check_routed_kernels.py``.
@@ -127,12 +134,139 @@ def test_the_contraction_follows_the_values(wrapper, int8):
     bf16 = [e for e in eqns
             if any(getattr(v.aval, "dtype", None) == jnp.bfloat16
                    for v in e.outvars)]
+    # the kernels that read lane tables (every one but ``multi``) do
+    # so by one bf16 contraction more (``_lane_lookup``): a (rows, Wp)
+    # table against the (Wp, T) one-hot of the lanes
+    lookups = int(wrapper != "multi")
+    wp = -(-W // 16) * 16
     if int8:
         assert dots.count((("int8", "int8"), "int32")) == 1
-        assert bf16 == []           # no bf16 one-hot, no bf16 rhs
+        assert dots.count((("bfloat16", "bfloat16"), "float32")) == lookups
+        # no bf16 one-hot, no bf16 rhs: the lookup's operands alone
+        assert all(wp in e.outvars[0].aval.shape and
+                   e.outvars[0].aval.size <= wp * RPB for e in bf16)
     else:
-        assert dots.count((("bfloat16", "bfloat16"), "float32")) == 1
+        assert dots.count((("bfloat16", "bfloat16"), "float32")) == \
+            1 + lookups
         assert not any("int8" in ins for ins, _ in dots)
+
+
+def _rhs_rows(on, valsc):
+    """The row-by-row right-hand side ``_rhs_int8`` replaced (PR 29's
+    form, the reference): row ``k`` is ``on[k] ? valsc[k % C] : 0``."""
+    lanes = on.shape[0]
+    C = valsc.shape[0]
+    c = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0), C)
+    v = valsc.astype(jnp.int32)
+    row = v[0:1]
+    for i in range(1, C):
+        row = jnp.where(c == i, v[i:i + 1], row)       # (lanes, T)
+    return jnp.where(on, row, 0).astype(jnp.int8)
+
+
+def _rhs_row_lane(width, cols):
+    """The subset rhs row ``k`` belongs to, -2 beyond the subsets."""
+    k = jax.lax.broadcasted_iota(
+        jnp.int32, (H._rhs_cols(width, cols), 1), 0)
+    return jnp.where(k < width * cols, jax.lax.div(k, cols), -2)
+
+
+@pytest.mark.parametrize("cols,width", [
+    (2, 1), (2, 21), (2, 42), (2, 64), (3, 1), (3, 21), (3, 42),
+    (3, 64)],       # 3 x 64: the 256-column right-hand side
+    ids=lambda v: str(v))
+def test_rhs_by_words_equals_rhs_by_rows(cols, width):
+    """Every subset, dead rows (-1) and the extreme values -127 and
+    127 in every column; three columns straddle words."""
+    import jax.experimental.pallas as pl
+    T = 512
+    rng = np.random.RandomState(cols * 100 + width)
+    lane = rng.randint(-1, width, size=(1, T)).astype(np.int32)
+    lane[0, :width] = np.arange(width)
+    lane[0, width:width + 8] = -1
+    vals = rng.randint(-127, 128, size=(cols, T)).astype(np.int8)
+    vals[:, :width:2] = 127
+    vals[:, 1:width:2] = -127
+    vals[:, T // 2:] = rng.choice([-127, 127], size=(cols, T - T // 2))
+    lane, vals = jnp.asarray(lane), jnp.asarray(vals)
+    lanes = H._rhs_cols(width, cols)
+    assert lanes == (256 if width * cols > 128 else 128)
+
+    def kernel(l_ref, v_ref, o_ref):
+        o_ref[...] = H._rhs_int8(l_ref[...], v_ref[...], width)
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((lanes, T), jnp.int8),
+        interpret=True)(lane, vals)
+    want = _rhs_rows(lane == _rhs_row_lane(width, cols), vals)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.abs(np.asarray(got, np.int32)).sum() > 0
+
+
+def _rhs_selector_times_values(sel_oh, valsc):
+    """The float32-valued right-hand side ``_rhs_bf16`` replaced (the
+    reference): a (W, T) selector times the (C, T) values in bf16,
+    regrouped (W, C, T) -> (W * C, T)."""
+    W_, T = sel_oh.shape
+    C = valsc.shape[0]
+    rhs = (sel_oh.astype(jnp.bfloat16)[:, None, :] *
+           valsc.astype(jnp.bfloat16)[None, :, :]).reshape(W_ * C, T)
+    return jnp.pad(rhs, ((0, H._rhs_cols(W_, C) - W_ * C), (0, 0)))
+
+
+@pytest.mark.parametrize("cols,width", [
+    (2, 1), (2, 64), (3, 42), (3, 64), (6, 1), (6, 16), (6, 21)],
+    ids=lambda v: str(v))
+def test_rhs_by_rows_equals_selector_times_values(cols, width):
+    """float32 values (hi/lo-split ones at 6 columns: the lo residual
+    rounds to bf16 either way), every subset, dead rows (-1)."""
+    import jax.experimental.pallas as pl
+    T = 512
+    rng = np.random.RandomState(cols * 100 + width)
+    lane = rng.randint(-1, width, size=(1, T)).astype(np.int32)
+    lane[0, :width] = np.arange(width)
+    lane[0, width:width + 8] = -1
+    v = (rng.randn(3, T) * 10.0 ** rng.randint(-3, 4, size=(3, T))
+         ).astype(np.float32)
+    vals = np.asarray(H._split_hi_lo(jnp.asarray(v)))[:cols] \
+        if cols == 6 else v[:cols]
+    lane, vals = jnp.asarray(lane), jnp.asarray(vals)
+    lanes = H._rhs_cols(width, cols)
+
+    def kernel(l_ref, v_ref, o_ref):
+        o_ref[...] = H._rhs(l_ref[...], v_ref[...], width)
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((lanes, T), jnp.bfloat16),
+        interpret=True)(lane, vals)
+    sel_oh = lane == jax.lax.broadcasted_iota(jnp.int32, (width, T), 0)
+    want = _rhs_selector_times_values(sel_oh, vals)
+    # the same bf16 values (the product form writes -0.0 where an
+    # unselected value is negative: the same nothing in the sum)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert np.abs(np.asarray(got, np.float32)).sum() > 0
+
+
+def test_leaf_stats_kernel_sums(monkeypatch):
+    """The renewal kernel's per-leaf sums (its right-hand side is
+    ``_rhs_bf16`` over the leaf id's high nibble) against float64: the
+    hi/lo split carries ~2^-16 of each summand's magnitude."""
+    monkeypatch.setenv("LTPU_PALLAS_INTERPRET", "1")
+    rng = np.random.RandomState(3)
+    n = 4096
+    li = rng.randint(0, 200, size=n).astype(np.int32)
+    gf = rng.randn(n).astype(np.float32)
+    hf = np.abs(rng.randn(n)).astype(np.float32)
+    mf = (rng.random_sample(n) < 0.9).astype(np.float32)
+    v = np.stack([gf * mf, hf * mf, mf], -1).astype(np.float64)
+    ref = np.zeros((256, 3), np.float64)
+    mag = np.zeros((256, 3), np.float64)
+    np.add.at(ref, li, v)
+    np.add.at(mag, li, np.abs(v))
+    got = np.asarray(H.leaf_stats_pallas(
+        jnp.asarray(li.astype(np.uint8)), jnp.asarray(gf), jnp.asarray(hf),
+        jnp.asarray(mf), 1024), np.float64)
+    assert np.abs((got - ref) / (mag + 1e-3)).max() <= 2.0 ** -14
+    np.testing.assert_array_equal(got[:, 2], ref[:, 2])     # counts exact
 
 
 def test_float_job_records_bf16(monkeypatch):

@@ -27,8 +27,14 @@ batched kernels at both cells' shapes, rows and lanes (21M x 28 on 64
 two-column lanes, 20M x 67 on 42), int8 values (the int8 x int8 ->
 int32 contraction) against the same integers as float32 (the bf16
 one); every diff must be 0, and the ms a pass of each is printed
-(medians of 6): the kernel-alone table of PERF.md, by one command.
+(medians of 6) with the seconds Mosaic took to compile it, its one-hot
+rows, the us a one-hot row the pass measures (the unrouted pass of its
+kind at two feature counts, the difference over the rows added), the
+stream (rows x us a row) and PASS LESS STREAM, the per-row prologue
+that does not shrink with the feature rows: the kernel-alone table of
+PERF.md, by one command.
 """
+import math
 import os
 import statistics
 import sys
@@ -120,11 +126,16 @@ def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
         if mode == "children":
             routed_pair("routed children+shift", vb, lb, tbl, Bc,
                         shift=shift, mode=mode)
-    # ids above 256 are not bf16-exact: pins the HIGHEST-precision
-    # new-leaf contraction (silent corruption at num_leaves>257 otherwise)
+    # ids above 256 are not bf16-exact: pins the three bytes the new
+    # leaf id rides as (silent corruption at num_leaves>257 otherwise;
+    # from 65792 on a high byte of two would pass 256 itself)
     routed_pair("routed L>256 ids", vb,
                 jnp.asarray(rng.randint(0, 500, size=N).astype(np.int32)),
                 tables(W, n_ids=500, new_lo=257, new_hi=511), B,
+                mode="small")
+    routed_pair("routed L>65792 ids", vb,
+                jnp.asarray(rng.randint(0, 500, size=N).astype(np.int32)),
+                tables(W, n_ids=500, new_lo=65792, new_hi=200000), B,
                 mode="small")
 
     # int8 value operand (quantized ints exact in int8/bf16)
@@ -202,37 +213,75 @@ def check_int8_contraction(cell: str, F: int, rows: int, W: int,
     lo_w = jnp.asarray(rng.randint(0, 255 - 32, size=(W, F))
                        .astype(np.int32))
     kw = dict(exact=True, two_col=two_col)
+    # (kind of pass, its bins): the unrouted pass of each kind takes
+    # the stream's slope below
     passes = {
-        "routed coarse": lambda v: histogram_pallas_multi_routed(
-            xb, v, lb, tbl, 16, W, RPB, shift=4, mode="small", **kw),
-        "win_lanes refine": lambda v: histogram_pallas_multi_win_lanes(
-            xb, v, lb, ids_w, lo_w, 32, W, RPB, **kw),
-        "multi coarse": lambda v: histogram_pallas_multi(
-            xb, v, selw, 16, W, RPB, shift=4, **kw),
-        "multi_win refine": lambda v: histogram_pallas_multi_win(
-            xb, v, selw, lo_w, 32, W, RPB, **kw),
+        "routed coarse": ("coarse", lambda v: histogram_pallas_multi_routed(
+            xb, v, lb, tbl, 16, W, RPB, shift=4, mode="small", **kw)),
+        "win_lanes refine": (
+            "refine", lambda v: histogram_pallas_multi_win_lanes(
+                xb, v, lb, ids_w, lo_w, 32, W, RPB, **kw)),
+        "multi coarse": ("coarse", lambda v, x=xb: histogram_pallas_multi(
+            x, v, selw, 16, W, RPB, shift=4, **kw)),
+        "multi_win refine": (
+            "refine", lambda v, x=xb, lo=lo_w: histogram_pallas_multi_win(
+                x, v, selw, lo, 32, W, RPB, **kw)),
     }
+    bins = {"coarse": 16, "refine": 32}
 
-    def timed(fn, v):
-        out = jax.block_until_ready(fn(v))      # compiles
+    def onehot_rows(f, b):
+        """The int8 one-hot rows a pass of ``f`` features streams
+        (``ops/histogram._accumulate``: up to the (32, 128) tile)."""
+        return (f + -f % (32 // math.gcd(b, 32))) * b
+
+    def timed(fn, *args):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))  # compiles
+        first = time.perf_counter() - t0
         ms = []
         for _ in range(6):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(v))
+            jax.block_until_ready(fn(*args))
             ms.append((time.perf_counter() - t0) * 1e3)
-        return jax.tree_util.tree_leaves(out), statistics.median(ms)
+        med = statistics.median(ms)
+        return jax.tree_util.tree_leaves(out), med, first - med / 1e3
 
-    for name, fn in passes.items():
+    ms8 = {}
+    for name, (_, fn) in passes.items():
         took = {}
 
         def pairs(fn=fn, took=took):
-            o8, took["int8"] = timed(fn, v8)
-            of, took["float32"] = timed(fn, vf)
+            o8, took["int8"], took["compile_s"] = timed(fn, v8)
+            of, took["float32"], _ = timed(fn, vf)
             return {f"out{i}": p for i, p in enumerate(zip(o8, of))}
         report(f"[{cell}: {rows} x {F}, {W} lanes] {name}, int8 against "
                f"float32 values", pairs)
-        print("    ms a pass: " + ", ".join(
-            f"{k} {v:.2f}" for k, v in took.items()), flush=True)
+        if took:
+            ms8[name] = took["int8"]
+            print(f"    ms a pass: int8 {took['int8']:.2f}, float32 "
+                  f"{took.get('float32', float('nan')):.2f}; int8 kernel "
+                  f"compiled in {took['compile_s']:.1f} s", flush=True)
+    # what a one-hot row costs: the unrouted pass of each kind again at
+    # fewer features, the difference over the one-hot rows taken away
+    f2 = {28: 14, 67: 60}.get(F, F // 2)
+    us_a_row = {}
+    for name, kind in (("multi coarse", "coarse"),
+                       ("multi_win refine", "refine")):
+        if name not in ms8:
+            continue
+        _, ms2, _ = timed(passes[name][1], v8, xb[:f2],
+                          *((lo_w[:, :f2],) if kind == "refine" else ()))
+        us_a_row[kind] = (ms8[name] - ms2) * 1e3 / (
+            onehot_rows(F, bins[kind]) - onehot_rows(f2, bins[kind]))
+    for name, (kind, _) in passes.items():
+        if name in ms8 and kind in us_a_row:
+            r = onehot_rows(F, bins[kind])
+            stream = r * us_a_row[kind] / 1e3
+            print(f"    [{cell}] {name}: {ms8[name]:.2f} ms a pass = "
+                  f"{r} one-hot rows x {us_a_row[kind]:.2f} us a row "
+                  f"({stream:.2f} ms of stream, slope of {F} against {f2} "
+                  f"features) + {ms8[name] - stream:.2f} ms PASS LESS "
+                  f"STREAM", flush=True)
 
 
 def check_leaf_stats(rng) -> None:
