@@ -28,6 +28,19 @@ from .tree import Tree, cat_bitset
 
 _KEPS = 1e-15
 
+
+def _host_default_device():
+    """Arrays made inside land on the host's CPU backend (a process
+    that runs without one keeps its default device)."""
+    import contextlib
+
+    import jax
+    try:
+        return jax.default_device(jax.devices("cpu")[0])
+    except RuntimeError:
+        return contextlib.nullcontext()
+
+
 def _threshold_l1(s, l1):
     if l1 <= 0:
         return np.asarray(s, np.float64)
@@ -379,10 +392,18 @@ class GBDT:
             efb_groups=(int(self._bundles.num_groups)
                         if self._bundles is not None else 0),
             forced=forced, use_pool=use_pool, rows_per_block=rpb,
-            monotone=monotone, penalty=penalty))
+            monotone=monotone, penalty=penalty,
+            objective_rows=(
+                objective.shard_refusal() if objective is not None
+                else "custom objective: the host hands over gradients "
+                     "of the whole job's rows")))
         self.grow_params = plan.grow_params
         self.tier_decision = plan.record
         self._counts_proxy = plan.grow_params.two_col
+        # per-row state (score carry, objective's row tensors,
+        # gradients, leaf index) on the shard's rows only: decided by
+        # the plan's row_state ladder, nothing here re-derives it
+        self._rows_on_shard = plan.record["row_state"] == "shard"
 
         # ---- device-block pager (io/pager.py, docs/Streaming.md
         # "Out-of-core on device"): decide whether the binned matrix
@@ -541,10 +562,14 @@ class GBDT:
                 # NARROW dtype end to end: the host->device copy AND
                 # device residency (uint8 = 295 MB at bench shape vs
                 # 1.18 GB int32); the pallas kernels and routing
-                # selects widen per tile
-                self._xt = jnp.asarray(xt)
-        self._base_mask = jnp.asarray(
-            np.pad(np.ones(n, np.float32), (0, self._n_pad - n)))
+                # selects widen per tile.  Under a mesh the host array
+                # goes to its devices below, each its own block: the
+                # whole matrix never sits on one of them
+                self._xt = xt if self._dist is not None \
+                    else jnp.asarray(xt)
+        base_mask = np.pad(np.ones(n, np.float32), (0, self._n_pad - n))
+        self._base_mask = base_mask if self._dist is not None \
+            else jnp.asarray(base_mask)
         if self._F_pad != F:
             # padded features are trivial: one bin, never splittable
             self._num_bins = jnp.concatenate(
@@ -559,15 +584,20 @@ class GBDT:
             # the per-tree dispatch nor the fused super-step re-shards
             # host-placed global arrays on every call (the per-shard
             # dispatch overhead behind the WEAKSCALE degradation)
+            from ..utils.profiling import timed
             shd = self._dist.shardings()
-            if self._pager is None and stream_info is None:
-                # streamed uploads were already placed window-by-window
-                self._xt = jax.device_put(self._xt, shd["xt"])
-            self._base_mask = jax.device_put(self._base_mask, shd["row"])
-            self._num_bins = jax.device_put(self._num_bins, shd["feat"])
-            self._missing_type = jax.device_put(self._missing_type,
+            with timed("dataset/shard_upload"):
+                if self._pager is None and stream_info is None:
+                    # streamed uploads were already placed window-by-
+                    # window
+                    self._xt = jax.device_put(self._xt, shd["xt"])
+                self._base_mask = jax.device_put(self._base_mask,
+                                                 shd["row"])
+                self._num_bins = jax.device_put(self._num_bins,
                                                 shd["feat"])
-            self._is_cat = jax.device_put(self._is_cat, shd["feat"])
+                self._missing_type = jax.device_put(self._missing_type,
+                                                    shd["feat"])
+                self._is_cat = jax.device_put(self._is_cat, shd["feat"])
         self._build_tree = build_tree if self._dist is None else self._dist
         if self._pager is not None and self._dist is None:
             # serial paged per-tree dispatch: the jitted builder closes
@@ -594,13 +624,7 @@ class GBDT:
             init = np.asarray(train_set.metadata.init_score,
                               np.float64).reshape(-1)
             score += init.reshape(k, n) if init.size == k * n else init
-        self._score = jnp.asarray(score)
-        if self._dist is not None:
-            # the score carry lives on the mesh too (replicated): the
-            # fused super-step donates it in place and the carry never
-            # leaves the device mesh between blocks
-            self._score = jax.device_put(self._score,
-                                         self._dist.shardings()["rep"])
+        self._score = self._place_score(score)
         self._rng_feature = np.random.RandomState(
             config.feature_fraction_seed & 0x7FFFFFFF)
         self._rec_layout = None  # lazy: packed split-record fetch plan
@@ -613,10 +637,32 @@ class GBDT:
         self._quant_key = (jax.random.PRNGKey(
             config.data_random_seed & 0x7FFFFFFF)
             if self.grow_params.quantize else None)
-        if objective is not None:
+        if objective is not None and not self._rows_on_shard:
             objective.init(train_set.metadata, n)
+        elif objective is not None:
+            # the objective's row tensors go to the mesh as the score
+            # did, each device its own rows: made on the host's
+            # backend, so that none sits whole on a device of the mesh
+            from ..utils.profiling import timed
+            shd = self._dist.shardings()
+            with _host_default_device():
+                objective.init(train_set.metadata, n)
+            with timed("dataset/shard_upload"):
+                objective.place_rows(
+                    self._n_pad, lambda a: jax.device_put(
+                        a, shd["row" if a.ndim == 1 else "rows2d"]))
 
         # ---- observability -------------------------------------------
+        from ..utils import telemetry as _tele_mod
+        row_state = [self._score, self._base_mask] + (
+            list(objective.rows().values()) if objective is not None
+            else [])
+        # what one device holds of the per-row state (a gauge: the
+        # row-sharded state keeps it x devices constant)
+        self.row_state_bytes_per_chip = int(sum(
+            a.addressable_shards[0].data.nbytes for a in row_state))
+        _tele_mod.counters.set("row_state_bytes_per_chip",
+                               self.row_state_bytes_per_chip)
         if self._dist is not None:
             # the built mesh's shape, which only the builder knows
             self.tier_decision["mesh_shape"] = [
@@ -624,15 +670,24 @@ class GBDT:
         self._collective_per_pass = 0
         self._collective_ops_per_pass = 0
         self._collective_per_axis = {}
+        self._collective_plan = {}
+        self._collective_last = (0, 0)  # (bytes, ops) of the last commit
         if dist_active and self._dist is not None:
-            from ..ops.grow import collective_bytes_per_pass
-            # the builder's params carry the real DistConfig (the
-            # booster-level grow_params keeps the serial default)
-            est = collective_bytes_per_pass(self._dist.params,
-                                            self._F_pad, self._n_pad)
-            self._collective_per_pass = est["total"]
-            self._collective_ops_per_pass = est["ops"]
-            self._collective_per_axis = est.get("per_axis", {})
+            from ..ops.grow import (collective_bytes_per_pass,
+                                    wave_collective_plan)
+            # the wave data learner's collectives are counted from the
+            # trees' own passes (_count_growth); the other learners
+            # keep the static estimate.  The builder's params carry
+            # the real DistConfig (the booster-level grow_params keeps
+            # the serial default)
+            self._collective_plan = wave_collective_plan(
+                self._dist.params, self._F_pad)
+            if not self._collective_plan:
+                est = collective_bytes_per_pass(
+                    self._dist.params, self._F_pad, self._n_pad)
+                self._collective_per_pass = est["total"]
+                self._collective_ops_per_pass = est["ops"]
+                self._collective_per_axis = est.get("per_axis", {})
         self._telemetry = None
         self._tele_counters_last: Dict[str, float] = {}
         if getattr(config, "telemetry_file", ""):
@@ -642,20 +697,57 @@ class GBDT:
             # CLI via telemetry.set_recorder) adopts every booster it
             # outlives: one JSONL stream for a whole ingest->train->
             # publish loop instead of one file handle per batch
-            from ..utils import telemetry as _tele_mod
             if _tele_mod.get_recorder() is not None:
                 self.attach_telemetry(_tele_mod.get_recorder())
         if self._stream_upload:
             # the streamed construction finished before the recorder
             # attached: publish the upload's prefetch-overlap stats
             # now (the ingest/prefetch record obs/rules.py watches)
-            from ..utils import telemetry as _tele_mod
             rec = self._telemetry or _tele_mod.get_recorder()
             if rec is not None:
                 rec.emit("ingest", event="prefetch",
                          **self._stream_upload)
 
     # ------------------------------------------------------------------
+    def _place_score(self, score_kn: np.ndarray):
+        """The (k, rows) score carry from a host array, where the
+        learner keeps it: on the one device; replicated on the mesh
+        (the fused super-step donates it in place and it never leaves
+        the mesh between blocks); or, where the row state is the
+        shard's, padded to ``n_pad`` rows and each device its own."""
+        import jax
+        import jax.numpy as jnp
+        score_kn = np.asarray(score_kn, np.float32)
+        if self._dist is None:
+            return jnp.asarray(score_kn)
+        shd = self._dist.shardings()
+        if not self._rows_on_shard:
+            return jax.device_put(score_kn, shd["rep"])
+        from ..utils.profiling import timed
+        pad = self._n_pad - score_kn.shape[-1]
+        with timed("dataset/shard_upload"):
+            return jax.device_put(np.pad(score_kn, ((0, 0), (0, pad))),
+                                  shd["rows2d"])
+
+    def _gradient_fn(self):
+        """``score -> (grad, hess)`` of the objective at the score
+        carry's width: the objective's jitted wrapper, or, where the
+        row tensors live on the shard, the one that takes them as
+        arguments (no constant of the data set in the program, and the
+        results stay each device's own rows)."""
+        obj = self.objective
+        if self._rows_on_shard:
+            fn, rows = obj.gradient_fn_rows(), obj.rows()
+            return lambda score: fn(score, rows)
+        return obj.gradient_fn() or obj.get_gradients
+
+    def _score_rows(self, rows):
+        """A per-row vector of ``n_pad`` rows at the score carry's
+        width: as it is where the carry is padded too (the row state is
+        the shard's), else without the padding rows."""
+        width = self._score.shape[-1]
+        return rows if rows.shape[-1] == width else rows[..., :width]
+
     def _constraint_tuples(self, config: Config, train_set: TpuDataset,
                            F: int):
         """Static per-feature (monotone, penalty) tuples padded to the
@@ -1023,6 +1115,12 @@ class GBDT:
         rows_sharded = dist is not None and dist.kind in ("data",
                                                           "voting",
                                                           "data2d")
+        # the row state on the shard (the plan's row_state ladder):
+        # score carry, objective's row tensors, gradients, leaf index
+        # and score update are each device's own rows; no array of the
+        # whole job's rows enters, leaves or is a constant of the
+        # per-device program
+        own_rows = self._rows_on_shard
         if rows_sharded:
             # data2d shards rows over the ROW axis only (R of the R*F
             # devices); the 1-D learners' row axis is the whole mesh
@@ -1047,6 +1145,52 @@ class GBDT:
                 saved_raw = getattr(self, "_trace_raw", False)
                 self._bag_key = bag_key
                 self._trace_raw = True
+            elif own_rows:
+                # the shard's rows of the objective's row tensors, and
+                # which of the shard's rows are the job's (not padding)
+                (rows,) = extras
+                live = base_mask > 0
+
+            def own_rows_step(carry, xs):
+                """One iteration on the shard's own rows.  Every row's
+                gradient, rounding bits (hashed from its index in the
+                job, ops/grow.py) and score update are those of the
+                serial scan; the sums the tree needs cross the row
+                axis inside ``build_tree_impl`` (each pass's histogram
+                psum, the scales' pmax, the statistics)."""
+                sc, bag_prev = carry
+                it, fmask, tid = xs
+                with obj.rows_as(rows):
+                    grad, hess = obj.get_gradients(sc)
+                # the padding rows' gradients are whatever the
+                # objective makes of a zero label: they count for
+                # nothing, and their score stays where it started
+                gp_b = jnp.where(live, jnp.atleast_2d(grad)[0].astype(
+                    jnp.float32), 0.0)
+                hp_b = jnp.where(live, jnp.atleast_2d(hess)[0].astype(
+                    jnp.float32), 0.0)
+                kw = {}
+                if quantize:
+                    kw["quant_key"] = jax.random.fold_in(quant_key, tid)
+                rec = build_tree_impl(xt, gp_b, hp_b, base_mask, fmask,
+                                      num_bins, missing_type, is_cat, p,
+                                      **kw)
+                vals = rec["leaf_values_final"] * lr
+                li = rec["leaf_idx"]
+                new_sc = sc.at[0].add(
+                    jnp.where(live, take_small(vals, li), 0.0))
+                host_rec = {k: v for k, v in rec.items()
+                            if k not in drop}
+                # the health flag (see the replicated step) is the
+                # job's: each shard's own rows, then one scalar pmax
+                bad = jnp.logical_not(
+                    jnp.all(jnp.isfinite(gp_b)) &
+                    jnp.all(jnp.isfinite(vals)) &
+                    jnp.all(jnp.isfinite(new_sc)))
+                host_rec["nonfinite"] = jax.lax.pmax(
+                    bad.astype(jnp.int32), ax) > 0
+                return (new_sc, bag_prev), \
+                    (host_rec, li.astype(li_dt), vals)
 
             def step(carry, xs):
                 sc, bag_prev = carry
@@ -1074,11 +1218,12 @@ class GBDT:
                     gp = gp * w
                     hp = hp * w
                 if rows_sharded:
-                    # the full-N weighted gradients are computed
-                    # replicated (bit-identical to the serial scan),
-                    # then each shard slices ITS contiguous row block
-                    # for the local histogram pass; base_mask arrives
-                    # already local via its in_spec
+                    # replicated row state: the full-N weighted
+                    # gradients are computed on every device
+                    # (bit-identical to the serial scan), then each
+                    # shard slices ITS contiguous row block for the
+                    # local histogram pass; base_mask arrives already
+                    # local via its in_spec
                     off = jax.lax.axis_index(ax) * n_loc
                     gp_b = jax.lax.dynamic_slice_in_dim(gp, off, n_loc)
                     hp_b = jax.lax.dynamic_slice_in_dim(hp, off, n_loc)
@@ -1101,15 +1246,14 @@ class GBDT:
                 vals = rec["leaf_values_final"] * lr
                 li = rec["leaf_idx"]
                 if rows_sharded:
-                    # the score delta is computed on the shard's OWN
-                    # rows (take_small's select chain is the per-row
-                    # cost) and ONE tiled all-gather rebuilds the
-                    # global (N,) update — per-shard work stays
-                    # O(N/D) and the gather's per-shard wire
-                    # contribution is a constant n_loc*4 bytes at any
-                    # mesh size.  The gather preserves contiguous row
-                    # order, so the adds land per row exactly as in
-                    # the serial scan (bit-parity)
+                    # the replicated row state (voting, data2d, and the
+                    # data learner where the row_state ladder refused
+                    # the shard: GOSS, MVS, bagging): the score delta
+                    # is looked up on the shard's own rows and ONE
+                    # tiled all-gather rebuilds the whole job's (N,)
+                    # update for the replicated carry, in contiguous
+                    # row order, so the adds land per row as in the
+                    # serial scan.  own_rows_step has no such gather
                     upd = jax.lax.all_gather(take_small(vals, li), ax,
                                              tiled=True)[:n]
                 else:
@@ -1135,7 +1279,8 @@ class GBDT:
 
             try:
                 (final_sc, final_bag), (recs, leaf_idx_k, vals_k) = \
-                    jax.lax.scan(step, (score, bag0),
+                    jax.lax.scan(own_rows_step if own_rows else step,
+                                 (score, bag0),
                                  (iters, fmasks, tree_ids))
             finally:
                 if batched:
@@ -1161,21 +1306,33 @@ class GBDT:
         shorter tail block recompiles once).  Big device residents
         (the binned matrix, masks, descriptors) ride as ARGUMENTS —
         closure capture would embed them in the remote-compile
-        payload; the objective's label tensors stay closure-captured
-        because ``gradient_fn`` owns them.
+        payload.  The objective's row tensors are arguments too where
+        the row state is the shard's; elsewhere they stay
+        closure-captured because ``gradient_fn`` owns them.
 
-        With a distributed learner the SAME scan body runs SPMD: the
-        whole K-iteration program is wrapped in ``shard_map`` over the
-        learner's 1-D mesh, the binned matrix arrives as the local
-        shard (rows for data/voting, features for feature-parallel),
-        and the per-strategy histogram/merge collectives inside
+        With a distributed learner the scan runs SPMD: the whole
+        K-iteration program is wrapped in ``shard_map`` over the
+        learner's mesh, the binned matrix arrives as the local shard
+        (rows for data/voting, features for feature-parallel), and the
+        per-strategy histogram/merge collectives inside
         ``build_tree_impl`` ride within the one compiled program — K
         iterations of sharded build+update cost ONE dispatch, not 5K
-        per-shard dispatches.  Gradients, mask draws and the score
-        update run replicated (identical math on every shard — the
+        per-shard dispatches.
+
+        Where per-row state lives is the plan's ``row_state``
+        (models/tier.py).  ``shard`` (the data learner, a pointwise
+        objective, no sampling over the whole job): the score carry,
+        the objective's row tensors, gradients, leaf index and score
+        update are each device's own rows (``own_rows_step``); what
+        crosses devices is each pass's histogram psum, the
+        quantization scales' pmax and scalars, and no array of the
+        whole job's rows is an operand, a constant or a result of the
+        per-device program.  ``replicated`` (every other case):
+        gradients, mask draws and the score update run on every device
+        for all rows (identical math on every shard — the
         bit-exactness anchor against the serial scan), and the
-        row-sharded learners all-gather the (N,) leaf assignment once
-        per iteration for the replicated score update."""
+        row-sharded learners all-gather the score delta once per
+        iteration for the replicated carry."""
         import jax
 
         superstep = self._superstep_core()
@@ -1214,6 +1371,17 @@ class GBDT:
             # stitches the global (K, n_pad) table with no collective
             # (the host-side rewind replay is its only reader)
             li_spec = P(None, ax_name) if rows_sharded else R
+            sc_spec = R
+            if self._rows_on_shard:
+                # the score carry (in, its block-start copy and out)
+                # and the objective's row tensors: rows over the axis,
+                # whatever leads whole.  The bagging carry is the
+                # one-element sentinel (no sampling on this path)
+                sc_spec = P(None, ax_name)
+                rows_spec = {
+                    k: P(*([None] * (v.ndim - 1)), ax_name)
+                    for k, v in self.objective.rows().items()}
+                in_specs = (sc_spec,) + in_specs[1:] + (rows_spec,)
             if self._pager is not None:
                 # paged: the xt slot carries a replicated dummy; each
                 # program instance pages its OWN (f_loc, n_loc) block
@@ -1223,8 +1391,8 @@ class GBDT:
             superstep = jax.shard_map(superstep, mesh=dist.mesh,
                                       check_vma=False,
                                       in_specs=in_specs,
-                                      out_specs=(R, R, R, R, R,
-                                                 li_spec, R))
+                                      out_specs=(sc_spec, R, sc_spec, R,
+                                                 R, li_spec, R))
 
         # carry donation frees both N-sized buffers for in-place reuse
         # on device; CPU XLA has no donation and would warn per call
@@ -1315,7 +1483,8 @@ class GBDT:
                     # bit-identical to "no mask" (x*1.0 == x); a zeros
                     # sentinel would silently zero every gradient
                     # until the first in-block draw
-                    bag0 = jnp.ones(self.num_data, jnp.float32)
+                    bag0 = jnp.ones(1 if self._rows_on_shard
+                                    else self.num_data, jnp.float32)
             qk = self._quant_key if self._quant_key is not None \
                 else jax.random.PRNGKey(0)
             _telemetry.counters.incr("superstep_dispatches")
@@ -1332,7 +1501,8 @@ class GBDT:
                 score0, bag0, jnp.float32(self.shrinkage_rate), qk,
                 self._xt, self._base_mask, self._num_bins,
                 self._missing_type, self._is_cat, iters, fmasks,
-                tree_ids)
+                tree_ids, *((self.objective.rows(),)
+                            if self._rows_on_shard else ()))
         # an abandoned attempt (elastic stall watchdog moved on and a
         # re-mesh owns ``self`` now) must not commit ANY state — the
         # checks bracket every device interaction
@@ -1476,7 +1646,8 @@ class GBDT:
                 tree = self._records_to_tree(rec_t)
                 tree.apply_shrinkage(entry["lr"])
                 trees.append(tree)
-        hist_passes = self._count_growth(host, len(trees))
+        hist_passes = self._count_growth(
+            host, len(trees), scan_flags=K if self._rows_on_shard else 0)
         self._fused_block = {
             "start_score": start_score, "start_iter": i0,
             "start_tid": start_tid, "rng_state": rng_state,
@@ -1544,23 +1715,36 @@ class GBDT:
             # single axis; data2d splits histogram traffic (row axis)
             # from merge+routing (feature axis).  The leaf-assignment
             # gather rides the row axis.
-            per_ax_b, per_ax_o = {}, {}
-            for axn, v in self._collective_per_axis.items():
-                per_ax_b[axn] = int(v["bytes"] * hp)
-                per_ax_o[axn] = int(v["ops"] * hp)
-            if extra_b and per_ax_b:
-                axn = self._dist.axis
-                per_ax_b[axn] = per_ax_b.get(axn, 0) + extra_b
-                per_ax_o[axn] = per_ax_o.get(axn, 0) + extra_o
+            if self._collective_plan:
+                # the wave data learner: the count _count_growth made
+                # of the block's own passes (the counters' figure),
+                # plus the score delta's gather where the carry is
+                # replicated; all of it on the one axis
+                if self._rows_on_shard:
+                    extra_b = extra_o = 0
+                total_b = self._collective_last[0] + extra_b
+                total_o = self._collective_last[1] + extra_o
+                per_ax_b = {self._dist.axis: total_b}
+                per_ax_o = {self._dist.axis: total_o}
+            else:
+                per_ax_b, per_ax_o = {}, {}
+                for axn, v in self._collective_per_axis.items():
+                    per_ax_b[axn] = int(v["bytes"] * hp)
+                    per_ax_o[axn] = int(v["ops"] * hp)
+                if extra_b and per_ax_b:
+                    axn = self._dist.axis
+                    per_ax_b[axn] = per_ax_b.get(axn, 0) + extra_b
+                    per_ax_o[axn] = per_ax_o.get(axn, 0) + extra_o
+                total_b = int(self._collective_per_pass * hp + extra_b)
+                total_o = int(self._collective_ops_per_pass * hp
+                              + extra_o)
             self._tele_superstep.update({
                 "learner": self._dist.kind,
                 "num_shards": int(self._dist.num_shards),
                 "mesh_shape": [int(s) for s in
                                self._dist.mesh.devices.shape],
-                "collective_bytes": int(
-                    self._collective_per_pass * hp + extra_b),
-                "collective_ops": int(
-                    self._collective_ops_per_pass * hp + extra_o),
+                "collective_bytes": total_b,
+                "collective_ops": total_o,
                 "collective_bytes_axis": per_ax_b,
                 "collective_ops_axis": per_ax_o,
             })
@@ -1597,12 +1781,11 @@ class GBDT:
         # row-sharded learners stitch the stacked leaf table at the
         # PADDED width (each shard emits its local block); the serial
         # scan stores it pre-sliced — normalize to the real row count
-        n = score.shape[-1]
         for t in range(pos):
             prev = score
-            score = score.at[0].add(
-                take_small(blk["vals"][t],
-                           blk["leaf_idx"][t][:n].astype(jnp.int32)))
+            score = score.at[0].add(take_small(
+                blk["vals"][t],
+                self._score_rows(blk["leaf_idx"][t]).astype(jnp.int32)))
         return score, prev
 
     def _fused_restore(self, pos: int) -> None:
@@ -1865,9 +2048,18 @@ class GBDT:
 
         n, n_pad = self.num_data, self._n_pad
         with timed("tree/prep", iter=self.iter):
-            gp = jnp.pad(grad_k.astype(jnp.float32), (0, n_pad - n))
-            hp = jnp.pad(hess_k.astype(jnp.float32), (0, n_pad - n))
+            gp = grad_k.astype(jnp.float32)
+            hp = hess_k.astype(jnp.float32)
             mask = self._base_mask
+            if gp.shape[0] == n_pad and n_pad != n:
+                # gradients at the padded width (the row state is the
+                # shard's): the padding rows' values are whatever the
+                # objective makes of a zero label, and count for nothing
+                gp = jnp.where(mask > 0, gp, 0.0)
+                hp = jnp.where(mask > 0, hp, 0.0)
+            else:
+                gp = jnp.pad(gp, (0, n_pad - gp.shape[0]))
+                hp = jnp.pad(hp, (0, n_pad - hp.shape[0]))
             if bag is not None:
                 # weights scale grad/hess (GOSS/MVS upweighting); the
                 # count channel stays presence-based like the
@@ -2024,19 +2216,16 @@ class GBDT:
             # (bit-parity between the two paths requires it).  An
             # objective that opted out of the pure contract
             # (gradient_fn -> None) keeps its eager get_gradients.
-            grad_fn = self.objective.gradient_fn() or \
-                self.objective.get_gradients
-            grad, hess = grad_fn(self._score)
+            grad, hess = self._gradient_fn()(self._score)
         grad = jnp.atleast_2d(grad)
         hess = jnp.atleast_2d(hess)
         bag = self._bagging_mask(grad, hess)
-        n = self.num_data
         rec, _ = self._dispatch_build(grad[0], hess[0], bag)
         with timed("tree/score_update", iter=self.iter):
             vals = rec["leaf_values_final"] * \
                 jnp.float32(self.shrinkage_rate)
             self._score = self._score.at[0].add(
-                take_small(vals, rec["leaf_idx"])[:n])
+                self._score_rows(take_small(vals, rec["leaf_idx"])))
         prev_stop = False
         if self._pending is not None:
             with timed("tree/fetch", iter=self.iter):
@@ -2165,19 +2354,25 @@ class GBDT:
                 S = self._models[-1].num_leaves - 1
                 fields["pool_hit_rate"] = round(
                     max(0.0, 1.0 - hp / float(2 * S)), 4)
-        if self._collective_per_pass:
-            # passes this iteration: measured for speculative/wave
-            # builds; otherwise ~one fresh smaller-child pass per
-            # split plus the root (subtraction covers the sibling)
-            hp = fields.get("hist_passes")
-            if hp is None:
-                n_leaves = (self._models[-1].num_leaves if self._models
-                            else self.config.num_leaves)
-                hp = max(n_leaves, 1) * self.num_tree_per_iteration
-            fields["collective_bytes"] = int(
-                self._collective_per_pass * hp)
-            fields["collective_ops"] = int(
-                self._collective_ops_per_pass * hp)
+        if self._collective_plan or self._collective_per_pass:
+            if self._collective_plan:
+                # the wave data learner: _count_growth's own count
+                fields["collective_bytes"], fields["collective_ops"] = \
+                    self._collective_last
+            else:
+                # passes this iteration: measured for speculative
+                # builds; otherwise ~one fresh smaller-child pass per
+                # split plus the root (subtraction covers the sibling)
+                hp = fields.get("hist_passes")
+                if hp is None:
+                    n_leaves = (self._models[-1].num_leaves
+                                if self._models
+                                else self.config.num_leaves)
+                    hp = max(n_leaves, 1) * self.num_tree_per_iteration
+                fields["collective_bytes"] = int(
+                    self._collective_per_pass * hp)
+                fields["collective_ops"] = int(
+                    self._collective_ops_per_pass * hp)
             if self._dist is not None:
                 fields["learner"] = self._dist.kind
                 fields["num_shards"] = int(self._dist.num_shards)
@@ -2262,9 +2457,7 @@ class GBDT:
                         Log.info("Start training from score %f", init)
             from ..utils.profiling import timed
             with timed("boosting/gradients", iter=self.iter):
-                grad_fn = self.objective.gradient_fn() or \
-                    self.objective.get_gradients
-                grad, hess = grad_fn(self._score)
+                grad, hess = self._gradient_fn()(self._score)
             grad = jnp.atleast_2d(grad)
             hess = jnp.atleast_2d(hess)
         else:
@@ -2351,7 +2544,7 @@ class GBDT:
                 vals, (0, max(0, self.config.num_leaves - vals.shape[0])))
             tree_idx = len(self.models) % self.num_tree_per_iteration
             self._score = self._score.at[tree_idx].add(
-                take_small(vals, rec["leaf_idx"])[:n])
+                self._score_rows(take_small(vals, rec["leaf_idx"])))
         # valid scores: device split-record replay when the binned
         # matrix is resident, host traversal fallback otherwise
         from ..ops.grow import route_rows
@@ -2388,7 +2581,7 @@ class GBDT:
         return tree
 
     # ------------------------------------------------------------------
-    def _count_growth(self, recs, n_trees: int = 1):
+    def _count_growth(self, recs, n_trees: int = 1, scan_flags: int = 0):
         """Add the growth loop's own counts (``ops/grow.py``
         ``GROW_COUNTERS``) of the ``n_trees`` trees just fetched to the
         process counters: ``trees_grown``, ``hist_passes_coarse`` (one
@@ -2396,8 +2589,10 @@ class GBDT:
         windowed passes), ``grow_waves`` and, on the wave tiers, where
         every lane a wave fills is one split, ``grow_lanes_live`` (the
         trees' splits) and ``grow_lanes_offered`` (waves x the wave's
-        lanes).  Returns the trees' histogram passes, each tree's root
-        pass included (a record's ``hist_passes``), or None on a tier
+        lanes); under the wave data learner also ``collective_bytes``
+        and ``collective_ops`` (kept for the telemetry record in
+        ``_collective_last``).  Returns the trees' histogram passes,
+        each tree's root pass included (a record's ``hist_passes``), or None on a tier
         whose loop does not count (no batched passes)."""
         if "n_arm_passes" not in recs:
             return None
@@ -2406,6 +2601,22 @@ class GBDT:
         waves = int(np.sum(np.atleast_1d(recs["n_waves"])[:n_trees]))
         self.last_arm_passes = int(arm[-1])
         arm = int(np.sum(arm))
+        plan = self._collective_plan
+        if plan:
+            # what these trees' passes handed to the row axis'
+            # collectives (ops/grow.py wave_collective_plan: the
+            # passes' own bins and lanes): a wave is one coarse pass,
+            # the other batched passes are refine ones; off c2f every
+            # pass is a full one.  ``scan_flags``: the fused own-rows
+            # scan's health flag, one scalar pmax an iteration
+            pb = plan["passes"]
+            moved = (waves * pb["coarse"] + (arm - waves) * pb["refine"]
+                     if "coarse" in pb else arm * pb["full"])
+            moved += n_trees * plan["tree_bytes"] + 4 * scan_flags
+            ops = arm + n_trees * plan["tree_ops"] + scan_flags
+            counters.incr("collective_bytes", moved)
+            counters.incr("collective_ops", ops)
+            self._collective_last = (int(moved), int(ops))
         counters.incr("trees_grown", n_trees)
         counters.incr("hist_passes_coarse", waves)
         counters.incr("hist_passes_refine", arm - waves)
@@ -2506,7 +2717,7 @@ class GBDT:
             "trees_dispatched": int(tid),
             "shrinkage_rate": float(self.shrinkage_rate),
             "stopped": bool(self._stop_flag),
-            "score": np.asarray(score),
+            "score": np.asarray(score)[:, :self.num_data],
             "rng_feature": rng_state,
             "models": list(self._models),
             "valid_scores": {vs.name: np.asarray(vs.score)
@@ -2531,7 +2742,6 @@ class GBDT:
         its defining PRNG fold.  Valid sets must already be
         registered; their accumulated scores (path-dependent under
         DART renormalization) are overwritten from the snapshot."""
-        import jax.numpy as jnp
         self._fused_block = None
         self._sq = []
         self.__dict__.pop("_dispatch_fence", None)
@@ -2541,15 +2751,11 @@ class GBDT:
         self.iter = int(snap["iter"])
         self._trees_dispatched = int(snap["trees_dispatched"])
         self.shrinkage_rate = float(snap["shrinkage_rate"])
-        self._score = jnp.asarray(np.asarray(snap["score"], np.float32))
-        if self._dist is not None:
-            # mesh-resident contract: the restored carry goes back on
-            # the mesh replicated, exactly as construction placed the
-            # fresh one — a host-placed carry would compile a second
-            # executable for its input sharding on the first block
-            import jax
-            self._score = jax.device_put(self._score,
-                                         self._dist.shardings()["rep"])
+        # mesh-resident contract: the restored carry goes back where
+        # construction placed the fresh one — a host-placed carry
+        # would compile a second executable for its input sharding on
+        # the first block
+        self._score = self._place_score(snap["score"])
         self._prev_score = None
         self._prev_valid_scores = []
         self._rng_feature.set_state(snap["rng_feature"])
@@ -2913,7 +3119,6 @@ class GBDT:
         set's raw feature matrix (the init model may have been trained
         with different bin boundaries, so replay must use real values).
         """
-        import jax.numpy as jnp
         if len(models) % max(self.num_tree_per_iteration, 1):
             Log.fatal("init model has %d trees, not a multiple of "
                       "num_tree_per_iteration=%d", len(models),
@@ -2957,8 +3162,7 @@ class GBDT:
                             tree.predict_leaf_index(blk).astype(dt))
             if self._track_train_leaf:
                 leaf_idx = [np.concatenate(p) for p in parts]
-        self._score = self._score + jnp.asarray(
-            np.pad(add, ((0, 0), (0, self._score.shape[1] - add.shape[1]))))
+        self._score = self._score + self._place_score(add)
         if self._track_train_leaf:
             # DART needs per-tree train-leaf assignments to drop and
             # renormalize the seeded trees

@@ -42,6 +42,10 @@ class TierFacts(NamedTuple):
     rows_per_block: int
     monotone: tuple
     penalty: tuple
+    # why the objective's per-row tensors cannot live on the shard
+    # (``Objective.shard_refusal``), or None: it is pointwise and hands
+    # its row tensors over as arguments
+    objective_rows: Optional[str] = None
 
     @property
     def dist_active(self) -> bool:
@@ -186,6 +190,51 @@ def _split_gate(config: Config, facts: TierFacts,
     return None
 
 
+def _row_state_gate(config: Config, facts: TierFacts) -> Optional[str]:
+    # where the per-row state of the boosting loop lives (the score
+    # carry, labels and weights, gradients, the leaf index, the score
+    # update).  On the shard: every device holds its own rows' state and
+    # no array of the whole job's rows is an operand, a constant or a
+    # result of its program; what crosses devices is each pass's
+    # histogram psum, the quantization scale's pmax and scalars.  That
+    # takes the row-sharded fused scan, an objective whose gradients
+    # are elementwise over rows, and no sampling that reads the whole
+    # job.  Everything else keeps the state replicated (the reference
+    # keeps a rank's rows on the rank, data_parallel_tree_learner.cpp;
+    # its other learners are not held to that here yet)
+    if not facts.dist_active:
+        return (f"tree_learner={facts.kind}: one device holds every "
+                f"row")
+    if facts.learner != "data":
+        return (f"tree_learner={facts.learner} keeps the replicated "
+                f"row state (only the data learner's fused scan is "
+                f"held to the shard)")
+    boosting = str(config.boosting).lower()
+    if boosting in ("dart", "rf", "random_forest"):
+        return f"boosting={boosting} runs the per-iteration loop"
+    if config.fused_iters <= 1:
+        return ("fused_iters <= 1: the per-iteration loops keep the "
+                "replicated row state")
+    if facts.objective_rows is not None:
+        return facts.objective_rows
+    if boosting == "goss":
+        return ("GOSS ranks the whole job's gradients (the top-rate "
+                "threshold is a statistic of every row)")
+    if boosting == "mvs":
+        return ("MVS thresholds the whole job's gradient norms (a "
+                "statistic of every row)")
+    if config.bagging_freq > 0 and (
+            config.bagging_fraction < 1.0 or
+            config.pos_bagging_fraction < 1.0 or
+            config.neg_bagging_fraction < 1.0):
+        return ("the bagging mask is one random stream drawn over the "
+                "whole job's rows")
+    if str(config.paged_training).lower() == "on" or \
+            config.hbm_budget_mb > 0:
+        return "paged training pages replicated row state"
+    return None
+
+
 def _passes(facts: TierFacts, gp: GrowParams) -> dict:
     """(bins, value columns) of each kind of histogram pass the booster
     runs.  c2f runs a coarse and a windowed refine pass (the root
@@ -285,6 +334,8 @@ def plan_tier(config: Config, facts: TierFacts) -> TierPlan:
                        facts.use_pool and not facts.forced)
                    else 0))
 
+    why_rows = _row_state_gate(config, facts)
+
     passes = _passes(facts, grow_params)
     # build_tree's own answer, for the batched pass it would route in
     # (the coarse one under c2f) over the columns one device holds
@@ -311,7 +362,8 @@ def plan_tier(config: Config, facts: TierFacts) -> TierPlan:
     gates = {name: why for name, why in (
         ("two_col", why_two_col), ("wave", why_wave), ("c2f", why_c2f),
         ("routed", why_routed), ("split", why_split),
-        ("learner", why_learner)) if why is not None}
+        ("learner", why_learner), ("row_state", why_rows))
+        if why is not None}
     record = {
         "tier": tier,
         "gates": gates,
@@ -331,5 +383,6 @@ def plan_tier(config: Config, facts: TierFacts) -> TierPlan:
         "num_shards": facts.num_shards if dist_active else 1,
         "mesh_shape": (list(facts.mesh_shape2d or (facts.num_shards,))
                        if dist_active else [1]),
+        "row_state": "replicated" if why_rows else "shard",
     }
     return TierPlan(grow_params, record)

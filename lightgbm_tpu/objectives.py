@@ -63,6 +63,13 @@ class Objective:
     name = "base"
     is_constant_hessian = False
     num_model_per_iteration = 1
+    # the per-row device tensors ``get_gradients`` reads, where a row's
+    # gradient is a function of that row's score and tensors alone (a
+    # pointwise objective): the row-sharded boosting loop hands each
+    # device its own rows of them as arguments (:meth:`rows`).  None
+    # where a row's gradient reads other rows (lambdarank's queries)
+    row_tensors: Optional[Tuple[str, ...]] = ("label", "weight")
+
     # transform applied to raw score at predict time
     def __init__(self, config):
         self.config = config
@@ -74,6 +81,67 @@ class Objective:
         self.label = jnp.asarray(metadata.label, jnp.float32)
         self.weight = (jnp.asarray(metadata.weight, jnp.float32)
                        if metadata.weight is not None else None)
+
+    # ---- per-row tensors as arguments (models/gbdt.py, row_state) -----
+    def shard_refusal(self) -> Optional[str]:
+        """Why this objective's row tensors cannot live on the shard
+        of a row-sharded learner (``models/tier.py`` records it), or
+        None."""
+        if self.row_tensors is None:
+            return (f"objective={self.name}: a row's gradient reads "
+                    f"other rows' scores")
+        if self.num_model_per_iteration > 1:
+            return (f"objective={self.name} grows "
+                    f"{self.num_model_per_iteration} trees an iteration "
+                    f"(not fused)")
+        if type(self).renew_tree_output is not \
+                Objective.renew_tree_output:
+            return (f"objective={self.name} renews leaf outputs on the "
+                    f"host from the whole job's residuals")
+        return None
+
+    def rows(self) -> Dict[str, jax.Array]:
+        """The row tensors that are set, by attribute name."""
+        return {k: v for k in self.row_tensors or ()
+                if (v := getattr(self, k, None)) is not None}
+
+    @contextlib.contextmanager
+    def rows_as(self, rows: Dict[str, jax.Array]):
+        """Swap the row tensors for ``rows`` while a program traces
+        (the shard's own rows inside ``shard_map``), as
+        :meth:`weight_override` swaps the weight."""
+        saved = {k: getattr(self, k) for k in rows}
+        self.__dict__.update(rows)
+        try:
+            yield
+        finally:
+            self.__dict__.update(saved)
+
+    def place_rows(self, width: int, place) -> None:
+        """Pad every row tensor to ``width`` rows and hand it to
+        ``place(host array) -> device array``: after this the tensors
+        are the mesh's, each device holding its rows, and host readers
+        slice ``[:num_data]`` (:meth:`_host_rows`)."""
+        for k, v in self.rows().items():
+            a = np.asarray(v)
+            pad = [(0, 0)] * (a.ndim - 1) + [(0, width - a.shape[-1])]
+            setattr(self, k, place(np.pad(a, pad)))
+
+    def _host_rows(self, a) -> np.ndarray:
+        """A row tensor on the host, float64, without padding rows."""
+        return np.asarray(a, np.float64)[..., :self.num_data]
+
+    def gradient_fn_rows(self):
+        """:meth:`gradient_fn` with the row tensors as arguments:
+        ``(score, rows) -> (grad, hess)``, jitted.  Nothing of the data
+        set is a constant of the program, so placed tensors keep their
+        placement and the compiled program does not depend on them."""
+        if getattr(self, "_gradient_fn_rows_jit", None) is None:
+            def fn(score, rows):
+                with self.rows_as(rows):
+                    return self.get_gradients(score)
+            self._gradient_fn_rows_jit = jax.jit(fn)
+        return self._gradient_fn_rows_jit
 
     def _w(self, grad, hess):
         if self.weight is not None:
@@ -158,9 +226,9 @@ class Objective:
         return None
 
     def _weighted_mean_label(self) -> float:
-        lab = np.asarray(self.label, np.float64)
+        lab = self._host_rows(self.label)
         if self.weight is not None:
-            w = np.asarray(self.weight, np.float64)
+            w = self._host_rows(self.weight)
             return float(np.sum(lab * w) / np.sum(w))
         return float(np.mean(lab))
 
@@ -289,11 +357,7 @@ class Huber(Objective):
         return self._w(grad, jnp.ones_like(score))
 
     def boost_from_score(self, class_id=0):
-        lab = np.asarray(self.label, np.float64)
-        if self.weight is not None:
-            w = np.asarray(self.weight, np.float64)
-            return float(np.sum(lab * w) / np.sum(w))
-        return float(np.mean(lab))
+        return self._weighted_mean_label()
 
 
 @register("fair")
@@ -459,6 +523,8 @@ class Binary(Objective):
         self.cls_weight = jnp.asarray(
             np.where(lab == 1, self.label_weights[1], self.label_weights[0]),
             jnp.float32)
+
+    row_tensors = ("label", "weight", "sign_label", "cls_weight")
 
     def get_gradients(self, score):
         return self._jitted_gradients(
@@ -647,6 +713,8 @@ class LambdaRank(Objective):
     2/(1+exp(2*sigma*d)) shape the reference tabulates
     (``rank_objective.hpp:194``).
     """
+
+    row_tensors = None      # a document's lambda reads its query
 
     def __init__(self, config):
         super().__init__(config)

@@ -66,7 +66,8 @@ from .split import (NEG_INF, SplitParams, choose_window,
                     leaf_output)
 
 __all__ = ["DistConfig", "GrowParams", "build_tree", "build_tree_impl",
-           "collective_bytes_per_pass", "GROW_COUNTERS"]
+           "collective_bytes_per_pass", "wave_collective_plan",
+           "GROW_COUNTERS"]
 
 # What the growth loop counts of its own work, as int32 scalars in the
 # loop state (wave and speculative tiers); they return with the tree's
@@ -334,6 +335,44 @@ def collective_bytes_per_pass(params: GrowParams, num_features: int,
         out["per_axis"] = {p.dist.axis: {"bytes": out["total"],
                                          "ops": out["ops"]}}
     return out
+
+
+def wave_collective_plan(params: GrowParams, num_features: int) -> dict:
+    """What one shard of the data learner's WAVE growth really hands to
+    the row axis' collectives, by what the growth loop counts: bytes
+    and operations of one batched pass of each kind (``coarse`` and
+    ``refine`` under c2f, else ``full``: the whole-wave psum of the
+    pass's own (lanes, F, bins, 3) float32 tensor) and the fixed part
+    of a tree (the root's passes, the root statistics' psum, the
+    quantization scales' two pmax, the exact leaf statistics' psum).
+    ``build_tree_impl`` holds every tensor it psums against this plan
+    as it traces, so the count and the program cannot disagree;
+    ``GBDT._count_growth`` multiplies it by the trees' own pass counts.
+    Empty where the learner is not the wave data learner."""
+    p = params
+    W = batched_width(p, p.dist.kind)
+    if not (p.dist.kind == "data" and p.wave and W > 1):
+        return {}
+    F = max(num_features, 1)
+
+    def pass_bytes(bins):
+        return W * F * bins * 3 * 4
+
+    if p.refine_shift:
+        coarse, window = c2f_bins(p.split.max_bin, p.refine_shift,
+                                  p.split.any_missing)
+        passes = {"coarse": pass_bytes(coarse),
+                  "refine": pass_bytes(window)}
+    else:
+        passes = {"full": pass_bytes(p.split.max_bin)}
+    # the root runs one pass of each kind, then the statistics
+    tree_bytes = sum(passes.values()) + 3 * 4
+    tree_ops = len(passes) + 1
+    if p.quantize:
+        tree_bytes += 2 * 4 + p.num_leaves * 3 * 4
+        tree_ops += 3
+    return {"passes": passes, "tree_bytes": tree_bytes,
+            "tree_ops": tree_ops}
 
 
 def c2f_bins(max_bin: int, shift: int, any_missing: bool):
@@ -685,6 +724,10 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
             (feature-sharded scans), voting stays local AND raw —
             the elected-only psum must run on integer units."""
             if wave_dist:
+                # the count GBDT._count_growth makes of this psum
+                assert h.size * h.dtype.itemsize in \
+                    wave_collective_plan(p, F_hist)["passes"].values(), \
+                    (h.shape, wave_collective_plan(p, F_hist))
                 h = jax.lax.psum(h, ax)
             if wave_vote:
                 return h

@@ -300,7 +300,11 @@ class DistributedBuilder:
         return {"xt": NamedSharding(m, self.xt_spec),
                 "row": NamedSharding(m, self.row_spec),
                 "feat": NamedSharding(m, self.feat_spec),
-                "rep": NamedSharding(m, P())}
+                "rep": NamedSharding(m, P()),
+                # per-row state with leading axes (the (k, n_pad) score
+                # carry, the (K, n_pad) stacked leaf index): rows as
+                # "row" lays them, whatever leads whole on every device
+                "rows2d": NamedSharding(m, P(None, *self.row_spec))}
 
     def pad_rows(self, n: int, base: int = 1) -> int:
         return pad_rows_for(self.kind, max(self.row_shards, 1), n, base)

@@ -299,12 +299,21 @@ class _Counters:
         # skew the bit-for-bit scrape oracle forever).  Hooks must not
         # call back into incr.
         with self._lock:
-            self._c[name] = self._c.get(name, 0.0) + by
-            for fn in self._hooks:
-                try:
-                    fn(name, by)
-                except Exception:  # noqa: BLE001 - hooks never break
-                    pass
+            self._add(name, by)
+
+    def set(self, name: str, value: float) -> None:
+        """A gauge among the counters: ``name`` takes ``value`` (a
+        size written once at construction); hooks see the difference."""
+        with self._lock:
+            self._add(name, value - self._c.get(name, 0.0))
+
+    def _add(self, name: str, by: float) -> None:
+        self._c[name] = self._c.get(name, 0.0) + by
+        for fn in self._hooks:
+            try:
+                fn(name, by)
+            except Exception:  # noqa: BLE001 - hooks never break
+                pass
 
     def add_hook(self, fn, prime=None) -> None:
         """Register an increment hook.  ``prime`` (if given) runs

@@ -41,10 +41,28 @@ def pytest_configure(config):
                    "8-device mesh); deselect with -m 'not slow'")
 
 
+# One test of tests/benchmark/test_harness.py holds every declared cell
+# to one chip (``cell.workload["chips"] == w["chips"] == 1``), which was
+# true of the benchmark it was written for; ``criteo67x4.fast`` takes
+# four.  A PR that adds a cell may not edit a file the benchmark
+# already has, so that test is expected to fail, by that assertion and
+# strictly: once a ``benchmark`` PR drops the ``== 1`` (ROADMAP,
+# Workloads worth adding 1) the test passes, the strict marker turns
+# the suite red, and the marker goes with it.
+# tests/benchmark/test_cells_declared.py asserts everything else that
+# test asserts, for every declared cell.
+_ONE_CHIP_ONLY = "test_harness.py::test_manifest_names_files_that_exist"
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.module.__name__ in _SLOW_MODULES:
             item.add_marker(pytest.mark.slow)
+        if item.nodeid.endswith(_ONE_CHIP_ONLY):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts chips == 1 of every declared cell; "
+                       "criteo67x4.fast takes 4",
+                raises=AssertionError, strict=True))
 
 
 @pytest.fixture(scope="session")

@@ -372,11 +372,241 @@ def test_data2d_mesh_resident_state(data601):
 def test_mesh_resident_state_sharded(data601):
     """The persistent training tensors are placed with the learner's
     NamedSharding ONCE at construction — the binned matrix must be
-    sharded over the mesh (not replicated host-placed per call)."""
+    sharded over the mesh (not replicated host-placed per call), and
+    under the data learner's fused scan so is the per-row state: the
+    score carry and the objective's row tensors, each device its own
+    rows (``row_state: shard``, models/tier.py)."""
     X, y = data601
     bst = _train(X, y, "data", 4, rounds=4)
     g = bst._gbdt
     shd = g._dist.shardings()
+    assert g.tier_decision["row_state"] == "shard"
     assert g._xt.sharding == shd["xt"]
     assert g._base_mask.sharding == shd["row"]
-    assert g._score.sharding.is_fully_replicated
+    assert g._score.sharding == shd["rows2d"]
+    assert g._score.shape == (1, g._n_pad)
+    assert g.train_score.shape == (1, N_ROWS)
+    # a learner the ladder refuses keeps the carry replicated
+    v = _train(X, y, "voting", 4, rounds=4)._gbdt
+    assert v.tier_decision["row_state"] == "replicated"
+    assert v._score.sharding.is_fully_replicated
+
+
+# ---- the row state on the shard (row_state: shard) --------------------
+N4 = 2001             # not divisible by the four devices: n_pad 2004
+FAST4 = {"wave_splits": True, "use_quantized_grad": True, "max_bin": 63,
+         "num_machines": 4}
+
+
+@pytest.fixture(scope="module")
+def data2001():
+    rng = np.random.RandomState(1)
+    X = rng.random_sample((N4, 8)).astype(np.float32)
+    y = (X[:, 0] + 0.5 * (X[:, 1] > 0.5) +
+         0.1 * rng.randn(N4) > 0.7).astype(float)
+    return X, y
+
+
+@pytest.mark.parametrize("min_data", [20, 1])
+def test_data4_fast_matches_serial_structure(data2001, min_data):
+    """The fast job (wave growth, quantized gradients, fused blocks)
+    on four devices, every device holding its own rows' state, grows
+    the serial learner's trees over iteration 0 and two fused blocks,
+    on the count-carrying tier (``min_data_in_leaf`` 20) and on the
+    two-column one (1): the histograms are sums of small integers,
+    exact in any order, and a row draws the rounding bits it draws in
+    the serial scan (hashed from its index in the job).  Leaf values
+    are renewed from float sums, whose order over shards differs: the
+    quantized tier's tolerance."""
+    X, y = data2001
+    extra = dict(FAST4, min_data_in_leaf=min_data)
+    serial = _train(X, y, "serial", 4, extra, rounds=9)
+    data = _train(X, y, "data", 4, extra, rounds=9)
+    g = data._gbdt
+    assert g.tier_decision["row_state"] == "shard"
+    assert g.tier_decision["num_shards"] == 4
+    assert g.tier_decision["tier"] == ("two_col" if min_data == 1
+                                       else "wave_quant")
+    assert g._fused_ok() and g._fused_block is not None
+    assert len(g.models) == len(serial._gbdt.models) == 9
+    for ts, td in zip(serial._gbdt.models, g.models):
+        n = ts.num_leaves - 1
+        assert td.num_leaves == ts.num_leaves
+        np.testing.assert_array_equal(td.split_feature[:n],
+                                      ts.split_feature[:n])
+        np.testing.assert_array_equal(td.threshold_bin[:n],
+                                      ts.threshold_bin[:n])
+    np.testing.assert_allclose(data.predict(X), serial.predict(X),
+                               rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(g.train_score, serial._gbdt.train_score,
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("extra", [
+    {"min_data_in_leaf": 20}, {"min_data_in_leaf": 1},
+    {"objective": "regression", "use_quantized_grad": False}],
+    ids=["wave_quant", "two_col", "regression_float"])
+def test_shard_state_is_the_replicated_states_model(data2001, extra,
+                                                    monkeypatch):
+    """Each device computing its own rows' gradients, leaf index and
+    score update gives, bit for bit, the model and the training score
+    of the replicated state (every device computing all rows and the
+    score delta gathered): the math is elementwise over rows."""
+    from lightgbm_tpu.models import tier
+    X, y = data2001
+    extra = dict(FAST4, **extra)
+    own = _train(X, y, "data", 4, extra, rounds=9)
+    assert own._gbdt.tier_decision["row_state"] == "shard"
+    monkeypatch.setattr(tier, "_row_state_gate",
+                        lambda config, facts: "forced by the test")
+    rep = _train(X, y, "data", 4, extra, rounds=9)
+    assert rep._gbdt.tier_decision["row_state"] == "replicated"
+    assert rep._gbdt._score.sharding.is_fully_replicated
+    assert own.model_to_string() == rep.model_to_string()
+    np.testing.assert_array_equal(own._gbdt.train_score,
+                                  rep._gbdt.train_score)
+
+
+def _shapes_of(hlo_text):
+    """Every array shape in a compiled module's text, as tuples."""
+    import re
+    return {tuple(int(d) for d in m.split(","))
+            for m in re.findall(r"\[(\d+(?:,\d+)*)\]", hlo_text)}
+
+
+def test_per_device_program_holds_no_array_of_the_job(data2001,
+                                                      monkeypatch):
+    """After construction and after a block the score carry, the
+    objective's row tensors and the stacked leaf index are sharded
+    over rows, and the compiled per-device program of the fused
+    super-step has no operand, constant or result with a dimension of
+    the job's rows (``n``) or its padded rows (``n_pad``)."""
+    import jax
+    from lightgbm_tpu.models.gbdt import GBDT
+    X, y = data2001
+    seen = {}
+    build = GBDT._build_superstep_fn
+
+    def spy(self):
+        fn = build(self)
+
+        def call(*args):
+            # shapes as placed: what sits on one device is uncommitted
+            seen["jit"], seen["args"] = fn, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=(a.sharding
+                              if len(a.sharding.device_set) > 1
+                              else None)), args)
+            return fn(*args)
+        return call
+    monkeypatch.setattr(GBDT, "_build_superstep_fn", spy)
+
+    extra = dict(FAST4, min_data_in_leaf=20)
+    params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+              "metric": "None", "tree_learner": "data",
+              "fused_iters": 4, "num_iterations": 9, **extra}
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    g = bst._gbdt
+    n_pad = g._n_pad
+    assert (N4, n_pad) == (2001, 2004)
+
+    def own_rows(a):
+        return a.addressable_shards[0].data.shape[-1] == n_pad // 4
+
+    def state():
+        return [g._score, g._base_mask, *g.objective.rows().values()]
+    assert set(g.objective.rows()) == {"label", "sign_label",
+                                       "cls_weight"}
+    assert all(own_rows(a) for a in state())          # as constructed
+    for _ in range(5):                                # iteration 0 + a block
+        bst.update()
+    assert all(own_rows(a) for a in state())
+    blk = g._fused_block
+    assert own_rows(blk["leaf_idx"]) and own_rows(blk["start_score"])
+    # the gradients' buffers: what the objective makes of that state
+    grad, hess = g._gradient_fn()(g._score)
+    assert own_rows(grad) and own_rows(hess)
+
+    text = seen["jit"].lower(*seen["args"]).compile().as_text()
+    # what crosses devices: sums and maxima, nothing gathered
+    assert "all-reduce" in text and "all-gather" not in text
+    whole = {s for s in _shapes_of(text) if N4 in s or n_pad in s}
+    assert not whole, sorted(whole)
+    assert any(n_pad // 4 in s for s in _shapes_of(text))
+    # the replicated state's program, for what the check can see: it
+    # holds the job's rows (scores, labels as constants, the gather)
+    monkeypatch.setattr("lightgbm_tpu.models.tier._row_state_gate",
+                        lambda config, facts: "forced by the test")
+    rep = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    for _ in range(5):
+        rep.update()
+    text = seen["jit"].lower(*seen["args"]).compile().as_text()
+    assert any(N4 in s for s in _shapes_of(text))
+    assert "all-gather" in text
+
+
+@pytest.mark.parametrize("n", [2000, 4000])
+def test_row_state_bytes_a_chip_do_not_grow_with_the_job(n):
+    """The same job on two and on four devices holds the same bytes of
+    per-row state in all (``row_state_bytes_per_chip`` x devices): a
+    chip holds its share and nothing that grows with the job.  The
+    replicated state holds the job's on every chip."""
+    from lightgbm_tpu.utils.telemetry import counters_snapshot
+    rng = np.random.RandomState(0)
+    X = rng.random_sample((n, 8)).astype(np.float32)
+    y = (X[:, 0] > 0.5).astype(float)
+
+    def per_chip(devices, learner="data"):
+        params = {"objective": "binary", "num_leaves": 15,
+                  "verbose": -1, "metric": "None",
+                  "tree_learner": learner, "fused_iters": 4,
+                  **dict(FAST4, num_machines=devices)}
+        g = lgb.Booster(params,
+                        lgb.Dataset(X, label=y, params=params))._gbdt
+        assert counters_snapshot()["row_state_bytes_per_chip"] == \
+            g.row_state_bytes_per_chip
+        return g.row_state_bytes_per_chip
+    # score, mask, label, sign_label, cls_weight: five float32 a row
+    assert per_chip(2) * 2 == per_chip(4) * 4 == 5 * 4 * n
+    assert per_chip(4, "voting") > per_chip(4) * 3
+
+
+def test_shard_state_midblock_resume_and_rollback(data2001, tmp_path):
+    """The state kept on the shard goes through the same doors as the
+    replicated one: a snapshot taken mid fused block (stored without
+    padding rows) resumes bit-identically onto the mesh and onto the
+    serial learner's width, and a rollback lands on a carry that is
+    still each device's own rows."""
+    X, y = data2001
+    extra = dict(FAST4, min_data_in_leaf=20, num_iterations=10)
+    oracle = _train(X, y, "data", 4, extra, rounds=10)
+    assert oracle._gbdt.tier_decision["row_state"] == "shard"
+    ck = str(tmp_path / "ck")
+    _train(X, y, "data", 4, dict(extra, checkpoint_dir=ck,
+                                 snapshot_freq=3, keep_last_n=8),
+           rounds=10)
+    snap = os.path.join(ck, "ckpt_00000003")
+    assert os.path.isdir(snap)
+
+    def resume(learner):
+        params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+                  "metric": "None", "tree_learner": learner,
+                  "fused_iters": 4, **extra}
+        return lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                         verbose_eval=False, resume_from=snap)
+    assert resume("data").model_to_string() == oracle.model_to_string()
+    assert len(resume("serial")._gbdt.models) == 10
+
+    params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+              "metric": "None", "tree_learner": "data", "fused_iters": 4,
+              **extra}
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    for _ in range(6):
+        bst.update()
+    bst.rollback_one_iter()
+    bst.update()
+    g = bst._gbdt
+    assert len(g.models) == 6
+    assert g._score.sharding == g._dist.shardings()["rows2d"]
+    assert g.train_score.shape == (1, N4)

@@ -12,6 +12,9 @@ plan must give the same, but for the ``routed`` corrections named
 below: there the record now says what ``build_tree`` does.  A pass's
 ``prologue`` key (PR 31) is younger than the dump and follows its
 ``mxu``: the int8-valued passes build their right-hand side by words.
+So is ``row_state`` (PR 33): the dump's rows take the plan's
+(``test_row_state_ladder`` says what it has to be); the
+``criteo67x4.*`` rows were dumped with it and pin it.
 """
 import dataclasses
 import glob
@@ -69,6 +72,10 @@ def test_plan_matches_parent(case):
     want = _plain(row["record"])
     for rec in want["hist_tiling"].values():
         rec["prologue"] = "words" if rec["mxu"] == "int8" else "rows"
+    if "row_state" not in want:
+        want["row_state"] = plan.record["row_state"]
+        if "row_state" in plan.record["gates"]:
+            want["gates"]["row_state"] = plan.record["gates"]["row_state"]
     if case in ROUTED_CORRECTIONS:
         why = ROUTED_CORRECTIONS[case]
         want["routed"] = why is None
@@ -98,6 +105,52 @@ def test_record_routed_is_build_trees(case):
     assert record["gates"].get("routed") == why
 
 
+ROW_STATE = {
+    # the four ranks of the benchmark's cell: every device its own rows
+    "criteo67x4.fast@tpu": None,
+    "criteo67x4.fast@cpu": None,
+    "fast28.data4@tpu": None,
+    # what the ladder refuses, each with the first reason that does
+    "criteo67x4.goss@tpu": "GOSS ranks the whole job's gradients",
+    "criteo67x4.lambdarank@tpu": "objective=lambdarank: a row's "
+                                 "gradient reads other rows' scores",
+    "criteo67.fast@tpu": "tree_learner=serial: one device holds every "
+                         "row",
+    "fast28.voting4@tpu": "tree_learner=voting keeps the replicated",
+    "fast28.data2d4@tpu": "tree_learner=data2d keeps the replicated",
+    "defaults28.data4@tpu": "fused_iters <= 1",
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_STATE))
+def test_row_state_ladder(case):
+    """Where the per-row state lives, and the first reason where it is
+    not the shard (``models/tier.py`` ``_row_state_gate``)."""
+    row = GOLDEN[case]
+    record = plan_tier(Config(dict(row["params"], verbose=-1)),
+                       _facts(row)).record
+    why = ROW_STATE[case]
+    assert record["row_state"] == ("shard" if why is None
+                                   else "replicated")
+    assert record["gates"].get("row_state", "").startswith(why or "") \
+        and ("row_state" in record["gates"]) == (why is not None)
+
+
+@pytest.mark.parametrize("extra, why", [
+    ({"bagging_fraction": 0.8, "bagging_freq": 1}, "the bagging mask"),
+    ({"boosting": "mvs"}, "MVS thresholds"),
+    ({"boosting": "dart"}, "boosting=dart"),
+    ({"fused_iters": 1}, "fused_iters <= 1"),
+    ({"hbm_budget_mb": 64.0}, "paged training"),
+])
+def test_row_state_refusals(extra, why):
+    row = GOLDEN["criteo67x4.fast@tpu"]
+    record = plan_tier(Config(dict(row["params"], verbose=-1, **extra)),
+                       _facts(row)).record
+    assert record["row_state"] == "replicated"
+    assert record["gates"]["row_state"].startswith(why)
+
+
 def _cells():
     return sorted(os.path.basename(p)[:-len(".json")]
                   for p in glob.glob(os.path.join(BENCH, "workloads",
@@ -114,14 +167,19 @@ def test_workload_expect_tier(cell_name):
         sys.path.insert(0, BENCH)
     from harness.cells import load_cell
     cell = load_cell(cell_name)
+    from lightgbm_tpu.parallel.learners import pad_features_for
     config = Config(dict(cell.params))
     features = int(cell.config["features"])
     # 255 bins a feature at the cells' rows: the device width is the
     # next power of two
     max_bin = 1 << (int(config.max_bin) - 1).bit_length()
+    # a cell on several chips names its learner and its ranks
+    chips = int(cell.workload["chips"])
+    learner = config.tree_learner if chips > 1 else "serial"
     record = plan_tier(config, TierFacts(
-        use_pallas=True, learner="serial", num_shards=1,
-        mesh_shape2d=None, features=features, g_cols=features,
+        use_pallas=True, learner=learner, num_shards=chips,
+        mesh_shape2d=None, features=features,
+        g_cols=pad_features_for(learner, chips, features),
         max_bin=max_bin, any_cat=False, any_missing=False, efb_groups=0,
         forced=(), use_pool=True,
         rows_per_block=int(config.tpu_rows_per_block), monotone=(),
