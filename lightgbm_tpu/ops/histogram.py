@@ -46,6 +46,16 @@ Value columns:
   (``_accumulate``, ``_onehot_int8``, ``_rhs_int8``).  The operand's
   dtype alone decides: float32 values keep the bf16 path, and the
   tier record's ``mxu`` says which a booster runs.
+- the ORDER of the int8 one-hot's rows is the kernel's own business:
+  the contraction sums over data rows, so a permutation of the
+  one-hot's rows is the same permutation of the accumulator's.  The
+  one-hot is made four rows to a 32-bit word at every bin count,
+  feature by feature on the 32-bin grid and slab by slab off it (the
+  coarse passes' 16 bins), whichever needs no regrouping
+  (``_onehot_int8``; the tier record's ``onehot``), and ONE helper on
+  the XLA side, ``_feature_bin``, which every wrapper calls on its
+  accumulator, puts the rows back to (feature, bin).  Nothing else
+  knows the order.
 - the per-row prologue, what a tile does before its contraction: each
   row's subset is ONE (1, T) index (the callers' selector, or the
   lane the kernel looks up with every lane table in one contraction:
@@ -189,6 +199,7 @@ class BinTiling(NamedTuple):
     f_pad: int
     fc: int
     t: int
+    b_pad: int      # the pass's bins, padded (``_pad_bins``)
 
     @property
     def one_chunk(self) -> bool:
@@ -219,13 +230,17 @@ class BinTiling(NamedTuple):
         ``prologue``: how a tile makes its right-hand side, ``words``
         from each row's subset index a 32-bit word at a time
         (:func:`_rhs_int8`), ``rows`` from it row by row
-        (:func:`_rhs_bf16`).  Both follow the values: the caller says
-        whether the pass is given int8 ones (ops/grow.py
+        (:func:`_rhs_bf16`); ``onehot``: the order the one-hot's rows
+        are built in, ``words`` or ``slabs`` by the bins where it is
+        int8 (:func:`_onehot_form`), ``plain`` (feature, bin) where it
+        is bf16.  All three follow the values: the caller says whether
+        the pass is given int8 ones (ops/grow.py
         ``GrowParams.int8_values``)."""
         return {"f": self.f, "f_pad": self.f_pad, "fc": self.fc,
                 "t": self.t, "xt_copied": False,
                 "mxu": "int8" if int8 else "bf16",
-                "prologue": "words" if int8 else "rows"}
+                "prologue": "words" if int8 else "rows",
+                "onehot": _onehot_form(self.b_pad) if int8 else "plain"}
 
 
 def bin_tiling(max_bin: int, f: int, cols: int = 128,
@@ -233,8 +248,8 @@ def bin_tiling(max_bin: int, f: int, cols: int = 128,
     """The tiling a pass over ``f`` stored features at ``max_bin`` bins
     runs with (``cols``: 128 for the batched passes, the value columns
     for the single-leaf one)."""
-    return BinTiling(f, *_tile(_pad_bins(max_bin), f, cols,
-                               rows_per_block))
+    b_pad = _pad_bins(max_bin)
+    return BinTiling(f, *_tile(b_pad, f, cols, rows_per_block), b_pad)
 
 
 def _miss_operand(miss_bin: jax.Array, til: BinTiling) -> jax.Array:
@@ -346,37 +361,172 @@ def _rhs_int8(lane: jax.Array, valsc: jax.Array, width: int
     return pltpu.bitcast(words, jnp.int8)
 
 
-def _onehot_int8(xb: jax.Array, b_pad: int) -> jax.Array:
-    """(R, T) int32 bins -> the (R * b_pad, T) int8 one-hot, row
-    ``r * b_pad + b`` holding ``xb[r] == b``.  ``R * b_pad`` is on the
-    (32, 128) int8 tile grid (:func:`_accumulate`).
+# ---- the int8 one-hot and the order of its rows ---------------------
+#
+# The contraction sums over data rows, so the ORDER of the one-hot's
+# rows is the kernel's own business: any permutation gives the same
+# accumulator with its rows permuted.  Three functions know the order
+# and nothing else does: :func:`_onehot_form` names it,
+# :func:`_onehot_int8` builds the one-hot in it, and
+# :func:`_feature_bin` (through :func:`_rows_to_feature_bin`) puts the
+# accumulator's rows back to (feature, bin) in XLA, where every wrapper
+# reshapes and moves the accumulator's axes anyway.
 
-    Where ``b_pad`` is a multiple of 32 the one-hot is made four rows
-    to a 32-bit word with no narrowing: int8 rows ``4j .. 4j + 3`` are
-    the bytes of int32 row ``j`` (``pltpu.bitcast``), so word ``q`` of
-    a feature is ``1 << 8 * (x & 3)`` where ``x >> 2 == q`` and 0
+_TAIL = 4       # a features' tail of up to this many rows: own slabs
+
+
+def _onehot_form(b_pad: int) -> str:
+    """The order :func:`_onehot_int8` builds in at ``b_pad`` bins:
+    ``words`` (feature by feature) where the ``b_pad / 4`` words of a
+    feature fill whole 8-sublane groups, ``slabs`` elsewhere."""
+    return "words" if b_pad % 32 == 0 else "slabs"
+
+
+def _slab_split(R: int) -> Tuple[int, int]:
+    """``R`` feature rows in slab order: (rows of the slabs, a
+    multiple of 8; features of the tail, which has slabs of ``_TAIL``
+    rows of its own, or 0)."""
+    tail = R % 8
+    if tail > _TAIL:
+        tail = 0                    # 5 to 7 rows: a group of 8 as it is
+    return -(-(R - tail) // 8) * 8, tail
+
+
+def _onehot_rows(R: int, b_pad: int) -> int:
+    """int8 one-hot rows :func:`_onehot_int8` makes of ``R`` features."""
+    if _onehot_form(b_pad) == "words":
+        return R * b_pad
+    main, tail = _slab_split(R)
+    return (main + (_TAIL if tail else 0)) * b_pad
+
+
+def _onehot_int8(xb: jax.Array, b_pad: int) -> jax.Array:
+    """(R, T) int32 bins -> the int8 one-hot, ``_onehot_rows(R, b_pad)``
+    rows on the (32, 128) int8 tile grid; a bin outside ``[0, b_pad)``
+    counts nowhere.
+
+    Made four rows to a 32-bit WORD with no narrowing: int8 rows
+    ``4j .. 4j + 3`` are the bytes of int32 row ``j``
+    (``pltpu.bitcast``), so the word that holds bins ``4q .. 4q + 3``
+    of a feature is ``1 << 8 * (x & 3)`` where ``x >> 2 == q`` and 0
     elsewhere (a bin outside ``[0, b_pad)`` meets no ``q``): a compare
-    and a select a WORD, against a compare, a select and two
-    narrowing packs an ELEMENT.  The words regroup ``(R, b_pad / 4,
-    T) -> (R * b_pad / 4, T)`` for nothing only where ``b_pad / 4``
-    fills the 8 sublanes; at 16 bins it is a relayout, and the plain
-    form (compare in int32, regroup, narrow) is the faster one: 30.2
-    against 52.6 ms a routed coarse pass of 20M x 67, where at 32 bins
-    the words take a refine pass from 40.2 to 34.8 (PERF.md, PR 29)."""
+    and a select a WORD, where the element-by-element form (compare in
+    int32, regroup ``(R, b_pad, T) -> (R * b_pad, T)``, narrow twice)
+    was some eleven vector operations a (32, 128) tile and set a coarse
+    pass's pace at 18.2 to 18.5 us a one-hot row against the MXU's 13
+    (PERF.md, PR 31).  The ``Q = b_pad / 4`` words of a feature lie
+
+    - ``words``, ``Q`` a multiple of 8: feature by feature, word
+      ``r * Q + q``: int8 row ``r * b_pad + b`` holds ``xb[r] == b``.
+      The regrouping ``(R, Q, T) -> (R * Q, T)`` fills whole sublane
+      groups and costs nothing;
+    - ``slabs``, every other ``Q`` (16 bins: 4): there that regrouping
+      is a relayout (52.6 against 30.2 ms a routed coarse pass of
+      20M x 67; PERF.md, PR 29), so no three-dimensional value exists:
+      word ``q`` of EVERY feature is one compare and select on the
+      ``(R8, T)`` block as it stands, and the ``Q`` slabs are
+      concatenated on the sublane grid: word ``q * R8 + r``, int8 row
+      ``4 * (q * R8 + r) + k`` holds bin ``4q + k`` of feature ``r``.
+      A features' tail of up to 4 rows (28 = 24 + 4, 67 = 64 + 3)
+      would add 8 rows to every slab; it follows the whole groups in
+      slabs of 4 rows of its own, two to a sublane group (the tail
+      tiled twice, compared with a per-sublane ``q``): 448 and 1088
+      one-hot rows stream at 16 bins, not 512 and 1152, which is 0.6
+      to 1.0 ms of a routed coarse pass at the benchmark's shapes
+      (9.60 against 10.58 ms at 21M x 28, 18.40 against 19.23 at
+      20M x 67, where the plain form took 12.05 and 23.66; a row of
+      the slabs costs 13.1 to 14.0 us: PERF.md, PR 34)."""
+    from jax.experimental.pallas import tpu as pltpu
     R, T = xb.shape
-    if b_pad % 32 == 0:
-        from jax.experimental.pallas import tpu as pltpu
-        q = b_pad // 4
-        byte = jnp.left_shift(1, (xb & 3) << 3)              # (R, T)
+    Q = b_pad // 4
+
+    def parts(x):
+        return x >> 2, jnp.left_shift(1, (x & 3) << 3)
+
+    if _onehot_form(b_pad) == "words":
+        hi, byte = parts(xb)
         words = jnp.where(
-            (xb >> 2)[:, None, :] ==
-            jax.lax.broadcasted_iota(jnp.int32, (R, q, T), 1),
-            byte[:, None, :], 0)
-        return pltpu.bitcast(words.reshape(R * q, T), jnp.int8)
-    onehot = (xb[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
-              ).astype(jnp.int32)
-    return onehot.reshape(R * b_pad, T).astype(jnp.int8)
+            hi[:, None, :] ==
+            jax.lax.broadcasted_iota(jnp.int32, (R, Q, T), 1),
+            byte[:, None, :], 0).reshape(R * Q, T)
+        return pltpu.bitcast(words, jnp.int8)
+
+    def rows(lo, hi_, n):
+        """Feature rows ``lo .. hi_`` up to ``n`` rows of bin -1."""
+        if hi_ - lo == n:
+            return xb[lo:hi_]
+        return jnp.concatenate(
+            [xb[lo:hi_], jnp.full((n - (hi_ - lo), T), -1, jnp.int32)],
+            axis=0)
+
+    main, tail = _slab_split(R)
+    slabs = []
+    if main:
+        hi, byte = parts(rows(0, R - tail, main))
+        slabs = [jnp.where(hi == q, byte, 0) for q in range(Q)]
+    if tail:
+        hi, byte = parts(jnp.concatenate(
+            [rows(R - tail, R, _TAIL)] * 2, axis=0))         # (8, T)
+        q01 = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0) // _TAIL
+        # b_pad is a multiple of 8: Q is even
+        slabs += [jnp.where(hi == q01 + q, byte, 0)
+                  for q in range(0, Q, 2)]
+    return pltpu.bitcast(jnp.concatenate(slabs, axis=0), jnp.int8)
+
+
+def _rows_to_feature_bin(acc: jax.Array, R: int, b_pad: int) -> jax.Array:
+    """(..., rows, lanes) in :func:`_onehot_int8`'s order of ``R``
+    features (rows beyond ``_onehot_rows`` are ignored) ->
+    (..., R, b_pad, lanes)."""
+    lead, lanes = acc.shape[:-2], acc.shape[-1]
+    if _onehot_form(b_pad) == "words":
+        return acc[..., :R * b_pad, :].reshape(*lead, R, b_pad, lanes)
+    n, Q = len(lead), b_pad // 4
+
+    def unslab(lo, r, keep):
+        """``r * b_pad`` rows from ``lo`` on, Q slabs of ``r`` words,
+        as the first ``keep`` features' (bins, lanes)."""
+        blk = acc[..., lo:lo + r * b_pad, :].reshape(*lead, Q, r, 4, lanes)
+        return jnp.swapaxes(blk, n, n + 1).reshape(
+            *lead, r, b_pad, lanes)[..., :keep, :, :]
+
+    main, tail = _slab_split(R)
+    parts = ([unslab(0, main, R - tail)] if main else []) + \
+        ([unslab(main * b_pad, _TAIL, tail)] if tail else [])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=n)
+
+
+def _feature_bin(out: jax.Array, til: BinTiling, int8: bool) -> jax.Array:
+    """A pass's accumulator (f_pad * b_pad, lanes), as its kernel left
+    it, -> (f, b_pad, lanes): the ONE place outside the kernel that
+    knows the order of the accumulator's rows.  A float32-valued pass
+    (bf16 one-hot) keeps (feature, bin); an int8-valued one has each
+    feature block's rows in :func:`_onehot_int8`'s order.  Integer
+    sums moved, not re-added: every output bit is what it was."""
+    f, f_pad, fc, _, b_pad = til
+    lanes = out.shape[1]
+    if not int8:
+        return out.reshape(f_pad, b_pad, lanes)[:f]
+    blocks = out.reshape(f_pad // fc, fc * b_pad, lanes)
+    return _rows_to_feature_bin(blocks, til.block_rows, b_pad).reshape(
+        -1, b_pad, lanes)[:f]
+
+
+def _batched_hists(out: jax.Array, til: BinTiling, int8: bool,
+                   width: int, cols: int, n_bins: int, two_col: bool,
+                   exact: bool) -> jax.Array:
+    """A batched pass's accumulator (f_pad * b_pad, 128 or 256), lane
+    ``w * cols + c`` column ``c`` of subset ``w`` -> (width, F,
+    n_bins, 3)."""
+    out = _feature_bin(out[:, :cols * width], til, int8).reshape(
+        til.f, til.b_pad, width, cols)
+    if two_col:
+        # count := hess copy keeps every downstream shape at (..., 3);
+        # the gate guarantees nothing reads it as a real count
+        out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
+    elif not exact:
+        out = out[..., :3] + out[..., 3:]    # hi + lo
+    return jnp.moveaxis(out[:, :n_bins], 2, 0)
 
 
 def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
@@ -387,7 +537,7 @@ def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
     xb (R, T) int32: the bin each row counts in, per feature (a value
     outside [0, b_pad) counts nowhere); rhs (128 or 256, T) bf16 or
     int8; out_ref (>= R * b_pad, lanes) f32.  The one-hot is laid out
-    (R*B, T) so the dot STREAMS R*B rows through the MXU while the
+    (rows, T) so the dot STREAMS its rows through the MXU while the
     tiny (T, lanes) value matrix sits stationary as weights; the
     reverse orientation reloads K x B weight tiles to stream only a
     few rows and is ~100x slower.
@@ -398,13 +548,15 @@ def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
     faster mode; a tile's partial sum is at most ``T`` x 127 = 2.08M
     at ``T`` = 16384, exact in int32 and in the float32 it is
     converted to and added into, so every output bit is what the bf16
-    contraction of the same integers gives.  Anything else: bf16 x
-    bf16 -> f32.
+    contraction of the same integers gives.  Its one-hot's rows, and
+    so the accumulator block's, are in :func:`_onehot_int8`'s order,
+    which :func:`_feature_bin` undoes outside the kernel.  Anything
+    else: bf16 x bf16 -> f32, rows in (feature, bin) order.
 
     The FEATURE TAIL is made here (see :class:`BinTiling`).  One
     chunk: ``xb`` has the stored features' rows only, fewer than the
     accumulator block; their one-hot rows alone are built, streamed
-    and added into the block's head, and the tail's rows keep the
+    and added into the block's head, and the rows beyond keep the
     zeros ``_init`` wrote.  Several chunks (``f_mask`` > 0): the last
     block overhangs the stored matrix, and rows whose feature index
     ``row0 + i`` is not below ``f_mask`` hold whatever VMEM held:
@@ -414,15 +566,6 @@ def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
         feat = row0 + jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
         xb = jnp.where(feat < f_mask, xb, -1)
     if rhs.dtype == jnp.int8:
-        # int8 tiles are (32, 128): one-hot rows off that grid (67
-        # features x 16 bins = 33.5 tiles) go up to the next multiple
-        # on feature rows of bin -1, which match nothing (1088 rows,
-        # not the 1152 of the whole accumulator block)
-        extra = -R % (32 // math.gcd(b_pad, 32))
-        if extra:
-            xb = jnp.concatenate(
-                [xb, jnp.full((extra, T), -1, jnp.int32)], axis=0)
-            R += extra
         acc = jax.lax.dot_general(
             _onehot_int8(xb, b_pad), rhs, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32).astype(jnp.float32)
@@ -434,10 +577,11 @@ def _accumulate(out_ref, xb: jax.Array, rhs: jax.Array, b_pad: int,
             onehot.reshape(R * b_pad, T), rhs.T,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (R*B, lanes)
-    if R * b_pad == out_ref.shape[0]:
+    assert acc.shape[0] <= out_ref.shape[0], (acc.shape, out_ref.shape)
+    if acc.shape[0] == out_ref.shape[0]:
         out_ref[...] += acc
     else:
-        out_ref[:R * b_pad, :] += acc
+        out_ref[:acc.shape[0], :] += acc
 
 
 def _hist_kernel(x_ref, v_ref, out_ref, *, b_pad: int, cols: int,
@@ -484,7 +628,7 @@ def histogram_pallas(bins_t: jax.Array, vals: jax.Array, max_bin: int,
     b_pad = _pad_bins(max_bin)
     cols = 3 if exact else 6
     til = bin_tiling(max_bin, f, cols, rows_per_block)
-    _, f_pad, fc, t = til
+    f_pad, fc, t = til.f_pad, til.fc, til.t
     assert n % t == 0, (n, t)
     vt = vals.astype(jnp.float32).T  # (3, N)
 
@@ -506,7 +650,7 @@ def histogram_pallas(bins_t: jax.Array, vals: jax.Array, max_bin: int,
     )(bins_t, vt)
     if not exact:
         out = out[:, :3] + out[:, 3:]  # hi + lo passes
-    return out.reshape(f_pad, b_pad, 3)[:f, :max_bin]
+    return _feature_bin(out, til, False)[:, :max_bin]
 
 
 def _pad_rows(n: int, block: int) -> int:
@@ -612,7 +756,7 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
     W = width
     assert W * cols <= 128, (W, cols)
     til = bin_tiling(max_bin, f, 128, rows_per_block)
-    _, f_pad, fc, t = til
+    f_pad, fc, t = til.f_pad, til.fc, til.t
     assert n % t == 0, (n, t)
     # narrow value operand: quantized gradients are small ints, exact
     # in int8/bf16 — keep the (3, N) operand at 1 byte/entry (it is
@@ -648,14 +792,8 @@ def histogram_pallas_multi(bins_t: jax.Array, vals: jax.Array,
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
-    out = out[:, :cols * W].reshape(f_pad, b_pad, W, cols)
-    if two_col:
-        # count := hess copy keeps every downstream shape at (..., 3);
-        # the gate guarantees nothing reads it as a real count
-        out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
-    elif not exact:
-        out = out[..., :3] + out[..., 3:]    # hi + lo
-    return jnp.moveaxis(out[:f, :max_bin], 2, 0)   # (W, F, B, 3)
+    return _batched_hists(out, til, vt.dtype == jnp.int8, W, cols,
+                          max_bin, two_col, exact)     # (W, F, B, 3)
 
 
 def histogram_segsum_multi(bins_t: jax.Array, vals: jax.Array,
@@ -835,7 +973,7 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
     W = width
     assert W * cols <= 128, (W, cols)
     til = bin_tiling(r_bins, f, 128, rows_per_block)
-    _, f_pad, fc, t = til
+    f_pad, fc, t = til.f_pad, til.fc, til.t
     assert n % t == 0, (n, t)
     if vals.dtype == jnp.int8:               # see histogram_pallas_multi
         assert exact or two_col, "int8 values need exact/two_col"
@@ -872,12 +1010,8 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
-    out = out[:, :cols * W].reshape(f_pad, r_pad, W, cols)
-    if two_col:
-        out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
-    elif not exact:
-        out = out[..., :3] + out[..., 3:]
-    return jnp.moveaxis(out[:f, :r_bins], 2, 0)    # (W, F, R, 3)
+    return _batched_hists(out, til, vt.dtype == jnp.int8, W, cols,
+                          r_bins, two_col, exact)      # (W, F, R, 3)
 
 
 # ---- routed multi-leaf pass ----------------------------------------
@@ -1031,7 +1165,7 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     Wl = width
     assert Wl * cols <= 128, (Wl, cols)
     til = bin_tiling(max_bin, f, 128, rows_per_block)
-    _, f_pad, fc, t = til
+    f_pad, fc, t = til.f_pad, til.fc, til.t
     assert til.one_chunk, "routed kernel needs a single feature chunk"
     assert n % t == 0, (n, t)
     if vals.dtype == jnp.int8:               # see histogram_pallas_multi
@@ -1088,12 +1222,8 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
-    out = out[:, :cols * Wl].reshape(f_pad, b_pad, Wl, cols)
-    if two_col:
-        out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
-    elif not exact:
-        out = out[..., :3] + out[..., 3:]
-    hist = jnp.moveaxis(out[:f, :max_bin], 2, 0)
+    hist = _batched_hists(out, til, vt.dtype == jnp.int8, Wl, cols,
+                          max_bin, two_col, exact)
     return hist, li_new[0], sel[0]
 
 
@@ -1178,7 +1308,7 @@ def histogram_pallas_multi_win_lanes(bins_t: jax.Array, vals: jax.Array,
     W = width
     assert W * cols <= 128, (W, cols)
     til = bin_tiling(r_bins, f, 128, rows_per_block)
-    _, f_pad, fc, t = til
+    f_pad, fc, t = til.f_pad, til.fc, til.t
     assert n % t == 0, (n, t)
     if vals.dtype == jnp.int8:
         assert exact or two_col, "int8 values need exact/two_col"
@@ -1215,12 +1345,8 @@ def histogram_pallas_multi_win_lanes(bins_t: jax.Array, vals: jax.Array,
         compiler_params=_compiler_params(),
         interpret=pallas_interpret(),
     )(*operands)
-    out = out[:, :cols * W].reshape(f_pad, r_pad, W, cols)
-    if two_col:
-        out = jnp.concatenate([out, out[..., 1:2]], axis=-1)
-    elif not exact:
-        out = out[..., :3] + out[..., 3:]
-    return jnp.moveaxis(out[:f, :r_bins], 2, 0)    # (W, F, R, 3)
+    return _batched_hists(out, til, vt.dtype == jnp.int8, W, cols,
+                          r_bins, two_col, exact)      # (W, F, R, 3)
 
 
 def histogram_segsum_multi_win_lanes(bins_t, vals, leaf_idx, lane_ids,

@@ -10,10 +10,15 @@ mode on the CPU:
 
 - the int8-valued call equals the same call on the same integers as
   float32 bit for bit, and its segsum twin, for the four batched
-  wrappers at 28 and 67 features, 8, 16 and 32 bins (67 at 8 chunks
-  its features, the last block overhanging the matrix; 67 at 16 is
-  off the int8 tile grid: 1072 one-hot rows), two-column and
+  wrappers at 28 and 67 features, 8, 16, 24 and 32 bins (67 at 8 and
+  at 24 chunks its features, the last block overhanging the matrix;
+  67 at 16 is off the int8 tile grid: 1072 one-hot rows, built as 64
+  features' slabs and a tail's), 68 and 30 at 16, two-column and
   three-column values, with and without a missing bin;
+- the int8 one-hot in the order it is built in
+  (``ops/histogram._onehot_int8``: feature by feature, or slab by
+  slab off the 32-bin grid), put back by the wrappers' helper
+  (``_rows_to_feature_bin``), equals the plain one-hot row for row;
 - the largest partial sum of a tile (16384 rows of +127 or -127 in one
   bin) is exact;
 - the kernel's jaxpr: an int8 x int8 -> int32 ``dot_general`` and
@@ -43,18 +48,20 @@ BATCHED = ["multi", "multi_win", "multi_routed", "multi_win_lanes"]
 
 def _cases():
     for wrapper in BATCHED:
-        for f in (28, 67):
-            for bins in (8, 16, 32):
-                if wrapper == "multi_routed" and \
-                        not H.bin_tiling(bins, f, 128, RPB).one_chunk:
-                    continue        # the routed pass is one chunk only
-                for two_col in (True, False):
-                    for miss in (False, True):
-                        yield pytest.param(
-                            wrapper, f, bins, two_col, miss,
-                            id=f"{wrapper}-F{f}-B{bins}-"
-                               f"{'two_col' if two_col else 'exact'}-"
-                               f"{'miss' if miss else 'nomiss'}")
+        # 68 and 30 at 16 bins: a tail of 4 of 68 and one padded to 8;
+        # 67 at 8 and at 24 bins: chunked (five of 16)
+        for f, bins in [(f, b) for f in (28, 67) for b in (8, 16, 24, 32)
+                        ] + [(68, 16), (30, 16)]:
+            if wrapper == "multi_routed" and \
+                    not H.bin_tiling(bins, f, 128, RPB).one_chunk:
+                continue            # the routed pass is one chunk only
+            for two_col in (True, False):
+                for miss in (False, True):
+                    yield pytest.param(
+                        wrapper, f, bins, two_col, miss,
+                        id=f"{wrapper}-F{f}-B{bins}-"
+                           f"{'two_col' if two_col else 'exact'}-"
+                           f"{'miss' if miss else 'nomiss'}")
 
 
 @pytest.mark.parametrize("wrapper,f,bins,two_col,miss", _cases())
@@ -80,6 +87,48 @@ def test_the_chunked_case_overhangs():
     til = H.bin_tiling(8, 67, 128, RPB)
     assert (til.f_pad, til.fc, til.f_mask) == (80, 16, 67)
     assert (67 * H._pad_bins(16)) % 32 == 16
+
+
+@pytest.mark.parametrize("R", [4, 8, 28, 67, 68, 72])
+@pytest.mark.parametrize("b_pad", [8, 16, 24, 32, 48, 64])
+def test_onehot_in_its_order_is_the_plain_onehot(b_pad, R):
+    """The one-hot as the kernel builds it, its rows put back to
+    (feature, bin) by the helper every wrapper calls on the
+    accumulator, against the plain compare; bins below 0 and at or
+    above ``b_pad`` among the values count nowhere."""
+    import jax.experimental.pallas as pl
+    T = 256
+    rng = np.random.RandomState(R * 100 + b_pad)
+    x = rng.randint(-6, b_pad + 6, size=(R, T)).astype(np.int32)
+    x[:, :8] = np.array([-5, -4, -1, 0, b_pad - 1, b_pad, b_pad + 3,
+                         b_pad + 4])
+    rows = H._onehot_rows(R, b_pad)
+    assert rows % 32 == 0           # whole (32, 128) int8 tiles
+    assert R * b_pad <= rows <= -(-R // 8) * 8 * b_pad
+    assert H._onehot_form(b_pad) == ("words" if b_pad in (32, 64)
+                                     else "slabs")
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = H._onehot_int8(x_ref[...], b_pad)
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((rows, T), jnp.int8),
+        interpret=True)(jnp.asarray(x))
+    want = (x[:, None, :] == np.arange(b_pad)[None, :, None])
+    np.testing.assert_array_equal(
+        np.asarray(H._rows_to_feature_bin(got, R, b_pad)),
+        want.astype(np.int8))
+    # and nothing in the rows the helper leaves out
+    assert int(np.asarray(got, np.int32).sum()) == int(want.sum()) > 0
+
+
+def test_rows_streamed_at_the_cells_shapes():
+    """What the coarse passes of the benchmark's cells stream: a tail
+    of 4 (of 28, of 68) or 3 (of 67) features is built as groups of
+    its own, not padded to the 8 rows of a slab."""
+    assert [H._onehot_rows(f, 16) for f in (28, 67, 68, 30, 72)] == [
+        448, 1088, 1088, 512, 1152]
+    assert [H._onehot_rows(f, 32) for f in (28, 67, 68)] == [
+        896, 2144, 2176]
 
 
 @pytest.mark.parametrize("wrapper", ["multi", "multi_win_lanes"])
@@ -283,3 +332,4 @@ def test_float_job_records_bf16(monkeypatch):
     tiling = g.tier_decision["hist_tiling"]
     assert tiling and not g.grow_params.int8_values
     assert {rec["mxu"] for rec in tiling.values()} == {"bf16"}
+    assert {rec["onehot"] for rec in tiling.values()} == {"plain"}

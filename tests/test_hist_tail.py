@@ -10,7 +10,10 @@ in front of a pass.  Pinned here, in interpret mode on the CPU:
   (28 and 67 features at 16 bins, 67 at 32, 5), without one (28 at 32),
   and at a chunked shape whose last block overhangs the matrix (67 at
   8 bins: five chunks of 16), with and without ``miss_bin``, uint8
-  storage, int8 values (equality is exact);
+  storage, int8 values (equality is exact); and where the int8
+  one-hot is built slab by slab (8, 16 and 24 bins), with a features'
+  tail in groups of its own (28, 67, 68), one padded to 8 (30) and
+  none (chunks of 16);
 - the copy is gone: the wrapper's jaxpr holds no ``pad``,
   ``concatenate`` or ``dynamic_update_slice`` of an N-column array
   outside the ``pallas_call``, and a booster built with the ``fast``
@@ -29,8 +32,13 @@ from lightgbm_tpu.ops import histogram as H
 N, RPB, W, FINE = 512, 256, 8, 256
 
 # (features, bins of the pass): tail 28->32, none, 67->72, 67->68, 5->8,
-# and 67->80 in five chunks of 16 (the last holds 3 stored features)
-SHAPES = [(28, 16), (28, 32), (67, 16), (67, 32), (5, 16), (67, 8)]
+# and 67->80 in five chunks of 16 (the last holds 3 stored features);
+# then the int8 one-hot's slab order (ops/histogram._onehot_int8) at
+# 8, 16 and 24 bins with a features' tail of 4, 3, 4 of 68 and 6 rows
+# (28 -> 24 + 4, 67 -> 64 + 3, 30 -> 32), and chunked (67 at 24 bins:
+# five chunks of 16, no tail)
+SHAPES = [(28, 16), (28, 32), (67, 16), (67, 32), (5, 16), (67, 8),
+          (28, 8), (28, 24), (68, 16), (30, 16), (67, 24)]
 WRAPPERS = ["single", "multi", "multi_win", "multi_routed",
             "multi_win_lanes"]
 
@@ -88,7 +96,7 @@ def _run(wrapper, d, bins, pallas: bool, two_col: bool = False,
     """One pass through the Pallas wrapper (its values int8, or the
     same integers as float32) or its segsum twin, as a tuple of
     arrays."""
-    shift = (FINE // bins).bit_length() - 1      # 256 fine -> `bins`
+    shift = ((FINE - 1) // bins).bit_length()    # 256 fine -> `bins`
     vals = d["v8"] if pallas and int8 else d["vf"]
     kw = dict(exact=True) if pallas else {}
     if wrapper != "single":
@@ -141,9 +149,12 @@ def test_tiling_of_the_cases():
     """The shapes above are the cases their names say."""
     t = {s: H.bin_tiling(s[1], s[0], 128, RPB) for s in SHAPES}
     assert [(t[s].f_pad, t[s].fc) for s in SHAPES] == [
-        (32, 32), (28, 28), (72, 72), (68, 68), (8, 8), (80, 16)]
-    assert [t[s].block_rows for s in SHAPES] == [28, 28, 67, 67, 5, 16]
-    assert [t[s].f_mask for s in SHAPES] == [0, 0, 0, 0, 0, 67]
+        (32, 32), (28, 28), (72, 72), (68, 68), (8, 8), (80, 16),
+        (32, 32), (32, 32), (72, 72), (32, 32), (80, 16)]
+    assert [t[s].block_rows for s in SHAPES] == [
+        28, 28, 67, 67, 5, 16, 28, 28, 68, 30, 16]
+    assert [t[s].f_mask for s in SHAPES] == [
+        0, 0, 0, 0, 0, 67, 0, 0, 0, 0, 67]
     assert not any(t[s].record()["xt_copied"] for s in SHAPES)
 
 
@@ -208,3 +219,6 @@ def test_fast_job_records_its_tiling(monkeypatch, f, extra, kinds):
         assert rec["xt_copied"] is False
         assert rec["t"] == 1024
         assert rec["mxu"] == "int8"     # quantized: int8 values
+        # 16 coarse bins: the one-hot slab by slab; the 32-bin window:
+        # feature by feature
+        assert rec["onehot"] == {"coarse": "slabs", "refine": "words"}[kind]

@@ -14,7 +14,12 @@ below: there the record now says what ``build_tree`` does.  A pass's
 ``mxu``: the int8-valued passes build their right-hand side by words.
 So is ``row_state`` (PR 33): the dump's rows take the plan's
 (``test_row_state_ladder`` says what it has to be); the
-``criteo67x4.*`` rows were dumped with it and pin it.
+``criteo67x4.*`` rows were dumped with it and pin it.  A pass's
+``onehot`` (PR 34: the order the int8 one-hot is built in) was written
+into the dump's rows when it came: ``slabs`` at the coarse passes' 16
+and 8 bins, ``words`` on the 32-bin grid, ``plain`` where the pass
+contracts in bf16 (``test_onehot_follows_the_bins`` says so of every
+row).
 """
 import dataclasses
 import glob
@@ -83,6 +88,29 @@ def test_plan_matches_parent(case):
         if why is not None:
             want["gates"]["routed"] = why
     assert _plain(plan.record) == want
+
+
+def test_onehot_follows_the_bins():
+    """Every row's ``onehot`` is what its pass's type and bins give,
+    reckoned here from the row's facts: bf16 passes build the plain
+    one-hot; int8 ones by words feature by feature on the 32-bin grid
+    and slab by slab off it."""
+    seen = set()
+    for case, row in GOLDEN.items():
+        gp = row["grow_params"]
+        bins = row["facts"]["max_bin"]
+        kinds = {"full": bins, "root": bins}
+        if gp["refine_shift"]:
+            kinds["coarse"], kinds["refine"] = c2f_bins(
+                bins, gp["refine_shift"], gp["split"]["any_missing"])
+        for kind, rec in row["record"]["hist_tiling"].items():
+            b_pad = -(-kinds[kind] // 8) * 8
+            want = ("plain" if rec["mxu"] == "bf16" else
+                    "words" if b_pad % 32 == 0 else "slabs")
+            assert rec["onehot"] == want, (case, kind)
+            seen.add((kind, want))
+    assert {("coarse", "slabs"), ("refine", "words"), ("full", "slabs"),
+            ("full", "words"), ("root", "plain")} <= seen
 
 
 def test_corrections_are_cases():

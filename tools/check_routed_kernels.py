@@ -22,18 +22,25 @@ the kernels would run interpreted and prove nothing about Mosaic.  The
 oracles themselves are pinned on the CPU by tests/test_routed.py and
 this script closes the kernel half.
 
-Last, the contraction's type (``ops/histogram._accumulate``): the four
-batched kernels at both cells' shapes, rows and lanes (21M x 28 on 64
-two-column lanes, 20M x 67 on 42), int8 values (the int8 x int8 ->
-int32 contraction) against the same integers as float32 (the bf16
-one); every diff must be 0, and the ms a pass of each is printed
-(medians of 6) with the seconds Mosaic took to compile it, its one-hot
-rows, the us a one-hot row the pass measures (the unrouted pass of its
-kind at two feature counts, the difference over the rows added), the
-stream (rows x us a row) and PASS LESS STREAM, the per-row prologue
-that does not shrink with the feature rows: the kernel-alone table of
-PERF.md, by one command.
+Last, the order of the int8 one-hot's rows (``ops/histogram``
+``_onehot_int8``, ``_feature_bin``): the batched kernels at the three
+cells' shapes, rows and lanes (21M x 28 on 64 two-column lanes, 20M x
+67 on 42, and a chip of the four-rank cell: 16M x 68 on 42), the
+PARENT's build of the one-hot (PR 33: the plain form off the 32-bin
+grid, kept here as the other side) beside the change's, both in this
+one process, each side its own trace.  The routed coarse pass and the
+root's coarse pass (16 bins) are what the order changes; the controls
+are a refine pass of each kind (32 bins: the words on both sides) and
+the routed coarse pass on float32 values (the bf16 contraction), which
+also has to equal the int8 one.  Every diff must be 0, and the ms a
+pass of each side is printed (medians of 6) with the seconds Mosaic
+took to compile it, the one-hot rows it streams, the us a one-hot row
+the pass measures (the unrouted pass of its kind at two feature
+counts, the difference over the rows added), the stream (rows x us a
+row) and PASS LESS STREAM, what does not shrink with the feature rows:
+the kernel-alone table of PERF.md, by one command.
 """
+import contextlib
 import math
 import os
 import statistics
@@ -47,6 +54,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from chip_smoke import acquire_chip  # noqa: E402
+from lightgbm_tpu.ops import histogram as H  # noqa: E402
 from lightgbm_tpu.ops.histogram import (  # noqa: E402
     histogram_pallas_multi, histogram_pallas_multi_routed,
     histogram_pallas_multi_win, histogram_pallas_multi_win_lanes,
@@ -189,12 +197,69 @@ def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
                                shift=shift, miss_bin=mbj))})
 
 
-def check_int8_contraction(cell: str, F: int, rows: int, W: int,
-                           two_col: bool, rng) -> None:
-    """The four batched kernels as the ``fast`` job of ``cell`` runs
-    them (c2f shift 4: 16 coarse bins, a 32-bin window), int8 values
-    against the same integers as float32: equal bit for bit, and the
-    ms a pass of each."""
+def _parent_onehot_int8(xb, b_pad):
+    """The int8 one-hot as the parent commit (PR 33) built it, kept
+    here as the other side: off the 32-bin grid the plain form
+    (compare element by element in int32, regroup, narrow twice), rows
+    in (feature, bin) order, feature rows of bin -1 up to the int8
+    tile; on it the words, which both sides share."""
+    if b_pad % 32 == 0:
+        return _CHANGE["_onehot_int8"](xb, b_pad)
+    R, T = xb.shape
+    extra = -R % (32 // math.gcd(b_pad, 32))
+    if extra:
+        xb = jnp.concatenate(
+            [xb, jnp.full((extra, T), -1, jnp.int32)], axis=0)
+        R += extra
+    onehot = (xb[:, None, :] ==
+              jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
+              ).astype(jnp.int32)
+    return onehot.reshape(R * b_pad, T).astype(jnp.int8)
+
+
+def _parent_rows_to_feature_bin(acc, R, b_pad):
+    return acc[..., :R * b_pad, :].reshape(
+        *acc.shape[:-2], R, b_pad, acc.shape[-1])
+
+
+def _parent_onehot_rows(R, b_pad):
+    return (R + -R % (32 // math.gcd(b_pad, 32))) * b_pad
+
+
+_CHANGE = {k: getattr(H, k) for k in (
+    "_onehot_int8", "_rows_to_feature_bin", "_onehot_rows")}
+SIDES = {"parent": {"_onehot_int8": _parent_onehot_int8,
+                    "_rows_to_feature_bin": _parent_rows_to_feature_bin,
+                    "_onehot_rows": _parent_onehot_rows},
+         "change": _CHANGE}
+
+
+@contextlib.contextmanager
+def build_of(side: str):
+    """The three functions of ops/histogram.py that know the order of
+    the int8 one-hot's rows, as ``side`` has them, while a pass
+    traces."""
+    for k, v in SIDES[side].items():
+        setattr(H, k, v)
+    try:
+        yield
+    finally:
+        for k, v in _CHANGE.items():
+            setattr(H, k, v)
+
+
+def check_onehot_order(cell: str, F: int, rows: int, W: int,
+                       two_col: bool, rng) -> None:
+    """The batched kernels as the ``fast`` job of ``cell`` runs them
+    (c2f shift 4: 16 coarse bins, a 32-bin window), the parent's build
+    of the int8 one-hot beside the change's in one process: outputs
+    equal bit for bit, the ms a pass of each side, the one-hot rows it
+    streams and the us a row.  The routed coarse pass and the root's
+    coarse pass are what the one-hot's order changes; the controls are
+    a refine pass of each kind (32 bins: the words on both sides) and
+    the routed coarse pass on float32 values (the bf16 contraction),
+    which must equal the int8 one bit for bit and read the same time
+    on both sides."""
     n = -(-rows // RPB) * RPB
     xb = jnp.asarray(np.random.default_rng(F).integers(
         0, 255, size=(F, n), dtype=np.uint8))
@@ -213,75 +278,101 @@ def check_int8_contraction(cell: str, F: int, rows: int, W: int,
     lo_w = jnp.asarray(rng.randint(0, 255 - 32, size=(W, F))
                        .astype(np.int32))
     kw = dict(exact=True, two_col=two_col)
-    # (kind of pass, its bins): the unrouted pass of each kind takes
-    # the stream's slope below
+    # the un-jitted wrappers: each side jits its own, or the second
+    # would be served the first's trace
+    routed, lanes, multi, win = (f.__wrapped__ for f in (
+        histogram_pallas_multi_routed, histogram_pallas_multi_win_lanes,
+        histogram_pallas_multi, histogram_pallas_multi_win))
+    # name -> (kind of pass, the pass over features ``x``)
     passes = {
-        "routed coarse": ("coarse", lambda v: histogram_pallas_multi_routed(
-            xb, v, lb, tbl, 16, W, RPB, shift=4, mode="small", **kw)),
-        "win_lanes refine": (
-            "refine", lambda v: histogram_pallas_multi_win_lanes(
-                xb, v, lb, ids_w, lo_w, 32, W, RPB, **kw)),
-        "multi coarse": ("coarse", lambda v, x=xb: histogram_pallas_multi(
+        "routed coarse": ("coarse", lambda v, x, lo: routed(
+            x, v, lb, tbl, 16, W, RPB, shift=4, mode="small", **kw)),
+        "multi coarse (the root's)": ("coarse", lambda v, x, lo: multi(
             x, v, selw, 16, W, RPB, shift=4, **kw)),
-        "multi_win refine": (
-            "refine", lambda v, x=xb, lo=lo_w: histogram_pallas_multi_win(
-                x, v, selw, lo, 32, W, RPB, **kw)),
+        "win_lanes refine": ("refine", lambda v, x, lo: lanes(
+            x, v, lb, ids_w, lo, 32, W, RPB, **kw)),
+        "multi_win refine (the root's)": ("refine", lambda v, x, lo: win(
+            x, v, selw, lo, 32, W, RPB, **kw)),
     }
     bins = {"coarse": 16, "refine": 32}
 
-    def onehot_rows(f, b):
-        """The int8 one-hot rows a pass of ``f`` features streams
-        (``ops/histogram._accumulate``: up to the (32, 128) tile)."""
-        return (f + -f % (32 // math.gcd(b, 32))) * b
+    def timed(side, fn, *args):
+        """(outputs, median ms of 6, seconds to compile) of ``fn``
+        traced with ``side``'s build."""
+        traces = []
 
-    def timed(fn, *args):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))  # compiles
-        first = time.perf_counter() - t0
+        def traced(*a):             # a function of its own: its own trace
+            traces.append(side)
+            return fn(*a)
+        jitted = jax.jit(traced)
+        with build_of(side):
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(jitted(*args))  # compiles
+            first = time.perf_counter() - t0
+        assert traces == [side], traces
         ms = []
         for _ in range(6):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
+            jax.block_until_ready(jitted(*args))
             ms.append((time.perf_counter() - t0) * 1e3)
         med = statistics.median(ms)
         return jax.tree_util.tree_leaves(out), med, first - med / 1e3
 
-    ms8 = {}
-    for name, (_, fn) in passes.items():
-        took = {}
-
-        def pairs(fn=fn, took=took):
-            o8, took["int8"], took["compile_s"] = timed(fn, v8)
-            of, took["float32"], _ = timed(fn, vf)
-            return {f"out{i}": p for i, p in enumerate(zip(o8, of))}
-        report(f"[{cell}: {rows} x {F}, {W} lanes] {name}, int8 against "
-               f"float32 values", pairs)
-        if took:
-            ms8[name] = took["int8"]
-            print(f"    ms a pass: int8 {took['int8']:.2f}, float32 "
-                  f"{took.get('float32', float('nan')):.2f}; int8 kernel "
-                  f"compiled in {took['compile_s']:.1f} s", flush=True)
+    tag = f"[{cell}: {rows} x {F}, {W} lanes]"
+    took = {}                       # (pass, side) -> ms
+    for name, (kind, fn) in passes.items():
+        def pairs(name=name, fn=fn):
+            outs = {}
+            for side in SIDES:
+                outs[side], took[name, side], c = timed(
+                    side, fn, v8, xb, lo_w)
+                took[name, side, "compile_s"] = c
+            got = {f"{side} out{i}": p for side in SIDES if side != "parent"
+                   for i, p in enumerate(zip(outs[side], outs["parent"]))}
+            if name == "routed coarse":
+                for side in SIDES:
+                    outs[side + " f32"], took[name + " f32", side], _ = \
+                        timed(side, fn, vf, xb, lo_w)
+                got.update({f"f32 out{i}": p for i, p in enumerate(
+                    zip(outs["change"], outs["change f32"]))})
+                got.update({f"f32 sides out{i}": p for i, p in enumerate(
+                    zip(outs["change f32"], outs["parent f32"]))})
+            return got
+        report(f"{tag} {name}, int8 values, the change's build against "
+               f"the parent's", pairs)
     # what a one-hot row costs: the unrouted pass of each kind again at
-    # fewer features, the difference over the one-hot rows taken away
-    f2 = {28: 14, 67: 60}.get(F, F // 2)
+    # fewer features (whole sublane groups fewer, so both sides' rows
+    # fall alike), the difference over the one-hot rows taken away
+    f2 = F - (32 if F > 32 else 16)
     us_a_row = {}
-    for name, kind in (("multi coarse", "coarse"),
-                       ("multi_win refine", "refine")):
-        if name not in ms8:
-            continue
-        _, ms2, _ = timed(passes[name][1], v8, xb[:f2],
-                          *((lo_w[:, :f2],) if kind == "refine" else ()))
-        us_a_row[kind] = (ms8[name] - ms2) * 1e3 / (
-            onehot_rows(F, bins[kind]) - onehot_rows(f2, bins[kind]))
+    for name, kind in (("multi coarse (the root's)", "coarse"),
+                       ("multi_win refine (the root's)", "refine")):
+        for side in SIDES:
+            if (name, side) not in took:
+                continue
+            _, ms2, _ = timed(side, passes[name][1], v8, xb[:f2],
+                              lo_w[:, :f2])
+            r, r2 = (SIDES[side]["_onehot_rows"](f, bins[kind])
+                     for f in (F, f2))
+            us_a_row[kind, side] = (took[name, side] - ms2) * 1e3 / (r - r2)
     for name, (kind, _) in passes.items():
-        if name in ms8 and kind in us_a_row:
-            r = onehot_rows(F, bins[kind])
-            stream = r * us_a_row[kind] / 1e3
-            print(f"    [{cell}] {name}: {ms8[name]:.2f} ms a pass = "
-                  f"{r} one-hot rows x {us_a_row[kind]:.2f} us a row "
-                  f"({stream:.2f} ms of stream, slope of {F} against {f2} "
-                  f"features) + {ms8[name] - stream:.2f} ms PASS LESS "
-                  f"STREAM", flush=True)
+        for side in SIDES:
+            if (name, side) not in took or (kind, side) not in us_a_row:
+                continue
+            r = SIDES[side]["_onehot_rows"](F, bins[kind])
+            us = us_a_row[kind, side]
+            print(f"    {tag} {name}, {side}: {took[name, side]:.2f} ms a "
+                  f"pass = {r} one-hot rows x {us:.2f} us a row "
+                  f"({r * us / 1e3:.2f} ms of stream, slope of {F} against "
+                  f"{f2} features) + {took[name, side] - r * us / 1e3:.2f} "
+                  f"ms PASS LESS STREAM; compiled in "
+                  f"{took[name, side, 'compile_s']:.1f} s", flush=True)
+    for side in SIDES:
+        if ("routed coarse f32", side) in took:
+            print(f"    {tag} routed coarse on float32 values (bf16 "
+                  f"contraction), {side}: "
+                  f"{took['routed coarse f32', side]:.2f} ms a pass",
+                  flush=True)
 
 
 def check_leaf_stats(rng) -> None:
@@ -313,10 +404,11 @@ def main() -> int:
         check_bins(F, W, two_col, 63, 3, rng)
         check_bins(F, W, two_col, 255, 4, rng)
     check_leaf_stats(rng)
-    # (cell, features, rows, lanes, two-column)
+    # (cell, features a chip stores, rows a chip, lanes, two-column)
     for cell in (("higgs28.fast", 28, 21_000_000, 64, True),
-                 ("criteo67.fast", 67, 20_000_000, 42, False)):
-        check_int8_contraction(*cell, rng)
+                 ("criteo67.fast", 67, 20_000_000, 42, False),
+                 ("criteo67x4.fast", 68, 16_000_000, 42, False)):
+        check_onehot_order(*cell, rng)
     print("FAILED: " + ", ".join(FAILED) if FAILED
           else "ALL KERNEL CHECKS PASS")
     return 1 if FAILED else 0
