@@ -2586,7 +2586,9 @@ class GBDT:
         ``GROW_COUNTERS``) of the ``n_trees`` trees just fetched to the
         process counters: ``trees_grown``, ``hist_passes_coarse`` (one
         routing pass a wave), ``hist_passes_refine`` (the rest: c2f's
-        windowed passes), ``grow_waves`` and, on the wave tiers, where
+        windowed passes), ``grow_waves``, ``route_gather_waves`` (the
+        waves routed by the routing step, where the tier record's
+        ``route`` is ``gather``) and, on the wave tiers, where
         every lane a wave fills is one split, ``grow_lanes_live`` (the
         trees' splits) and ``grow_lanes_offered`` (waves x the wave's
         lanes); under the wave data learner also ``collective_bytes``
@@ -2621,6 +2623,11 @@ class GBDT:
         counters.incr("hist_passes_coarse", waves)
         counters.incr("hist_passes_refine", arm - waves)
         counters.incr("grow_waves", waves)
+        if self.tier_decision.get("route") == "gather":
+            # the waves whose rows the routing step routed
+            # (ops/histogram.py histogram_pallas_route): every wave,
+            # on a shape whose features chunk
+            counters.incr("route_gather_waves", waves)
         p = self.grow_params
         if p.wave:
             leaves = np.atleast_1d(recs["n_leaves"])[:n_trees]

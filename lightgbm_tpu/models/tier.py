@@ -19,7 +19,7 @@ from typing import NamedTuple, Optional, Tuple
 
 from ..config import Config
 from ..ops.grow import (DistConfig, GrowParams, batched_width, c2f_bins,
-                        routed_gate)
+                        route_kind, routed_gate)
 from ..ops.histogram import _pad_bins, bin_tiling, multi_width
 from ..ops.split import SplitParams
 from ..utils.log import Log
@@ -339,9 +339,10 @@ def plan_tier(config: Config, facts: TierFacts) -> TierPlan:
     passes = _passes(facts, grow_params)
     # build_tree's own answer, for the batched pass it would route in
     # (the coarse one under c2f) over the columns one device holds
-    why_routed = routed_gate(
-        grow_params, facts.kind,
-        passes.get("coarse", (facts.max_bin,))[0], facts.local_cols)
+    routed_pass = (grow_params, facts.kind,
+                   passes.get("coarse", (facts.max_bin,))[0],
+                   facts.local_cols)
+    why_routed = routed_gate(*routed_pass)
     # how each pass tiles the stored bin matrix (ops/histogram.py
     # BinTiling).  The batched passes contract in int8 where their
     # values are int8; the single-leaf pass ("root") takes float32
@@ -369,6 +370,12 @@ def plan_tier(config: Config, facts: TierFacts) -> TierPlan:
         "gates": gates,
         "split_kernel": split_kernel,
         "routed": why_routed is None,
+        # where a wave's rows are routed: inside the routed pass
+        # (``kernel``), by the routing step, whose kernel fetches the
+        # lanes' split columns itself, ahead of a pass in several
+        # feature chunks (``gather``), or by XLA's select chain
+        # (``xla``)
+        "route": route_kind(*routed_pass),
         "c2f": bool(refine_shift),
         "refine_shift": refine_shift,
         "quantize": grow_params.quantize,

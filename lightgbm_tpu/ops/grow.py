@@ -51,14 +51,14 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from .histogram import (histogram_pallas, histogram_pallas_multi,
-                        histogram_pallas_multi_routed,
+from .histogram import (bin_tiling, histogram_pallas,
+                        histogram_pallas_multi,
                         histogram_pallas_multi_win,
                         histogram_pallas_multi_win_lanes,
+                        histogram_routed,
                         histogram_segsum, histogram_segsum_multi,
                         histogram_segsum_multi_win,
-                        histogram_segsum_multi_win_lanes,
-                        routed_chunk_ok)
+                        histogram_segsum_multi_win_lanes)
 from ..io.pager import PagedXt
 from .split import (NEG_INF, SplitParams, choose_window,
                     eval_forced_split, find_best_split,
@@ -229,14 +229,16 @@ def routed_gate(params: GrowParams, kind: str, max_bin: int,
     branches on it and the tier record (models/tier.py) reports it.
 
     The wave's row-routing select chain re-reads leaf_idx + every xt
-    row from HBM; when every feature fits one kernel chunk and splits
-    are plain threshold compares, the pass itself resolves
-    lanes/goes-left and emits the new leaf vector.  Feature-parallel
-    is excluded: the lane's split column lives on one shard only, so
-    goes-left needs a cross-shard psum the kernel cannot do.  Missing
-    values ARE supported: the lane tables carry a default-left row and
-    the kernel resolves the per-row missing bin by a feature
-    contraction."""
+    row from HBM; where splits are plain threshold compares the
+    kernels resolve lanes/goes-left and emit the new leaf vector:
+    inside the pass where every feature fits one kernel chunk, in a
+    step of its own over the lanes' split columns where they do not
+    (:func:`route_kind`; ops/histogram.py ``histogram_routed``).
+    Feature-parallel is excluded: the lane's split column lives on one
+    shard only, so goes-left needs a cross-shard psum the kernel
+    cannot do.  Missing values ARE supported: the lane tables carry a
+    default-left row and the kernel resolves the per-row missing bin
+    by a feature contraction."""
     p = params
     if p.hist_impl != "pallas":
         return "cpu backend (segsum histograms)"
@@ -246,11 +248,26 @@ def routed_gate(params: GrowParams, kind: str, max_bin: int,
         return "categorical splits need bin masks"
     if kind == "feature":
         return "feature-parallel: split column lives on one shard"
-    if not routed_chunk_ok(max_bin, g_cols, 128, p.rows_per_block):
-        return "feature block exceeds one kernel chunk"
     if batched_width(p, kind) <= 1:
         return "no batched pass (single-leaf passes route nothing)"
     return None
+
+
+def route_kind(params: GrowParams, kind: str, max_bin: int,
+               g_cols: int) -> str:
+    """Where a wave's rows are routed (the tier record's ``route``):
+    ``kernel``, inside the routed batched pass (one feature chunk);
+    ``gather``, by the routing kernel, which fetches each live lane's
+    split column itself (the 32-row storage tile that holds it, by a
+    scalar-prefetched block index), ahead of a pass that walks several
+    feature chunks;
+    ``xla``, by the select chain over every stored column (or, off the
+    batched passes, a split at a time)."""
+    if routed_gate(params, kind, max_bin, g_cols) is not None:
+        return "xla"
+    one_chunk = bin_tiling(max_bin, g_cols, 128,
+                           params.rows_per_block).one_chunk
+    return "kernel" if one_chunk else "gather"
 
 
 def collective_bytes_per_pass(params: GrowParams, num_features: int,
@@ -752,10 +769,10 @@ def build_tree_impl(xt: jax.Array, grad: jax.Array, hess: jax.Array,
     li_narrow = L <= 255
 
     def routed_call(li, tbl, max_bin_r, shift_r, mode):
-        hist, li_new, sel = histogram_pallas_multi_routed(
+        hist, li_new, sel = histogram_routed(
             xt, kvals, li, tbl, max_bin_r, W_spec,
             p.rows_per_block, exact=p.quantize > 0, two_col=p.two_col,
-            shift=shift_r, mode=mode, miss_bin=mb_l)
+            shift=shift_r, mode=mode, miss_bin=mb_l, dead_id=L)
         return _wave_hist_finish(hist), li_new, sel
 
     def lane_tables(ids_leaf, feat_w, thr_w, new_ids, flag_w, dl_w):

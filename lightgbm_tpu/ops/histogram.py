@@ -78,6 +78,7 @@ __all__ = ["histogram", "histogram_segsum", "histogram_segsum_into",
            "histogram_pallas",
            "histogram_segsum_multi", "histogram_pallas_multi",
            "histogram_segsum_multi_win", "histogram_pallas_multi_win",
+           "histogram_routed", "histogram_pallas_route",
            "multi_width"]
 
 
@@ -133,6 +134,9 @@ def _pad_bins(max_bin: int) -> int:
     return (max_bin + 7) // 8 * 8
 
 
+_VMEM_BUDGET = 56 * 1024 * 1024     # what one grid step may hold
+
+
 def _tile(b_pad: int, f: int, cols: int, rows_per_block: int
           ) -> Tuple[int, int, int]:
     """(padded features, features-per-chunk, rows-per-tile).
@@ -147,7 +151,7 @@ def _tile(b_pad: int, f: int, cols: int, rows_per_block: int
     Then prefer large row tiles (fewer grid steps / accumulator
     revisits) under a VMEM budget of one-hot (FC, B, T) bf16 +
     accumulator (FC*B, cols) f32 + double-buffered inputs."""
-    budget = 56 * 1024 * 1024
+    budget = _VMEM_BUDGET
     for f_pad in range(max(f, 2), f + 9):
         best = None
         for fc in range(f_pad, 0, -1):
@@ -216,6 +220,11 @@ class BinTiling(NamedTuple):
         return self.f if self.one_chunk else self.fc
 
     @property
+    def chunks(self) -> int:
+        """Feature blocks the pass's grid walks."""
+        return self.f_pad // self.fc
+
+    @property
     def f_mask(self) -> int:
         """``f`` where the last feature block overhangs the stored
         matrix and the kernel has to mask it, else 0."""
@@ -235,9 +244,10 @@ class BinTiling(NamedTuple):
         int8 (:func:`_onehot_form`), ``plain`` (feature, bin) where it
         is bf16.  All three follow the values: the caller says whether
         the pass is given int8 ones (ops/grow.py
-        ``GrowParams.int8_values``)."""
+        ``GrowParams.int8_values``).  ``chunks``: the feature blocks
+        the grid walks (``f_pad // fc``)."""
         return {"f": self.f, "f_pad": self.f_pad, "fc": self.fc,
-                "t": self.t, "xt_copied": False,
+                "chunks": self.chunks, "t": self.t, "xt_copied": False,
                 "mxu": "int8" if int8 else "bf16",
                 "prologue": "words" if int8 else "rows",
                 "onehot": _onehot_form(self.b_pad) if int8 else "plain"}
@@ -841,6 +851,14 @@ def histogram_segsum_multi(bins_t: jax.Array, vals: jax.Array,
 _LANE_HEAD = 8      # scalar rows ahead of a table's per-feature rows
 
 
+def _lane_head(W: int, scalars) -> jax.Array:
+    """The (8, W) int32 head of a lane table: ``lane + 1``, then the
+    callers' scalar rows, then zeros."""
+    head = jnp.stack([jnp.arange(1, W + 1, dtype=jnp.int32)] +
+                     [r.astype(jnp.int32) for r in scalars])
+    return jnp.pad(head, ((0, _LANE_HEAD - head.shape[0]), (0, 0)))
+
+
 def _lane_operands(lane_ids: jax.Array, scalars, per_feat: jax.Array,
                    til: BinTiling):
     """The two operands :func:`_lane_lookup` takes.
@@ -856,10 +874,8 @@ def _lane_operands(lane_ids: jax.Array, scalars, per_feat: jax.Array,
     W = lane_ids.shape[0]
     wp = -W % 16
     rows = til.fc if not til.one_chunk else -(-til.f // 8) * 8
-    chunks = til.f_pad // til.fc
-    head = jnp.stack([jnp.arange(1, W + 1, dtype=jnp.int32)] +
-                     [r.astype(jnp.int32) for r in scalars])
-    head = jnp.pad(head, ((0, _LANE_HEAD - head.shape[0]), (0, 0)))
+    chunks = til.chunks
+    head = _lane_head(W, scalars)
     feat = jnp.pad(per_feat.astype(jnp.int32),
                    ((0, chunks * rows - per_feat.shape[0]), (0, 0)))
     tab = jnp.concatenate(
@@ -1023,9 +1039,24 @@ def histogram_pallas_multi_win(bins_t: jax.Array, vals: jax.Array,
 # lane and its lane's tables (:func:`_lane_lookup`), its split column
 # value (the lane's feature one-hot against the resident x tile), the
 # threshold compare, and the subset selector — and writes the NEW leaf
-# assignment and selector as side outputs.  Requires the whole feature
-# dimension in one chunk (fc == f_pad, i.e. F <= ~32 at 8 bins) —
-# callers fall back to the XLA routing otherwise.
+# assignment and selector as side outputs.  That takes the whole
+# feature dimension in one chunk (fc == f_pad): the split column can
+# be any feature's, and only then is every feature's tile resident.
+#
+# A feature set in SEVERAL chunks (2,000 features at 16 coarse bins:
+# 25 blocks of 80) routes its rows in a step of its own before the
+# contraction (:func:`histogram_pallas_route`): a row's split column is
+# its lane's, so a row tile walks the wave's live lanes, fetches for
+# each the one storage tile of rows that holds the lane's split column
+# (32 rows of uint8 bins) and keeps that column's bins for the lane's
+# rows; then lane, goes-left, new leaf and selector come out of the
+# same routing math, once a row tile.  The batched pass
+# (:func:`histogram_pallas_multi`) then walks the feature blocks with
+# that selector.  What a wave pays to route is the leaf vector and 32
+# bins a row and live lane, whatever the feature count (XLA's own
+# gather of the lanes' rows cost 7 to 9 ms a wave at 1.2M x 2000, twice
+# a read of the whole matrix: PERF.md, PR 35).
+# :func:`histogram_routed` picks the form by the tiling alone.
 #
 # The callers' lane tables are a (5-6, W) int32 array, which the
 # wrapper stacks into the kernel's operand (:func:`_lane_operands`):
@@ -1052,18 +1083,30 @@ def _routed_parts(x, li, ids, tab, width: int, mode: str, narrow: bool,
     its column value 0: it is never above its threshold, so it goes
     nowhere and is selected nowhere without a mask of its own."""
     FC = x.shape[0]
-    W = width if mode == "small" else width // 2
     lane, got = _lane_lookup(li, ids, tab, narrow)
     # per-row split-column value: the lane's feature one-hot against
     # the resident x tile, an FC*T multiply-reduce
     fsel = got[_LANE_HEAD:_LANE_HEAD + FC]          # (FC, T)
     col = jnp.sum(x.astype(jnp.float32) * fsel, axis=0,
                   keepdims=True)                    # (1, T)
-    gr = col > got[1:2]                             # goes right
+    mb_pr = None
     if mb is not None:
-        # per-row missing bin of the lane's feature + default-left
+        # per-row missing bin of the lane's feature
         mb_pr = jnp.sum(mb.astype(jnp.float32) * fsel, axis=0,
                         keepdims=True)              # (1, T)
+    return _route_decide(col, mb_pr, lane, got, li, width, mode, li_bytes)
+
+
+def _route_decide(col, mb_pr, lane, got, li, width: int, mode: str,
+                  li_bytes: int):
+    """(li_new, sel_out) from each row's split-column bin ``col``
+    (1, T) float32, its lane and its lane's table column ``got``
+    (:func:`_routed_parts`); ``mb_pr`` (1, T): the missing bin of the
+    lane's split feature (-1: none), or None."""
+    W = width if mode == "small" else width // 2
+    gr = col > got[1:2]                             # goes right
+    if mb_pr is not None:
+        # a row AT the missing bin goes the default way
         is_miss = (col == mb_pr) & (mb_pr >= 0)
         gr = gr & ~((got[6:7] > 0.5) & is_miss)
     # a leaf id above 256 is not bf16-exact: it rides as its bytes in
@@ -1125,13 +1168,6 @@ def _hist_kernel_multi_routed(x_ref, v_ref, li_ref, ids_ref, tab_ref,
     _accumulate(out_ref, xb, rhs, b_pad)    # one chunk: nothing to mask
 
 
-def routed_chunk_ok(max_bin: int, f: int, cols: int = 128,
-                    rows_per_block: int = 1024) -> bool:
-    """True when the tiler keeps the whole feature dimension in one
-    chunk — the routed kernel's requirement."""
-    return bin_tiling(max_bin, f, cols, rows_per_block).one_chunk
-
-
 @functools.partial(jax.jit, static_argnames=(
     "max_bin", "width", "rows_per_block", "exact", "two_col", "shift",
     "mode"))
@@ -1156,6 +1192,8 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     missing bin route by the default direction, and with ``shift``
     they land in the reserved last coarse slot.
     Returns (hist (width, F, B, 3), new_leaf_idx (N,), sel (N,)).
+    One feature chunk only: :func:`histogram_routed` is the entry that
+    also serves the shapes that chunk.
     """
     import jax.experimental.pallas as pl
 
@@ -1166,7 +1204,7 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     assert Wl * cols <= 128, (Wl, cols)
     til = bin_tiling(max_bin, f, 128, rows_per_block)
     f_pad, fc, t = til.f_pad, til.fc, til.t
-    assert til.one_chunk, "routed kernel needs a single feature chunk"
+    assert til.one_chunk, "in-kernel routing needs one feature chunk"
     assert n % t == 0, (n, t)
     if vals.dtype == jnp.int8:               # see histogram_pallas_multi
         assert exact or two_col, "int8 values need exact/two_col"
@@ -1225,6 +1263,173 @@ def histogram_pallas_multi_routed(bins_t: jax.Array, vals: jax.Array,
     hist = _batched_hists(out, til, vt.dtype == jnp.int8, Wl, cols,
                           max_bin, two_col, exact)
     return hist, li_new[0], sel[0]
+
+
+def _route_kernel(grp_ref, row_ref, x_ref, li_ref, ids_ref, tab_ref,
+                  li_out_ref, sel_out_ref, got_ref, col_ref, *,
+                  width: int, mode: str, with_miss: bool):
+    """One (row tile, lane) step of :func:`histogram_pallas_route`.
+
+    x_ref (g, T): the storage tile of rows that holds this lane's
+    split column (block ``grp_ref[w]`` of the matrix), row
+    ``row_ref[w]`` of it the column, -1 for a lane no row is in;
+    li_ref (1, T) the leaf vector.  The tile's first step looks every
+    row's lane and table column up (``got_ref`` (8, T) keeps them), a
+    live lane's step keeps its column's bins for its own rows
+    (``col_ref`` (1, T) int32), and the last step decides."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    w = pl.program_id(1)
+    g, T = x_ref.shape
+    # stored bins to a 32-bit word (a matrix of fewer rows than a
+    # storage tile comes whole and is read row by row)
+    per = 4 // x_ref.dtype.itemsize if g * x_ref.dtype.itemsize == 32 else 1
+
+    @pl.when(w == 0)
+    def _lookup():
+        _, got = _lane_lookup(li_ref[...].astype(jnp.int32), ids_ref[...],
+                              tab_ref[...], x_ref.dtype.itemsize == 1)
+        got_ref[...] = got
+        col_ref[...] = jnp.zeros_like(col_ref)
+
+    r = row_ref[w]
+
+    @pl.when(r >= 0)
+    def _keep_column():
+        # the column is one stored row: take its word row (4 uint8 rows
+        # to a 32-bit word), then its byte
+        words = x_ref[...] if per == 1 else \
+            pltpu.bitcast(x_ref[...], jnp.int32)            # (g / per, T)
+        words = words.astype(jnp.int32)
+        word = words[0:1]
+        for k in range(1, g // per):        # a tile's 8 word rows
+            word = jnp.where(r // per == k, words[k:k + 1], word)
+        bits = 32 // per
+        col = word if per == 1 else \
+            (word >> ((r % per) * bits)) & ((1 << bits) - 1)
+        lane = got_ref[0:1].astype(jnp.int32) - 1
+        col_ref[...] = jnp.where(lane == w, col, col_ref[...])
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _decide():
+        got = got_ref[...]
+        li = li_ref[...].astype(jnp.int32)
+        li_new, sel_out = _route_decide(
+            col_ref[...].astype(jnp.float32),
+            got[7:8] if with_miss else None,
+            got[0:1].astype(jnp.int32) - 1, got, li, width, mode,
+            li_ref.dtype.itemsize)
+        li_out_ref[...] = li_new.astype(li_out_ref.dtype)
+        sel_out_ref[...] = sel_out
+
+
+@functools.partial(jax.jit, static_argnames=("width", "rows_per_block",
+                                             "mode"))
+def histogram_pallas_route(bins_t: jax.Array, leaf_idx: jax.Array,
+                           tables: jax.Array, width: int,
+                           rows_per_block: int = 1024,
+                           mode: str = "small", miss_bin=None,
+                           dead_id=None):
+    """A wave's row routing as a kernel of its own, for the batched
+    pass whose features do not fit one chunk.
+
+    bins_t (F, N), leaf_idx (N,), tables (5-6, W) int32, ``width``,
+    ``mode`` and ``miss_bin`` as :func:`histogram_pallas_multi_routed`
+    takes them.  ``dead_id``: the leaf id the callers give a lane that
+    holds no split (no row carries it); such lanes are skipped.
+    Returns (new_leaf_idx (N,), sel (N,)), the one-chunk kernel's side
+    outputs bit for bit: the decision is the same function
+    (:func:`_route_decide`) of each row's split-column bin, lane and
+    lane tables.
+
+    The grid is (row tiles, lanes).  The matrix stays in HBM and a
+    step is handed ONE storage tile of its rows, the ``32 /
+    itemsize`` rows that hold the lane's split column (the block index
+    comes from the lane tables by scalar prefetch), so a wave reads
+    the leaf vector and, a live lane, 32 bins a row: proportional to
+    rows x live lanes, whatever F.  Dead lanes repeat block 0, which
+    the pipeline does not fetch again, and do no work."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f, n = bins_t.shape
+    Wl = width if mode == "small" else width // 2
+    assert n % rows_per_block == 0, (n, rows_per_block)
+    # a step is a few microseconds of work and there are row tiles x
+    # lanes of them: the largest row tile that divides the rows
+    # (6.3 ms a wave of 64 live lanes at 16384 rows a tile and 1.2M
+    # rows, 1.3 us a step against 0.6 us of fetch)
+    t = rows_per_block
+    while t < 65536 and n % (2 * t) == 0:
+        t *= 2
+    g = min(32 // bins_t.dtype.itemsize, f)     # rows of one storage tile
+    tbl = tables[:, :Wl].astype(jnp.int32)
+    feat = jnp.clip(tbl[1], 0, f - 1)
+    live = jnp.ones((Wl,), bool) if dead_id is None else tbl[0] != dead_id
+    grp = jnp.where(live, feat // g, 0)
+    row = jnp.where(live, feat % g, -1)
+    scalars = [tbl[2], tbl[3] & 0xFF, tbl[3] & 0xFF00, tbl[3] & 0xFF0000,
+               *tbl[4:6]]
+    if miss_bin is not None:
+        assert tables.shape[0] >= 6, \
+            "missing routing needs the default-left row"
+        # head row 7: the missing bin of the lane's split feature
+        scalars.append(jnp.take(miss_bin.astype(jnp.int32), feat))
+    wp = -Wl % 16
+    tab = jnp.pad(_lane_head(Wl, scalars), ((0, 0), (0, wp))
+                  ).astype(jnp.float32)
+    ids = jnp.pad(tbl[0], (0, wp), constant_values=-2)[:, None]
+    li_new, sel = pl.pallas_call(
+        functools.partial(_route_kernel, width=width, mode=mode,
+                          with_miss=miss_bin is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // t, Wl),
+            in_specs=[
+                pl.BlockSpec((g, t), lambda i, w, grp, row: (grp[w], i)),
+                pl.BlockSpec((1, t), lambda i, w, grp, row: (0, i)),
+                pl.BlockSpec(ids.shape, lambda i, w, grp, row: (0, 0)),
+                pl.BlockSpec(tab.shape, lambda i, w, grp, row: (0, 0)),
+            ],
+            out_specs=[pl.BlockSpec((1, t),
+                                    lambda i, w, grp, row: (0, i))] * 2,
+            scratch_shapes=[pltpu.VMEM((_LANE_HEAD, t), jnp.float32),
+                            pltpu.VMEM((1, t), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((1, n), leaf_idx.dtype),
+                   jax.ShapeDtypeStruct((1, n), jnp.int32)],
+        compiler_params=_compiler_params(),
+        interpret=pallas_interpret(),
+    )(grp, row, bins_t, leaf_idx[None, :], ids, tab)
+    return li_new[0], sel[0]
+
+
+def histogram_routed(bins_t: jax.Array, vals: jax.Array,
+                     leaf_idx: jax.Array, tables: jax.Array,
+                     max_bin: int, width: int, rows_per_block: int = 1024,
+                     exact: bool = False, two_col: bool = False,
+                     shift: int = 0, mode: str = "small", miss_bin=None,
+                     dead_id=None):
+    """The routed batched pass at any feature count: arguments and
+    results of :func:`histogram_pallas_multi_routed`.  Features in one
+    chunk: that kernel, routing inside the pass.  Several chunks: the
+    rows are routed once a wave (:func:`histogram_pallas_route`, which
+    ``dead_id`` is for) and the batched pass walks the feature blocks
+    with the selector (:func:`histogram_pallas_multi`); the outputs
+    are the same bit for bit.  The tiling alone decides
+    (``BinTiling.one_chunk``), which is what the tier record's
+    ``route`` reports."""
+    kw = dict(exact=exact, two_col=two_col, shift=shift, miss_bin=miss_bin)
+    if bin_tiling(max_bin, bins_t.shape[0], 128, rows_per_block).one_chunk:
+        return histogram_pallas_multi_routed(
+            bins_t, vals, leaf_idx, tables, max_bin, width,
+            rows_per_block, mode=mode, **kw)
+    li_new, sel = histogram_pallas_route(
+        bins_t, leaf_idx, tables, width, rows_per_block, mode, miss_bin,
+        dead_id)
+    hist = histogram_pallas_multi(bins_t, vals, sel, max_bin, width,
+                                  rows_per_block, **kw)
+    return hist, li_new, sel
 
 
 def histogram_segsum_multi_routed(bins_t, vals, leaf_idx, tables,

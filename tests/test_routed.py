@@ -138,7 +138,7 @@ def test_routed_kernel_equals_its_oracle(monkeypatch, mode, shift, miss,
     from lightgbm_tpu.ops import histogram as H
     monkeypatch.setenv("LTPU_PALLAS_INTERPRET", "1")
     d = _routed_case(mode, shift, miss, fine=256 if shift else 64)
-    assert H.routed_chunk_ok(d["max_bin"], 28, 128, 256)
+    assert H.bin_tiling(d["max_bin"], 28, 128, 256).one_chunk
     kw = dict(two_col=vdtype == np.int8, shift=shift, mode=mode,
               miss_bin=d["mb"])
     got = H.histogram_pallas_multi_routed(

@@ -19,7 +19,13 @@ So is ``row_state`` (PR 33): the dump's rows take the plan's
 into the dump's rows when it came: ``slabs`` at the coarse passes' 16
 and 8 bins, ``words`` on the 32-bin grid, ``plain`` where the pass
 contracts in bf16 (``test_onehot_follows_the_bins`` says so of every
-row).
+row).  A pass's ``chunks`` (``f_pad // fc``) and the record's
+``route`` (PR 35: where a wave's rows are routed) were written into
+the rows the same way, ``route`` by the row's own ``routed``
+(``kernel`` where it is true: every routed pass of the dump fits one
+chunk); ``fast2000.max_bin255@tpu`` is the plan's own answer for the
+shape whose coarse pass chunks, which
+``test_wide_set_routes_by_gather`` holds to what it has to be.
 """
 import dataclasses
 import glob
@@ -84,6 +90,7 @@ def test_plan_matches_parent(case):
     if case in ROUTED_CORRECTIONS:
         why = ROUTED_CORRECTIONS[case]
         want["routed"] = why is None
+        want["route"] = "kernel" if why is None else "xla"
         want["gates"].pop("routed", None)
         if why is not None:
             want["gates"]["routed"] = why
@@ -111,6 +118,48 @@ def test_onehot_follows_the_bins():
             seen.add((kind, want))
     assert {("coarse", "slabs"), ("refine", "words"), ("full", "slabs"),
             ("full", "words"), ("root", "plain")} <= seen
+
+
+def test_wide_set_routes_by_gather():
+    """2,000 dense features on the ``fast`` job: at 255 bins the 16
+    coarse bins tile as 25 chunks of 80 and the 32-bin window as 50 of
+    40, and the rows are routed by the routing step (``gather``); at 63
+    bins the 8 coarse bins stay one chunk and the routed kernel routes
+    them (``kernel``).  Both are the two-column, c2f, routed tier."""
+    wide, narrow = (GOLDEN[f"fast2000.max_bin{b}@tpu"] for b in (255, 63))
+    for row, route, chunks in ((wide, "gather", (25, 50)),
+                               (narrow, "kernel", (1, 25))):
+        record = plan_tier(Config(dict(row["params"], verbose=-1)),
+                           _facts(row)).record
+        assert record["tier"] == "two_col" and record["c2f"]
+        assert record["routed"] and "routed" not in record["gates"]
+        assert record["route"] == route
+        til = record["hist_tiling"]
+        assert (til["coarse"]["chunks"], til["refine"]["chunks"]) == chunks
+        for rec in til.values():
+            assert rec["chunks"] * rec["fc"] == rec["f_pad"] >= rec["f"]
+    assert wide["record"]["hist_tiling"]["coarse"]["fc"] == 80
+    assert wide["record"]["hist_tiling"]["refine"]["fc"] == 40
+    assert wide["record"]["refine_shift"] == 4
+
+
+def test_route_follows_routed():
+    """Every row: ``route`` is ``xla`` exactly where ``routed`` is
+    false, and ``kernel`` or ``gather`` by whether the routed pass (the
+    coarse one under c2f, else the full one) fits one chunk."""
+    seen = set()
+    for case, row in GOLDEN.items():
+        record = plan_tier(Config(dict(row["params"], verbose=-1)),
+                           _facts(row)).record
+        til = record["hist_tiling"]
+        if not record["routed"]:
+            want = "xla"
+        else:
+            kind = "coarse" if record["c2f"] else "full"
+            want = "kernel" if til[kind]["chunks"] == 1 else "gather"
+        assert record["route"] == want, case
+        seen.add(want)
+    assert seen == {"xla", "kernel", "gather"}
 
 
 def test_corrections_are_cases():

@@ -15,7 +15,14 @@ the kernels' operand as stored and the feature tail is made in VMEM
 (``ops/histogram.BinTiling``): 28 features have a tail at 16 bins (32
 rows) and none at 32; 67 have one everywhere (72 at 16 bins, 68 at
 32), and at 8 coarse bins they run in five chunks of 16 whose last
-block overhangs the matrix.  Every integer diff must be 0; the script
+block overhangs the matrix.  A third width, 155 features on 64 lanes
+(at 65,536 rows, what the oracle's memory bears), is there for the
+passes whose features CHUNK at the routed bins (two blocks of 80 at 16
+coarse bins, ten of 16 at 64 bins, twenty of 8 at 256): there
+``histogram_routed`` routes the rows in a step of its own
+(``histogram_pallas_route``) ahead of the chunked pass, and the new
+leaf vector and the selector have to equal the oracle's as the
+one-chunk kernel's do.  Every integer diff must be 0; the script
 exits non-zero otherwise, and it refuses to run anywhere but on a TPU
 with Pallas compiled (``chip_smoke.acquire_chip``): on a CPU backend
 the kernels would run interpreted and prove nothing about Mosaic.  The
@@ -38,8 +45,20 @@ took to compile it, the one-hot rows it streams, the us a one-hot row
 the pass measures (the unrouted pass of its kind at two feature
 counts, the difference over the rows added), the stream (rows x us a
 row) and PASS LESS STREAM, what does not shrink with the feature rows:
-the kernel-alone table of PERF.md, by one command.
+the kernel-alone table of PERF.md, by one command.  A fourth row of
+that table is the wide shape of ``benchmark/configs/epsilon2000.json``
+(1.2M x 2000: 25 and 50 feature chunks), and for every shape the
+routing step alone and, where the features fit one chunk, the routed
+coarse pass in its CHUNKED form (the tiler's budget cut for the
+trace) beside the one-chunk kernel, outputs equal bit for bit.
+
+    python tools/check_routed_kernels.py [--widths 28,155] [--table a,b]
+
+``--widths`` keeps those feature counts of the oracle half and
+``--table`` the named rows of the timing table (all by default; an
+empty value, none).
 """
+import argparse
 import contextlib
 import math
 import os
@@ -56,11 +75,12 @@ import numpy as np  # noqa: E402
 from chip_smoke import acquire_chip  # noqa: E402
 from lightgbm_tpu.ops import histogram as H  # noqa: E402
 from lightgbm_tpu.ops.histogram import (  # noqa: E402
-    histogram_pallas_multi, histogram_pallas_multi_routed,
+    bin_tiling, histogram_pallas_multi, histogram_pallas_multi_routed,
     histogram_pallas_multi_win, histogram_pallas_multi_win_lanes,
+    histogram_pallas_route, histogram_routed,
     histogram_segsum_multi, histogram_segsum_multi_routed,
     histogram_segsum_multi_win, histogram_segsum_multi_win_lanes,
-    leaf_stats_pallas, routed_chunk_ok)
+    leaf_stats_pallas)
 
 N, RPB, L = 262144, 16384, 255
 FAILED = []
@@ -87,11 +107,14 @@ def report(name, pairs, tol=0.0):
 
 
 def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
-               rng) -> None:
+               rng, N: int = N) -> None:
     """All kernel-vs-oracle pairs over ``F`` features at ``B`` fine
     bins, ``W`` lanes a pass; the c2f stage collapses the bins
     ``2^shift``-to-1 and refines a ``2 << shift`` bin window, as
-    ops/grow.py does."""
+    ops/grow.py does.  The routed pairs go through
+    ``histogram_routed``, as ops/grow.py does: the routed kernel where
+    the features fit one chunk, the routing step and the chunked pass
+    where they do not."""
     tag = f"[{F} features, {B} bins]"
     Bc, R = ((B - 1) >> shift) + 1, 2 << shift
     bins = rng.randint(0, B, size=(F, N)).astype(np.uint8)
@@ -111,21 +134,19 @@ def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
         return np.stack(t).astype(np.int32)
 
     def routed_pair(name, vals_k, leaf, tbl, max_bin, **kw):
-        if not routed_chunk_ok(max_bin, F, 128, RPB):
-            # ops/grow.py routes in XLA at such a shape
-            print(f"{tag} {name}: features chunk at {max_bin} bins, "
-                  f"no routed pass", flush=True)
-            return
+        chunks = bin_tiling(max_bin, F, 128, RPB).chunks
 
         def pairs():
-            hp, lp, sp_ = histogram_pallas_multi_routed(
+            hp, lp, sp_ = histogram_routed(
                 xb, vals_k, leaf, jnp.asarray(tbl), max_bin, W, RPB,
-                exact=True, two_col=two_col, **kw)
+                exact=True, two_col=two_col, dead_id=L, **kw)
             hs, ls, ss = histogram_segsum_multi_routed(
                 xb, vb, leaf, jnp.asarray(tbl), max_bin, W,
                 two_col=two_col, **kw)
             return {"hist": (hp, hs), "li": (lp, ls), "sel": (sp_, ss)}
-        report(f"{tag} {name}", pairs)
+        report(f"{tag} {name} ({chunks} feature chunk"
+               f"{'s: routing step' if chunks > 1 else ': routed kernel'})",
+               pairs)
 
     for mode, Wt in (("small", W), ("children", W // 2)):
         tbl = tables(Wt)
@@ -280,13 +301,40 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
     kw = dict(exact=True, two_col=two_col)
     # the un-jitted wrappers: each side jits its own, or the second
     # would be served the first's trace
-    routed, lanes, multi, win = (f.__wrapped__ for f in (
+    one_chunk, lanes, multi, win, route = (f.__wrapped__ for f in (
         histogram_pallas_multi_routed, histogram_pallas_multi_win_lanes,
-        histogram_pallas_multi, histogram_pallas_multi_win))
+        histogram_pallas_multi, histogram_pallas_multi_win,
+        histogram_pallas_route))
+
+    def routed(x, v, **k):
+        """``histogram_routed`` out of the un-jitted wrappers: the
+        routed kernel where the features fit one chunk under the
+        tiler's budget of the moment, else the routing step and the
+        chunked pass."""
+        if bin_tiling(16, x.shape[0], 128, RPB).one_chunk:
+            return one_chunk(x, v, lb, tbl, 16, W, RPB, shift=4,
+                             mode="small", **k)
+        li_new, sel = route(x, lb, tbl, W, RPB, "small", None, 255)
+        return multi(x, v, sel, 16, W, RPB, shift=4, **k), li_new, sel
+
+    def in_chunks(x, v, **k):
+        """The routed coarse pass of a one-chunk shape in its chunked
+        form: the tiler's budget cut to a fifth while it traces."""
+        budget = H._VMEM_BUDGET
+        H._VMEM_BUDGET = budget // 5
+        try:
+            assert not bin_tiling(16, x.shape[0], 128, RPB).one_chunk
+            return routed(x, v, **k)
+        finally:
+            H._VMEM_BUDGET = budget
+
+    chunked = not bin_tiling(16, F, 128, RPB).one_chunk
     # name -> (kind of pass, the pass over features ``x``)
     passes = {
-        "routed coarse": ("coarse", lambda v, x, lo: routed(
-            x, v, lb, tbl, 16, W, RPB, shift=4, mode="small", **kw)),
+        "routed coarse": ("coarse", lambda v, x, lo: routed(x, v, **kw)),
+        "routing step alone (histogram_pallas_route)": (
+            "route", lambda v, x, lo: route(x, lb, tbl, W, RPB, "small",
+                                            None, 255)),
         "multi coarse (the root's)": ("coarse", lambda v, x, lo: multi(
             x, v, selw, 16, W, RPB, shift=4, **kw)),
         "win_lanes refine": ("refine", lambda v, x, lo: lanes(
@@ -294,6 +342,9 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
         "multi_win refine (the root's)": ("refine", lambda v, x, lo: win(
             x, v, selw, lo, 32, W, RPB, **kw)),
     }
+    if not chunked and F >= 32:     # a whole storage tile of rows
+        passes["routed coarse in chunks (budget cut)"] = (
+            "coarse", lambda v, x, lo: in_chunks(x, v, **kw))
     bins = {"coarse": 16, "refine": 32}
 
     def timed(side, fn, *args):
@@ -320,6 +371,7 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
 
     tag = f"[{cell}: {rows} x {F}, {W} lanes]"
     took = {}                       # (pass, side) -> ms
+    routed_once = []                # the one-chunk routed pass's outputs
     for name, (kind, fn) in passes.items():
         def pairs(name=name, fn=fn):
             outs = {}
@@ -329,7 +381,11 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
                 took[name, side, "compile_s"] = c
             got = {f"{side} out{i}": p for side in SIDES if side != "parent"
                    for i, p in enumerate(zip(outs[side], outs["parent"]))}
+            if name == "routed coarse in chunks (budget cut)":
+                got.update({f"one chunk out{i}": p for i, p in enumerate(
+                    zip(outs["change"], routed_once[0]))})
             if name == "routed coarse":
+                routed_once.append(outs["change"])
                 for side in SIDES:
                     outs[side + " f32"], took[name + " f32", side], _ = \
                         timed(side, fn, vf, xb, lo_w)
@@ -343,7 +399,9 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
     # what a one-hot row costs: the unrouted pass of each kind again at
     # fewer features (whole sublane groups fewer, so both sides' rows
     # fall alike), the difference over the one-hot rows taken away
-    f2 = F - (32 if F > 32 else 16)
+    # (a chunked shape: whole blocks of 80 fewer, so that both feature
+    # counts tile alike)
+    f2 = F - (80 if chunked else 32 if F > 32 else 16)
     us_a_row = {}
     for name, kind in (("multi coarse (the root's)", "coarse"),
                        ("multi_win refine (the root's)", "refine")):
@@ -367,6 +425,11 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
                   f"{f2} features) + {took[name, side] - r * us / 1e3:.2f} "
                   f"ms PASS LESS STREAM; compiled in "
                   f"{took[name, side, 'compile_s']:.1f} s", flush=True)
+    step = "routing step alone (histogram_pallas_route)"
+    for side in SIDES:
+        if (step, side) in took:
+            print(f"    {tag} {step}, {side}: {took[step, side]:.2f} ms a "
+                  f"wave of {W} live lanes", flush=True)
     for side in SIDES:
         if ("routed coarse f32", side) in took:
             print(f"    {tag} routed coarse on float32 values (bf16 "
@@ -397,18 +460,34 @@ def check_leaf_stats(rng) -> None:
 
 
 def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    # (features, lanes, two-column, rows): the two cells' `fast` jobs,
+    # and a width whose routed passes chunk (fewer rows: the oracle
+    # holds a (features x rows, 3) float32 tensor a lane)
+    widths = ((28, 64, True, N), (67, 42, False, N), (155, 64, True, 65536))
+    # (shape, features a chip stores, rows a chip, lanes, two-column)
+    table = (("higgs28.fast", 28, 21_000_000, 64, True),
+             ("criteo67.fast", 67, 20_000_000, 42, False),
+             ("criteo67x4.fast", 68, 16_000_000, 42, False),
+             ("epsilon2000", 2000, 1_200_000, 64, True))
+    ap.add_argument("--widths", default=",".join(str(w[0]) for w in widths))
+    ap.add_argument("--table", default=",".join(c[0] for c in table))
+    args = ap.parse_args()
+    keep_w = {int(w) for w in filter(None, args.widths.split(","))}
+    keep_t = set(filter(None, args.table.split(",")))
+    assert keep_w <= {w[0] for w in widths}, keep_w
+    assert keep_t <= {c[0] for c in table}, keep_t
     acquire_chip()
     rng = np.random.RandomState(0)
-    # (features, lanes, two-column): the two cells' `fast` jobs
-    for F, W, two_col in ((28, 64, True), (67, 42, False)):
-        check_bins(F, W, two_col, 63, 3, rng)
-        check_bins(F, W, two_col, 255, 4, rng)
-    check_leaf_stats(rng)
-    # (cell, features a chip stores, rows a chip, lanes, two-column)
-    for cell in (("higgs28.fast", 28, 21_000_000, 64, True),
-                 ("criteo67.fast", 67, 20_000_000, 42, False),
-                 ("criteo67x4.fast", 68, 16_000_000, 42, False)):
-        check_onehot_order(*cell, rng)
+    for F, W, two_col, rows in widths:
+        if F in keep_w:
+            check_bins(F, W, two_col, 63, 3, rng, N=rows)
+            check_bins(F, W, two_col, 255, 4, rng, N=rows)
+    if keep_w:
+        check_leaf_stats(rng)
+    for cell in table:
+        if cell[0] in keep_t:
+            check_onehot_order(*cell, rng)
     print("FAILED: " + ", ".join(FAILED) if FAILED
           else "ALL KERNEL CHECKS PASS")
     return 1 if FAILED else 0
