@@ -315,3 +315,245 @@ def test_c2f_engine_auc_with_missing():
             assert gp.split.any_missing
     assert aucs[True] > 0.5
     assert abs(aucs[True] - aucs[False]) < 0.015, aucs
+
+
+# ---- the scan's winner read at one slot (ISSUE 36) ---------------------
+
+def _c2f_oracle(coarse, win, win_lo, parent, num_bins, feature_mask,
+                params, shift, monotone=None, penalty=None,
+                min_output=None, max_output=None, missing_type=None):
+    """The scan as it stood before PR 36: coarse and window slots
+    joined into one (F, Bcv + R) axis, the winner gathered from it.
+    Also returns the joined gains (``_all_gain``) for the tie checks."""
+    from lightgbm_tpu.ops.split import (EPS, NEG_INF, _c2f_coarse_scan,
+                                        _c2f_miss, _constraints,
+                                        _split_gain, leaf_gain)
+    p = params
+    F = coarse.shape[0]
+    R_w = win.shape[1]
+    B = p.max_bin
+    l1, l2, mds = p.lambda_l1, p.lambda_l2, p.max_delta_step
+    mn, mx = min_output, max_output
+    g_c, L_c, thr_c, dirl_c = _c2f_coarse_scan(
+        coarse, parent, num_bins, p, shift, monotone, mn, mx,
+        missing_type)
+    Bcv = g_c.shape[1]
+    parent_gain = leaf_gain(parent[0], parent[1], l1, l2, mds)
+    gain_shift = parent_gain + p.min_gain_to_split
+    vals_c, miss, no_miss = _c2f_miss(coarse, missing_type, p)
+    if p.any_missing:
+        has_missing = missing_type != 0
+        nv = num_bins - has_missing.astype(jnp.int32)
+    else:
+        has_missing = jnp.zeros((F,), bool)
+        nv = num_bins
+    cum_c = jnp.cumsum(vals_c, axis=1)
+    cpad = jnp.concatenate([jnp.zeros((F, 1, 3), coarse.dtype), cum_c],
+                           axis=1)
+    win_c0 = (win_lo >> shift).astype(jnp.int32)
+    base = jnp.take_along_axis(cpad, win_c0[:, None, None], axis=1)
+    Lf_base = base + jnp.cumsum(win, axis=1)
+    thr_f = win_lo[:, None] + jnp.arange(R_w, dtype=jnp.int32)[None, :]
+    ok_f = thr_f <= nv[:, None] - 2
+    mono_col = None if monotone is None else monotone[:, None]
+
+    def fine_dir(default_left):
+        L_f = Lf_base + (miss[:, None, :] if default_left else 0.0)
+        R_side = parent[None, None, :] - L_f
+        g = (_split_gain(L_f[..., 0], L_f[..., 1] + EPS,
+                         R_side[..., 0], R_side[..., 1] + EPS, l1, l2,
+                         mds, mn, mx, mono_col) - gain_shift)
+        return jnp.where(ok_f & _constraints(L_f, R_side, p), g,
+                         NEG_INF), L_f
+
+    gf_r, Lf_r = fine_dir(False)
+    if p.any_missing:
+        gf_l, Lf_l = fine_dir(True)
+        gf_l = jnp.where(no_miss[:, None], NEG_INF, gf_l)
+        g_f = jnp.maximum(gf_r, gf_l)
+        dirl_f = gf_l > gf_r
+        L_f = jnp.where(dirl_f[..., None], Lf_l, Lf_r)
+    else:
+        g_f, L_f = gf_r, Lf_r
+        dirl_f = jnp.zeros_like(g_f, dtype=bool)
+    all_gain = jnp.concatenate([g_c, g_f], axis=1)
+    all_thr = jnp.concatenate(
+        [jnp.broadcast_to(thr_c[None, :], (F, Bcv)), thr_f], axis=1)
+    all_L = jnp.concatenate([L_c, L_f], axis=1)
+    all_dirl = jnp.concatenate([dirl_c, dirl_f], axis=1)
+    if penalty is not None:
+        all_gain = jnp.where(all_gain > 0.5 * NEG_INF,
+                             all_gain * penalty[:, None], all_gain)
+    all_gain = jnp.where(feature_mask[:, None], all_gain, NEG_INF)
+    best_per_f = jnp.max(all_gain, axis=1)
+    best_k = jnp.argmax(all_gain, axis=1).astype(jnp.int32)
+    f_star = jnp.argmax(best_per_f).astype(jnp.int32)
+    k_star = best_k[f_star]
+    j_star = all_thr[f_star, k_star]
+    dir_left = all_dirl[f_star, k_star]
+    jidx = jnp.arange(B, dtype=jnp.int32)
+    left_mask = (jidx <= j_star) & (jidx < nv[f_star])
+    if p.any_missing:
+        left_mask = left_mask | \
+            (dir_left & has_missing[f_star] &
+             (jidx == num_bins[f_star] - 1))
+    return {"gain": best_per_f[f_star], "feature": f_star,
+            "threshold": j_star, "default_left": dir_left,
+            "is_cat": jnp.asarray(False), "left_mask": left_mask,
+            "left_stats": all_L[f_star, k_star],
+            "per_feature_gain": best_per_f, "_all_gain": all_gain}
+
+
+_SCAN_KEYS = ("gain", "feature", "threshold", "default_left", "is_cat",
+              "left_mask", "left_stats", "per_feature_gain")
+
+
+def _children_case(case, W=8, F=7, B=64, shift=3, N=6000, seed=11):
+    """Histograms of W children: (coarse, win, win_lo, parent) stacked
+    over the children, plus the scan's per-feature arguments."""
+    import jax
+    rng = np.random.RandomState(seed)
+    missing = case == "missing"
+    nv = B - 1 if missing else B
+    bins = rng.randint(0, nv, size=(F, N)).astype(np.int32)
+    if missing:
+        # features 0..3 hold NaNs (the last bin); the rest none at all
+        bins[:4][rng.random_sample((4, N)) < 0.15] = B - 1
+    child = rng.randint(0, W, size=N).astype(np.int32)
+    # each child its own signal: a feature and a threshold of its own
+    c = np.arange(W)
+    f_c, t_c = c % 3, 8 + 5 * c
+    if case in ("tie_coarse_window", "tie_features"):
+        # integer gradients: every prefix sum is exact, so a coarse
+        # boundary inside the window and the window threshold at the
+        # same bin give bit-equal gains; the signal sits on a boundary
+        if case == "tie_features":
+            bins[3] = bins[0]                 # an identical feature
+            f_c = 0 * c
+        t_c = ((2 + c % 5) << shift) - 1
+    x = bins[f_c[child], np.arange(N)]
+    step = np.where(x > t_c[child], 1.0, 0.0)
+    if case in ("tie_coarse_window", "tie_features"):
+        grad = 4.0 * step - 2.0 + rng.randint(-1, 2, N)
+        hess = np.ones(N)
+    else:
+        y = step + 0.5 * (bins[3] < 20) + 0.3 * rng.randn(N)
+        if missing:                           # NaNs lean by the child
+            y += np.where(x == B - 1, np.where(child % 2, 0.9, -0.9), 0)
+        grad, hess = 0.5 - y, 0.25 + 0.5 * rng.random_sample(N)
+    vals = np.stack([grad, hess, np.ones(N)], -1).astype(np.float32)
+    sp = SplitParams(max_bin=B, min_data_in_leaf=5, any_cat=False,
+                     any_missing=missing)
+    nb = jnp.full(F, B, jnp.int32)
+    mt = jnp.asarray([1] * 4 + [0] * (F - 4), jnp.int32) if missing \
+        else None
+    mb = jnp.where(mt != 0, nb - 1, -1) if missing else None
+    Bc = ((B - 1) >> shift) + (2 if missing else 1)
+    R = 2 << shift
+    coarse = histogram_segsum_multi(jnp.asarray(bins), jnp.asarray(vals),
+                                    jnp.asarray(child), Bc, W,
+                                    shift=shift, miss_bin=mb)
+    parent = jnp.sum(histogram_segsum_multi(
+        jnp.asarray(bins), jnp.asarray(vals), jnp.asarray(child), B, W,
+    )[:, 0], axis=1)                                    # (W, 3)
+    kw = dict(missing_type=mt)
+    fm = jnp.ones(F, bool)
+    mn = mx = None
+    if case == "monotone":
+        kw["monotone"] = jnp.asarray([1, -1, 0, 1, 0, -1, 0][:F],
+                                     jnp.int32)
+        mn = jnp.linspace(-2.0, -0.1, W).astype(jnp.float32)
+        mx = jnp.linspace(0.3, 2.0, W).astype(jnp.float32)
+    if case == "penalty":
+        kw["penalty"] = jnp.asarray(rng.uniform(0.3, 1.5, F), jnp.float32)
+    if case == "feature_mask":
+        fm = jnp.asarray([False, True, True, False, True, True, False][:F])
+    if mn is None:
+        lo = jax.vmap(lambda c, s: choose_window(
+            c, s, nb, sp, shift, missing_type=mt))(coarse, parent)
+    else:
+        lo = jax.vmap(lambda c, s, a, b: choose_window(
+            c, s, nb, sp, shift, kw["monotone"], a, b,
+            missing_type=mt))(coarse, parent, mn, mx)
+    win = histogram_segsum_multi_win(jnp.asarray(bins), jnp.asarray(vals),
+                                     jnp.asarray(child), lo, R, W,
+                                     miss_bin=mb)
+    return (coarse, win, lo, parent, mn, mx), (nb, fm, sp, shift, kw)
+
+
+@pytest.mark.parametrize("case", ["plain", "missing", "monotone",
+                                  "penalty", "feature_mask",
+                                  "tie_coarse_window", "tie_features"])
+def test_c2f_scan_equals_joined_slot_oracle(case):
+    """The scan that picks its winner from the gains and reads the
+    stats at one slot gives today's record bit for bit (CPU), under the
+    wave's vmap over children, the planted ties included.  Op by op:
+    under one jit XLA:CPU contracts the gains' multiply-adds as each
+    program's fusions fall, so two programs with the same gain
+    expression can round a gain apart (a coarse boundary and the window
+    threshold at the same bin, 0.66856194 against 0.66856146 here)."""
+    import jax
+    (coarse, win, lo, parent, mn, mx), (nb, fm, sp, shift, kw) = \
+        _children_case(case)
+
+    def run(fn):
+        def one(c, wh, l, s, a, b):
+            return fn(c, wh, l, s, nb, fm, sp, shift, min_output=a,
+                      max_output=b, **kw)
+        with jax.disable_jit():
+            if mn is None:
+                return jax.vmap(
+                    lambda c, wh, l, s: one(c, wh, l, s, None, None))(
+                        coarse, win, lo, parent)
+            return jax.vmap(one)(coarse, win, lo, parent, mn, mx)
+
+    got, want = run(find_best_split_c2f), run(_c2f_oracle)
+    for k in _SCAN_KEYS:
+        g, w = np.asarray(got[k]), np.asarray(want[k])
+        assert g.dtype == w.dtype and g.shape == w.shape, k
+        assert g.tobytes() == w.tobytes(), (k, g, w)
+    assert (np.asarray(want["gain"]) > 0).sum() >= 6   # real splits
+    ag = np.asarray(want["_all_gain"])                  # (W, F, Bcv + R)
+    f = np.asarray(want["feature"])
+    Bcv = ag.shape[2] - win.shape[2]
+    at_f = ag[np.arange(len(f)), f]                     # (W, Bcv + R)
+    if case == "tie_coarse_window":
+        # the winner is a coarse slot that a window slot ties exactly
+        coarse_best, win_best = at_f[:, :Bcv].max(1), at_f[:, Bcv:].max(1)
+        assert (coarse_best == win_best).all()
+        assert ((np.asarray(want["threshold"]) + 1) % (1 << shift)
+                == 0).all()
+    if case == "tie_features":
+        assert (ag[:, 0] == ag[:, 3]).all() and (f == 0).all()
+
+
+@pytest.mark.parametrize("scan,joined", [("find_best_split_c2f", False),
+                                         ("oracle", True)])
+def test_c2f_scan_builds_no_joined_slot_axis(scan, joined):
+    """Compiled under a wave's vmap at (2W=128, F=200, Bc=16, R=32), the
+    scan holds no array with a Bc + R = 48 slot axis: not the joined
+    left stats (147 MB a wave at 2,000 features, a 9.8 ms layout copy
+    on the chip), thresholds or gains.  The oracle, which joins them,
+    shows the check can see one."""
+    import re
+    import jax
+    W2, F, Bc, R, B, shift = 128, 200, 16, 32, 255, 4
+    fn = find_best_split_c2f if scan == "find_best_split_c2f" \
+        else _c2f_oracle
+    sp = SplitParams(max_bin=B, min_data_in_leaf=1,
+                     min_sum_hessian_in_leaf=100.0, any_cat=False,
+                     any_missing=False, counts_proxy=True)
+    nb = jnp.full(F, B, jnp.int32)
+    fm = jnp.ones(F, bool)
+    f32 = jnp.float32
+    hlo = jax.jit(jax.vmap(
+        lambda c, wh, lo, s: fn(c, wh, lo, s, nb, fm, sp, shift))).lower(
+        jax.ShapeDtypeStruct((W2, F, Bc, 3), f32),
+        jax.ShapeDtypeStruct((W2, F, R, 3), f32),
+        jax.ShapeDtypeStruct((W2, F), jnp.int32),
+        jax.ShapeDtypeStruct((W2, 3), f32)).compile().as_text()
+    shapes = re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", hlo)
+    assert shapes
+    with_joined = [s for s in shapes
+                   if str(Bc + R) in s.split(",")]
+    assert bool(with_joined) == joined, with_joined[:5]
