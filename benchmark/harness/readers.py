@@ -12,12 +12,35 @@ def _span(spec: Dict, ctx: Dict) -> Optional[float]:
     return ctx["spans"].get(spec["span"])
 
 
+def _growth(names, over: str, ctx: Dict) -> Optional[float]:
+    """The summed growth of telemetry counters over ``setup`` or the
+    ``window``; None where none of them was ever counted."""
+    lo, hi = ctx["counters"][over]
+    names = [names] if isinstance(names, str) else names
+    seen = [n for n in names if n in hi or n in lo]
+    if not seen:
+        return None
+    return sum(hi.get(n, 0.0) - lo.get(n, 0.0) for n in seen)
+
+
 def _counter(spec: Dict, ctx: Dict) -> Optional[float]:
-    """A telemetry counter's growth over ``setup`` or the ``window``."""
-    lo, hi = ctx["counters"][spec["over"]]
-    if spec["counter"] not in hi and spec["counter"] not in lo:
-        return 0.0 if spec.get("zero_if_absent") else None
-    return hi.get(spec["counter"], 0.0) - lo.get(spec["counter"], 0.0)
+    """A counter's growth, or the sum of a list's, over ``setup`` or the
+    ``window``; with ``per``, over another counter's growth over the
+    same interval (``{"counter": ...}``) or a quantity the run counted
+    (``{"quantity": ...}``), and nothing where that is absent or 0."""
+    v = _growth(spec["counter"], spec["over"], ctx)
+    if v is None:
+        if not spec.get("zero_if_absent"):
+            return None
+        v = 0.0
+    per = spec.get("per")
+    if per is None:
+        return v
+    if "counter" in per:
+        den = _growth(per["counter"], spec["over"], ctx)
+    else:
+        den = ctx["quantities"].get(per["quantity"])
+    return v / den if den else None
 
 
 def _ratio(spec: Dict, ctx: Dict) -> Optional[float]:
@@ -74,8 +97,26 @@ def _quantity(spec: Dict, ctx: Dict) -> Optional[float]:
     return None if v is None else v * spec.get("scale", 1.0)
 
 
+def _idle(spec: Dict, ctx: Dict) -> Optional[float]:
+    """Seconds of the traced window's device idle gaps whose name (the
+    host span under the gap, ``xplane.named_gaps``) matches
+    ``patterns``, a traced iteration; nothing where no host span in the
+    window matches, as in a program without such a phase."""
+    trace = ctx.get("trace")
+    win = xplane.window_of(trace) if trace else None
+    iters = ctx["quantities"].get("traced_iterations")
+    if win is None or not iters:
+        return None
+    if not xplane.matching(xplane.clip(trace["host"], *win),
+                           spec["patterns"]):
+        return None
+    hit = xplane.matching(xplane.named_gaps(trace), spec["patterns"])
+    return sum(e - s for _, s, e in hit) / iters
+
+
 KINDS = {"span": _span, "counter": _counter, "ratio": _ratio,
-         "trace": _trace, "work": _work, "quantity": _quantity}
+         "trace": _trace, "work": _work, "quantity": _quantity,
+         "idle": _idle}
 
 
 def read_all(metrics, ctx: Dict) -> Dict[str, Dict]:

@@ -1,11 +1,11 @@
 """From a profiler trace to numbers: which intervals the device was
-busy in, how long the events of a name pattern took, what the longest
-idle gaps were under.
+busy in, how long the events of a name pattern took, what the host was
+doing in each of the device's idle gaps.
 
 ``load`` reads an ``.xplane.pb`` with ``jax.profiler.ProfileData`` into
-plain tuples; everything else works on those, so that a small recorded
-trace (tests/benchmark/trace_small.json) checks the reduction with no
-profiler at hand."""
+plain tuples; everything else works on those, so that small recorded
+traces (tests/benchmark/trace_small.json, trace_spans_small.json) check
+the reduction with no profiler at hand."""
 from __future__ import annotations
 
 import glob
@@ -15,8 +15,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 DEVICE_PLANE = "/device:TPU:"
 OP_LINE = "XLA Ops"
-HOST_SPAN_PREFIX = "bench."
+# the host spans kept: the benchmark's own, and the program's phases
+# (``lightgbm_tpu.utils.profiling.SPAN_PREFIX``, written here as a
+# literal: only harness/trainer.py imports the program)
+HOST_SPAN_PREFIXES = ("bench.", "ltpu.")
 WINDOW_SPAN = "bench.window"
+NO_HOST_SPAN = "no_host_span"
 
 Event = Tuple[str, float, float]        # name, start_s, end_s
 
@@ -46,7 +50,8 @@ def find_xplane(logdir: str) -> str:
 def load(path: str, device_plane: str = DEVICE_PLANE,
          op_line: str = OP_LINE) -> Dict:
     """{"devices": {plane: [Event...]}, "host": [Event...]}: the device
-    operations of every device plane, and the host's ``bench.*`` spans."""
+    operations of every device plane, and the host's ``bench.*`` and
+    ``ltpu.*`` spans."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     devices: Dict[str, List[Event]] = {}
@@ -65,7 +70,7 @@ def load(path: str, device_plane: str = DEVICE_PLANE,
                 host.extend((e.name, e.start_ns * 1e-9,
                              (e.start_ns + e.duration_ns) * 1e-9)
                             for e in line.events
-                            if e.name.startswith(HOST_SPAN_PREFIX))
+                            if e.name.startswith(HOST_SPAN_PREFIXES))
     return {"devices": devices, "host": host}
 
 
@@ -150,9 +155,30 @@ def top_ops(trace: Dict, k: int = 10):
             sorted(tot.items(), key=lambda kv: -kv[1])[:k]]
 
 
-def idle_gaps(trace: Dict, k: int = 10):
-    """The longest gaps between device operations on the first device
-    plane, each named by the host span that covers most of it."""
+def name_gap(s: float, e: float, spans: Sequence[Event]) -> str:
+    """What the host was doing in the gap ``[s, e)``: the shortest of
+    ``spans`` that covers at least half of it (the innermost phase
+    under it); else the one that covers most of it; else
+    ``no_host_span``."""
+    inner, inner_len = None, float("inf")
+    most, cover = NO_HOST_SPAN, 0.0
+    for name, hs, he in spans:
+        c = min(e, he) - max(s, hs)
+        if c <= 0:
+            continue
+        if 2 * c >= e - s and he - hs < inner_len:
+            inner, inner_len = name, he - hs
+        if c > cover:
+            most, cover = name, c
+    return inner if inner is not None else most
+
+
+def named_gaps(trace: Dict) -> List[Event]:
+    """Every gap between device operations on the first device plane in
+    the traced window, in order, each named by ``name_gap`` from the
+    host spans other than ``bench.window``."""
+    if "gaps" in trace:             # read once: the gaps are many
+        return trace["gaps"]
     win = window_of(trace)
     if win is None or not trace["devices"]:
         return []
@@ -166,24 +192,36 @@ def idle_gaps(trace: Dict, k: int = 10):
         end = max(end, e)
     if hi > end:
         gaps.append((end, hi))
-    gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:k]
-    spans = [h for h in trace["host"] if h[0] != WINDOW_SPAN]
-    out = []
+    # one sweep: the gaps are disjoint and in order, so a span that
+    # ends before a gap starts serves no later gap
+    spans = sorted((h for h in trace["host"] if h[0] != WINDOW_SPAN),
+                   key=lambda h: h[1])
+    out, live, i = [], [], 0
     for s, e in gaps:
-        best, cover = "no_host_span", 0.0
-        for name, hs, he in spans:
-            c = min(e, he) - max(s, hs)
-            if c > cover:
-                best, cover = name, c
-        out.append([best, e - s])
+        while i < len(spans) and spans[i][1] < e:
+            live.append(spans[i])
+            i += 1
+        live = [h for h in live if h[2] > s]
+        out.append((name_gap(s, e, live), s, e))
+    trace["gaps"] = out
     return out
 
 
+def idle_gaps(trace: Dict, k: int = 10):
+    """The ``k`` longest idle gaps of ``named_gaps``: [name, seconds]."""
+    longest = sorted(named_gaps(trace), key=lambda g: g[1] - g[2])[:k]
+    return [[name, e - s] for name, s, e in longest]
+
+
 def excerpt(trace: Dict, seconds: float = 0.2) -> Dict:
-    """The first ``seconds`` of the window, as JSON can hold it: the
-    small recorded trace of the tests is one of these."""
-    lo, _ = window_of(trace)
-    hi = lo + seconds
+    """``seconds`` of the window around the start of its longest idle
+    gap (the block boundary, where there is one), as JSON can hold it:
+    the small recorded traces of the tests are these."""
+    lo, hi = window_of(trace)
+    gaps = named_gaps(trace)
+    mid = max(gaps, key=lambda g: g[2] - g[1])[1] if gaps else lo
+    lo = max(lo, min(mid - seconds / 2, hi - seconds))
+    hi = min(hi, lo + seconds)
     return {"devices": {p: clip(evs, lo, hi)
                         for p, evs in trace["devices"].items()},
             "host": clip(trace["host"], lo, hi)}

@@ -189,7 +189,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             # the profiler starts while a block is on the device; one
             # block not counted brings host and device to a boundary
             opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0    # bench.* spans only
+            opts.python_tracer_level = 0    # annotations only: bench.*, ltpu.*
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
             win.run_block()
             win.land()

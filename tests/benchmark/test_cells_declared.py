@@ -112,13 +112,13 @@ def test_four_rank_trainer_agrees_with_reference(bench_root, monkeypatch):
 
 
 def test_collective_counters_read_what_the_passes_moved(bench_root):
-    """``collective_bytes_in_window`` and ``collective_ops_in_window``,
+    """``collective_bytes_per_tree`` and ``collective_ops_per_tree``,
     through the metric files' own readers, are the bytes and psums
-    reckoned from the window's passes and the passes' shapes; the
-    set-up's ``shard_upload_s`` is read too."""
+    reckoned from the window's passes and the passes' shapes, over the
+    window's trees; the set-up's ``shard_upload_s`` is read too."""
     cell = cells.load_cell("tiny4.fused", bench_root)
     metrics = {}
-    for name in ("collective_bytes_in_window", "collective_ops_in_window",
+    for name in ("collective_bytes_per_tree", "collective_ops_per_tree",
                  "shard_upload_s"):
         with open(os.path.join(BENCH, "metrics", f"{name}.json")) as f:
             metrics[name] = json.load(f)
@@ -151,10 +151,10 @@ def test_collective_counters_read_what_the_passes_moved(bench_root):
     lanes = min(gp.speculate, gp.num_leaves)
     a_pass = lanes * tr.gbdt._F_pad * gp.split.max_bin * 3 * 4
     reckoned = (passes + trees) * a_pass
-    moved = got["collective_bytes_in_window"]["value"]
-    assert reckoned <= moved <= reckoned * 1.01
-    assert moved == reckoned + trees * (
-        3 * 4 + 2 * 4 + gp.num_leaves * 3 * 4 + 4)
-    assert got["collective_ops_in_window"]["value"] == \
-        passes + trees * (1 + 1 + 3 + 1)
+    moved = got["collective_bytes_per_tree"]["value"]
+    assert reckoned / trees <= moved <= reckoned / trees * 1.01
+    assert moved == (reckoned + trees * (
+        3 * 4 + 2 * 4 + gp.num_leaves * 3 * 4 + 4)) / trees
+    assert got["collective_ops_per_tree"]["value"] == \
+        (passes + trees * (1 + 1 + 3 + 1)) / trees
     tr.close()

@@ -3,13 +3,15 @@
 They cover the plain reference against the trainer on both growth
 tiers, the control (the reference in the precision below the cell's)
 and the planted faults coming out as not correct, the work count, the
-trace reduction on a small recorded trace, the loader finding files a
-later PR would add, and run.py's refusals.  Nothing here touches a JAX
-backend while it is imported."""
+trace reduction on small recorded traces and on traces built by hand,
+the readers of the program's spans and counters, the loader finding
+files a later PR would add, and run.py's refusals.  Nothing here
+touches a JAX backend while it is imported."""
 from __future__ import annotations
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -200,19 +202,35 @@ def test_unknown_device_kind_is_an_error():
 
 
 # ---------------------------------------------------------------- trace
-@pytest.fixture(scope="module")
-def small_trace():
-    with open(os.path.join(HERE, "trace_small.json")) as f:
+def load_recorded(name):
+    """A recorded trace (``xplane.excerpt`` of a chip run) as ``load``
+    gives it."""
+    with open(os.path.join(HERE, name)) as f:
         raw = json.load(f)
     return {"devices": {k: [tuple(e) for e in v]
                         for k, v in raw["devices"].items()},
             "host": [tuple(e) for e in raw["host"]]}
 
 
-def test_trace_busy_union(small_trace):
-    b = xplane.busy(small_trace)
-    lo, hi = xplane.window_of(small_trace)
-    evs = xplane.clip(next(iter(small_trace["devices"].values())), lo, hi)
+RECORDED = ("trace_small.json", "trace_spans_small.json")
+
+
+@pytest.fixture(scope="module")
+def small_trace():
+    return load_recorded("trace_small.json")
+
+
+@pytest.fixture(scope="module")
+def spans_trace():
+    return load_recorded("trace_spans_small.json")
+
+
+@pytest.mark.parametrize("name", RECORDED)
+def test_trace_busy_union(name):
+    trace = load_recorded(name)
+    b = xplane.busy(trace)
+    lo, hi = xplane.window_of(trace)
+    evs = xplane.clip(next(iter(trace["devices"].values())), lo, hi)
     assert 0 < b["busy_s"] <= b["window_s"] == pytest.approx(hi - lo)
     # the union never exceeds the sum, and a grid over the window agrees
     assert b["busy_s"] <= sum(e - s for _, s, e in evs) + 1e-12
@@ -238,23 +256,201 @@ def test_trace_name_patterns(small_trace):
     assert xplane.reduce_events(evs, spec["patterns"], "union") <= got + 1e-12
 
 
-def test_trace_breakdown(small_trace):
-    ops = xplane.top_ops(small_trace)
-    gaps = xplane.idle_gaps(small_trace)
+@pytest.mark.parametrize("name", RECORDED)
+def test_trace_breakdown(name):
+    trace = load_recorded(name)
+    ops = xplane.top_ops(trace)
+    gaps = xplane.idle_gaps(trace)
     assert 0 < len(ops) <= 10 and len(gaps) <= 10
     assert ops == sorted(ops, key=lambda o: -o[1])
     assert all(g[1] > 0 for g in gaps)
+    assert gaps == sorted(gaps, key=lambda g: -g[1])
 
 
-def test_reader_without_anything_to_read_returns_nothing():
+def test_load_keeps_the_program_spans_and_drops_the_rest(tmp_path):
+    """A real profile, on the CPU: the program's phase (``timed``, its
+    span prefix the literal ``xplane`` keeps) and the benchmark's spans
+    land on a host plane and are kept; any other annotation is not."""
+    import jax
+    trainer_mod.use_program()
+    from lightgbm_tpu.utils.profiling import SPAN_PREFIX, timed
+    assert SPAN_PREFIX in xplane.HOST_SPAN_PREFIXES
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # as run.py traces
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(xplane.WINDOW_SPAN):
+            with timed("superstep/to_tree", iter=3, k=8):
+                with jax.profiler.TraceAnnotation("other.phase"):
+                    jax.numpy.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    got = xplane.load(xplane.find_xplane(str(tmp_path)))
+    assert sorted(h[0] for h in got["host"]) == [
+        "bench.window", "ltpu.superstep.to_tree"]
+    (_, ws, we), (_, ts, te) = sorted(got["host"])
+    assert ws <= ts < te <= we
+
+
+def _by_hand_trace():
+    """Device operations with gaps (1, 2), (3, 5), (6, 8), (9, 10) and
+    (11, 12) in a window of 12 s, under nested host spans."""
+    ops = [("op", 0.0, 1.0), ("op", 2.0, 3.0), ("op", 5.0, 6.0),
+           ("op", 8.0, 9.0), ("op", 10.0, 11.0)]
+    host = [("bench.window", 0.0, 12.0), ("bench.block", 0.0, 9.2),
+            # (1, 2): to_tree covers 0.8 of it, the span inside it 0.2
+            ("ltpu.superstep.to_tree", 1.0, 1.8),
+            ("ltpu.tree.inner", 1.1, 1.3),
+            # (3, 5): the program's span covers a quarter
+            ("ltpu.superstep.dispatch", 4.5, 5.0),
+            # (6, 8): both program spans cover over half: the shorter
+            ("ltpu.superstep.fetch", 5.5, 8.5),
+            ("ltpu.tree.fetch", 6.0, 7.5)]
+    return {"devices": {"/device:TPU:0": ops}, "host": host}
+
+
+def test_gap_named_by_the_innermost_span_under_it():
+    trace = _by_hand_trace()
+    assert xplane.named_gaps(trace) == [
+        ("ltpu.superstep.to_tree", 1.0, 2.0),
+        ("bench.block", 3.0, 5.0),      # no program span covers half
+        ("ltpu.tree.fetch", 6.0, 8.0),
+        ("bench.block", 9.0, 10.0),     # none covers half: the most
+        ("no_host_span", 11.0, 12.0)]
+    assert xplane.idle_gaps(trace, 2) == [["bench.block", 2.0],
+                                          ["ltpu.tree.fetch", 2.0]]
+
+
+def _metric(name):
+    with open(os.path.join(BENCH, "metrics", f"{name}.json")) as f:
+        return json.load(f)
+
+
+def test_idle_kind_on_a_trace_by_hand():
+    """Only the gap under ``superstep/to_tree``, host work, counts:
+    1 s over 4 traced iterations."""
+    spec = _metric("idle_in_host_work_s_per_iter")
+    ctx = {"trace": _by_hand_trace(),
+           "quantities": {"traced_iterations": 4}}
+    assert readers.read_all([spec], ctx)[spec["name"]]["value"] == 0.25
+    # a window with no host-work span reads nothing, not 0
+    bare = _by_hand_trace()
+    bare["host"] = [h for h in bare["host"] if "dispatch" not in h[0]
+                    and "to_tree" not in h[0]]
+    assert readers.read_all([spec], dict(ctx, trace=bare)) == {}
+
+
+def _named_by_hand(s, e, spans):
+    """``xplane.name_gap`` written the slow way: candidates by length."""
+    def cover(h):
+        return max(0.0, min(e, h[2]) - max(s, h[1]))
+    over_half = sorted((h for h in spans if 2 * cover(h) >= e - s),
+                       key=lambda h: h[2] - h[1])
+    if over_half:
+        return over_half[0][0]
+    most = max(spans, key=cover, default=None)
+    return most[0] if most is not None and cover(most) > 0 \
+        else "no_host_span"
+
+
+def test_idle_kind_on_the_recorded_trace(spans_trace):
+    """The recorded block boundary: the gaps found on a grid of the
+    window, each named the slow way, summed where the name is host
+    work; the longest gap is named by a program phase."""
+    spec = _metric("idle_in_host_work_s_per_iter")
+    lo, hi = xplane.window_of(spans_trace)
+    evs = sorted(xplane.clip(next(iter(spans_trace["devices"].values())),
+                             lo, hi), key=lambda ev: ev[1])
+    spans = [h for h in spans_trace["host"] if h[0] != "bench.window"]
+    gaps, end = [], lo
+    for _, s, e in evs:
+        if s > end:
+            gaps.append((s - end, _named_by_hand(end, s, spans)))
+        end = max(end, e)
+    if hi > end:
+        gaps.append((hi - end, _named_by_hand(end, hi, spans)))
+    rx = [re.compile(p) for p in spec["read"]["patterns"]]
+    by_hand = sum(d for d, n in gaps if any(r.search(n) for r in rx))
+    ctx = {"trace": spans_trace, "quantities": {"traced_iterations": 1}}
+    got = readers.read_all([spec], ctx)[spec["name"]]["value"]
+    assert got == pytest.approx(by_hand, rel=1e-12) and got > 0
+    assert sum(d for d, _ in gaps) == pytest.approx(
+        (hi - lo) - xplane.busy(spans_trace)["busy_s"], rel=1e-9)
+    assert xplane.idle_gaps(spans_trace, 1)[0][0].startswith("ltpu.")
+
+
+def test_counter_per_against_raw_pairs():
+    """A list of counters is summed; ``per`` divides by another
+    counter's growth over the same interval or by a quantity, and
+    reads nothing where the divisor is absent or 0."""
+    lo = {"grow_waves": 10.0, "trees_grown": 4.0,
+          "phase_secs/superstep/fetch": 1.0, "phase_secs/tree/fetch": 0.5}
+    hi = {"grow_waves": 100.0, "trees_grown": 13.0,
+          "phase_secs/superstep/fetch": 4.0, "phase_secs/tree/fetch": 0.5,
+          "phase_secs/tree/device_wait": 2.0}
+    ctx = {"counters": {"window": (lo, hi), "setup": ({}, lo)},
+           "quantities": {"window_iterations": 10}}
+    got = readers.read_all([_metric("waves_per_tree"),
+                            _metric("fetch_wait_s_per_iter")], ctx)
+    assert got["waves_per_tree"]["value"] == (100 - 10) / (13 - 4)
+    assert got["fetch_wait_s_per_iter"]["value"] == (3.0 + 0.0 + 2.0) / 10
+    flat = {"counters": {"window": (lo, dict(hi, trees_grown=4.0))},
+            "quantities": {"window_iterations": 0}}
+    assert readers.read_all([_metric("waves_per_tree"),
+                             _metric("fetch_wait_s_per_iter")], flat) == {}
+    none = {"counters": {"window": ({"grow_waves": 1.0},
+                                    {"grow_waves": 5.0})},
+            "quantities": {}}
+    assert readers.read_all([_metric("waves_per_tree"),
+                             _metric("fetch_wait_s_per_iter")], none) == {}
+
+
+NEW = ("fetch_wait_s_per_iter", "host_work_s_per_iter",
+       "idle_in_host_work_s_per_iter", "waves_per_tree",
+       "refine_passes_per_tree", "wave_lane_fill", "routed_waves_per_tree",
+       "collective_bytes_per_tree", "collective_ops_per_tree")
+
+
+def test_reader_without_anything_to_read_returns_nothing(small_trace):
     ctx = {"spans": {}, "quantities": {}, "counters":
            {"setup": ({}, {}), "window": ({}, {})}, "trace": None,
            "traced_trees": [], "features": 4, "rows": 10, "peaks": {}}
     metrics = [json.load(open(os.path.join(BENCH, "metrics", f)))
                for f in sorted(os.listdir(os.path.join(BENCH, "metrics")))]
+    assert set(NEW) <= {m["name"] for m in metrics}
     got = readers.read_all(metrics, ctx)
     assert set(got) <= {"compiles_in_window"}     # a count may be 0
     assert not any("roofline" in k or "mfu" in k for k in got)
+    # a trace that holds no program span: no idle time is put on one
+    ctx.update(trace=small_trace, quantities={"traced_iterations": 1})
+    assert "idle_in_host_work_s_per_iter" not in readers.read_all(
+        metrics, ctx)
+
+
+def test_growth_counts_add_up_in_a_traced_run(bench_root, on_cpu,
+                                              monkeypatch, small_trace):
+    """A traced run of the real trainer through ``run_cell``, the
+    profiler and its reading stood in for by a recorded trace: each
+    tree's passes are its waves, its refine passes and its root pass,
+    and the host's phases fit in the window's blocks."""
+    import jax
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(bench_run, "traced_metrics", lambda d, w: (
+        dict(small_trace), xplane.busy(small_trace)))
+    monkeypatch.setitem(readers.KINDS, "work", lambda spec, ctx: None)
+    cell = cells.load_cell("tiny.fused", bench_root)
+    names = ("hist_passes_per_tree", "block_s_per_iter_max") + NEW[:6]
+    cell.metrics = [_metric(n) for n in names]
+    res = bench_run.run_cell(cell, SEED, 0.3, True)
+    assert res["correct"], res["checks"]
+    m = {k: v["value"] for k, v in res["metrics"].items()}
+    assert set(m) == set(names) - {"idle_in_host_work_s_per_iter"}
+    assert m["hist_passes_per_tree"] == pytest.approx(
+        m["waves_per_tree"] + m["refine_passes_per_tree"] + 1, rel=1e-12)
+    assert m["waves_per_tree"] >= 1 and 0 < m["wave_lane_fill"] <= 1
+    assert m["fetch_wait_s_per_iter"] + m["host_work_s_per_iter"] <= \
+        m["block_s_per_iter_max"]
 
 
 # --------------------------------------------------------------- loader
@@ -283,20 +479,21 @@ def _manifest():
         return json.load(f)
 
 
-def test_manifest_names_files_that_exist():
+@pytest.mark.parametrize("entry", _manifest()["workloads"],
+                         ids=lambda e: e["name"])
+def test_manifest_names_files_that_exist(entry):
     m = _manifest()
     assert m["command"] == ["python3", "benchmark/run.py"]
-    for c in m["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        assert cfg["source"] == c["source"]
-        assert cfg["reduced"] == c["reduced"]
-    for w in m["workloads"]:
-        cell = cells.load_cell(w["name"])
-        assert cell.workload["config"] == w["config"]
-        assert cell.workload["traffic"] == w["traffic"]
-        assert cell.workload["chips"] == w["chips"] == 1
-        assert cell.workload["why"] == w["why"]
+    c = next(c for c in m["configs"] if c["name"] == entry["config"])
+    with open(os.path.join(ROOT, c["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["source"] == c["source"]
+    assert cfg["reduced"] == c["reduced"]
+    cell = cells.load_cell(entry["name"])
+    assert cell.workload["config"] == entry["config"]
+    assert cell.workload["traffic"] == entry["traffic"]
+    assert cell.workload["chips"] == entry["chips"] in (1, 4)
+    assert cell.workload["why"] == entry["why"]
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in

@@ -1,8 +1,9 @@
-"""The per-layer metrics that read the program's own spans and
-counters (PR 25): each is a file beside the old ones, read by a reader
-the benchmark already had, and finds its counter in a run of the real
+"""The per-layer metrics of set-up that read the program's own spans
+and counters: each is a file beside the old ones, read by the
+``counter`` reader, and finds its counter in a run of the real
 trainer; where the program has no such counter it reads nothing; the
-metrics the benchmark opened with read what they read before."""
+metrics the benchmark opened with read what they read before, on both
+recorded traces."""
 from __future__ import annotations
 
 import json
@@ -22,7 +23,8 @@ from harness import (cells, datagen, readers, reference,   # noqa: E402
                      trainer as trainer_mod, xplane)
 
 NEW = ("bin_s", "xt_host_prep_s", "superstep_compile_s", "jax_trace_s")
-CELLS = ["higgs28.fast", "criteo67.fast"]
+CELLS = ["higgs28.fast", "criteo67.fast", "criteo67x4.fast",
+         "epsilon2000.fast"]
 
 
 def _metric(name):
@@ -99,21 +101,36 @@ def _chain_tree():
         np.array([6_000_000, 3_000_000, 1_000_000], np.int64), 1.0)
 
 
-# what the parent of PR 25 read from the recorded trace, with the
-# made-up quantities below
+# what the benchmark's harness read from the two recorded traces, with
+# the made-up quantities below, before it kept the program's spans: the
+# parent of PR 25 on trace_small.json, and on trace_spans_small.json (a
+# block boundary of higgs28.fast with the program's spans) the harness
+# whose ``load`` dropped them
 PINNED = {
-    "block_s_per_iter_max": 1.07, "compile_s": 68.4,
-    "compiles_in_window": 0.0, "data_prep_s": 10.5,
-    "device_idle_pct": 4.214073999993861,
-    "hist_kernel_s_per_iter": 0.17531951400000167,
-    "hist_passes_per_tree": 22.0, "peak_hbm_gib": 2.4,
-    "hist_roofline": 0.2925062368245602,
-    "train_mfu": 0.4029304029304044}
+    "trace_small.json": {
+        "block_s_per_iter_max": 1.07, "compile_s": 68.4,
+        "compiles_in_window": 0.0, "data_prep_s": 10.5,
+        "device_idle_pct": 4.214073999993861,
+        "hist_kernel_s_per_iter": 0.17531951400000167,
+        "hist_passes_per_tree": 22.0, "peak_hbm_gib": 2.4,
+        "hist_roofline": 0.2925062368245602,
+        "train_mfu": 0.4029304029304044},
+    "trace_spans_small.json": {
+        "block_s_per_iter_max": 1.07, "compile_s": 68.4,
+        "compiles_in_window": 0.0, "data_prep_s": 10.5,
+        "device_idle_pct": 28.619164000001508,
+        "hist_kernel_s_per_iter": 0.1164451729999989,
+        "hist_passes_per_tree": 22.0, "peak_hbm_gib": 2.4,
+        "hist_roofline": 0.440396539941177,
+        "train_mfu": 0.4029304029304026}}
+# what only the program's spans let the harness read there: the gap
+# from the block's last operation to the window's end, under
+# ``ltpu.superstep.to_tree``, over the one traced iteration
+SPANS_ONLY = {"idle_in_host_work_s_per_iter": 0.05721665300000023}
 
 
-@pytest.fixture(scope="module")
-def recorded_ctx():
-    with open(os.path.join(HERE, "trace_small.json")) as f:
+def recorded_ctx(name):
+    with open(os.path.join(HERE, name)) as f:
         raw = json.load(f)
     trace = {"devices": {k: [tuple(e) for e in v]
                          for k, v in raw["devices"].items()},
@@ -138,10 +155,16 @@ def recorded_ctx():
         "peaks": {"hbm_bytes_per_s": 819e9}}
 
 
-@pytest.mark.parametrize("metric", sorted(PINNED))
-def test_existing_metric_reads_the_same(recorded_ctx, metric):
+@pytest.mark.parametrize("trace,metric", [(t, m) for t in sorted(PINNED)
+                                          for m in sorted(PINNED[t])])
+def test_existing_metric_reads_the_same(trace, metric):
     metrics = [_metric(f[:-5])
                for f in sorted(os.listdir(os.path.join(BENCH, "metrics")))]
-    got = readers.read_all(metrics, dict(recorded_ctx))
-    assert got[metric]["value"] == pytest.approx(PINNED[metric], rel=1e-12)
-    assert set(got) == set(PINNED)       # the new ones find nothing here
+    got = readers.read_all(metrics, recorded_ctx(trace))
+    assert got[metric]["value"] == pytest.approx(PINNED[trace][metric],
+                                                 rel=1e-12)
+    # the other new ones find no counter here
+    extra = SPANS_ONLY if trace == "trace_spans_small.json" else {}
+    assert set(got) == set(PINNED[trace]) | set(extra)
+    for k, v in extra.items():
+        assert got[k]["value"] == pytest.approx(v, rel=1e-12)
