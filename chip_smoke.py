@@ -328,7 +328,7 @@ def main() -> int:
     log(f"compiles: {c.get('xla_compiles', 0):.0f} requests "
         f"({c.get('xla_compile_secs', 0.0):.1f} s); persistent cache "
         f"{cache_dir}: {c.get('jax_cache_hits', 0):.0f} hits, "
-        f"{c.get('jax_cache_misses', 0):.0f} misses")
+        f"{c.get('jax_cache_misses', 0):.0f} writes")
     mem = dev.memory_stats() or {}
     log(f"device memory: peak {mem.get('peak_bytes_in_use', 0) / 2**20:.0f}"
         f" MiB of {mem.get('bytes_limit', 0) / 2**20:.0f} MiB")

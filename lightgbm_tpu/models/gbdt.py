@@ -1552,7 +1552,9 @@ class GBDT:
         behind the next block's device compute), then land the oldest
         block and serve its first tree.  The healthy-path device-call
         budget stays 2 per K-block at any pipeline depth — pipelining
-        reorders the same dispatch+fetch pair, it never adds calls."""
+        reorders the same dispatch+fetch pair, it never adds calls, and
+        the fetch's ``block_until_ready`` (``fetch/wait``) waits on the
+        pack its transfer waits on, so it adds none either."""
         self._flush_pending()
         if self._stop_flag:
             return True
@@ -1595,7 +1597,7 @@ class GBDT:
         with timed("superstep/fetch", iter=i0, k=K):
             # the block's ONE device->host transfer (packed f32)
             _telemetry.counters.incr("superstep_fetches")
-            host = self._fetch_records(entry["outs"][4])
+            host = self._fetch_records(entry["outs"][4], iter=i0, k=K)
         self._abandoned_check(elastic_alive)
         # the live fence moves to the next outstanding dispatch (or
         # clears): this block is fetched, its state commits below
@@ -2109,7 +2111,7 @@ class GBDT:
             from ..utils.profiling import timed
             with timed("tree/device_wait", iter=self.iter):
                 jax.block_until_ready(rec["n_leaves"])
-        recs = self._fetch_records(rec)
+        recs = self._fetch_records(rec, iter=self.iter)
         self._count_growth(recs)
         n_leaves = int(recs["n_leaves"])
         if n_leaves <= 1:
@@ -2497,7 +2499,7 @@ class GBDT:
             with timed("tree/fetch", iter=self.iter):
                 # one packed device->host transfer per tree; doubles as
                 # the device sync
-                recs = self._fetch_records(rec)
+                recs = self._fetch_records(rec, iter=self.iter)
             n_leaves = int(recs["n_leaves"])
             self._count_growth(recs)
 
@@ -2637,16 +2639,24 @@ class GBDT:
                           waves * min(p.speculate, p.num_leaves))
         return arm + n_trees
 
-    def _fetch_records(self, rec):
+    def _fetch_records(self, rec, **ids):
         """ONE device->host transfer per tree: every split record except
         the (N,) leaf assignment (which stays on device for the score
         update), concatenated into a single f32 buffer on device —
         ``device_get`` on a dict pays one transfer PER array, and the
         records hold ~15.  All record values (leaf ids,
         bins, gains, stats, flag bits) are exactly representable in f32.
+
+        Two child phases split the caller's fetch phase: ``fetch/wait``,
+        the pack's dispatch up to ``block_until_ready`` on its result
+        (the wait for the device), and ``fetch/copy``, the transfer to
+        the host and the unpack.  The ``block_until_ready`` waits on
+        the pack the transfer waits on anyway: it adds no device call.
         """
         import jax
         import jax.numpy as jnp
+
+        from ..utils.profiling import timed
 
         keys = [k for k in sorted(rec) if k != "leaf_idx"]
         layout = [(k, tuple(rec[k].shape), np.dtype(rec[k].dtype))
@@ -2658,12 +2668,16 @@ class GBDT:
             self._rec_layout = layout
             self._rec_pack = jax.jit(lambda r: jnp.concatenate(
                 [r[k].astype(jnp.float32).reshape(-1) for k in keys]))
-        flat = np.asarray(self._rec_pack({k: rec[k] for k in keys}))
-        out, off = {}, 0
-        for k, shp, dt in self._rec_layout:
-            size = int(np.prod(shp)) if shp else 1
-            out[k] = flat[off:off + size].reshape(shp).astype(dt)
-            off += size
+        with timed("fetch/wait", **ids):
+            packed = jax.block_until_ready(
+                self._rec_pack({k: rec[k] for k in keys}))
+        with timed("fetch/copy", **ids):
+            flat = np.asarray(packed)
+            out, off = {}, 0
+            for k, shp, dt in self._rec_layout:
+                size = int(np.prod(shp)) if shp else 1
+                out[k] = flat[off:off + size].reshape(shp).astype(dt)
+                off += size
         return out
 
     # ------------------------------------------------------------------

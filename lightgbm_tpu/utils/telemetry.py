@@ -350,6 +350,11 @@ def counters_snapshot() -> Dict[str, float]:
 
 _HOOKS_INSTALLED = False
 _HOOKS_LOCK = threading.Lock()
+# a compile request's outcome, by the thread that makes it
+_COMPILE_OUTCOME = threading.local()
+# what a compile request came to: served by the persistent cache, or not
+_LOADED = ("xla_cache_loads", "xla_cache_load_secs")
+_FRESH = ("xla_fresh_compiles", "xla_fresh_compile_secs")
 
 
 def install_jax_hooks() -> None:
@@ -368,7 +373,26 @@ def install_jax_hooks() -> None:
     totals as ``xla_compiles/<fun_name>``,
     ``xla_compile_secs/<fun_name>`` and ``jax_trace_secs/<fun_name>``,
     so a recompile inside a run names its program in the record's
-    ``counters``."""
+    ``counters``.
+
+    Each compile request also goes to exactly one outcome: where the
+    persistent cache serves it, the thread that makes it sees
+    ``cache_hits`` before its ``backend_compile_duration``, which
+    consumes that mark.  Served, it counts in
+    ``xla_cache_loads`` and ``xla_cache_load_secs``; else (a miss, or a
+    request that never asked the cache) in ``xla_fresh_compiles`` and
+    ``xla_fresh_compile_secs``, so the two outcomes add up to
+    ``xla_compiles`` and ``xla_compile_secs``.  Each has its
+    ``/<fun_name>`` counters too, and a request adds 0 to the other
+    outcome's, so that a program loaded whole reads 0 fresh seconds.
+    Lowering (``jaxpr_to_mlir_module_duration``, ``fun_name`` as
+    ``jit(f)``) goes to ``jax_lower_secs`` and its
+    ``jax_lower_secs/<fun_name>``.  The totals stand at 0 from here on:
+    a process with the hooks reads 0 where nothing happened.
+    ``jax_cache_hits`` counts the cache's hits; ``jax_cache_misses``
+    counts its WRITES (jax fires ``cache_misses`` when it puts an entry
+    in, and not for one under the cache's minimum size), not the
+    requests it could not serve."""
     global _HOOKS_INSTALLED
     with _HOOKS_LOCK:
         if _HOOKS_INSTALLED:
@@ -378,11 +402,25 @@ def install_jax_hooks() -> None:
         def _on_duration(name, secs, **kw):
             fun = kw.get("fun_name")
             if name.endswith("backend_compile_duration"):
+                loaded = getattr(_COMPILE_OUTCOME, "loaded", False)
+                _COMPILE_OUTCOME.loaded = False
+                took, other = (_LOADED, _FRESH) if loaded else \
+                    (_FRESH, _LOADED)
                 counters.incr("xla_compiles")
                 counters.incr("xla_compile_secs", secs)
+                counters.incr(took[0])
+                counters.incr(took[1], secs)
                 if fun:
                     counters.incr(f"xla_compiles/{fun}")
                     counters.incr(f"xla_compile_secs/{fun}", secs)
+                    counters.incr(f"{took[0]}/{fun}")
+                    counters.incr(f"{took[1]}/{fun}", secs)
+                    for key in other:
+                        counters.incr(f"{key}/{fun}", 0.0)
+            elif name.endswith("jaxpr_to_mlir_module_duration"):
+                counters.incr("jax_lower_secs", secs)
+                if fun:
+                    counters.incr(f"jax_lower_secs/{fun}", secs)
             elif name.endswith("jaxpr_trace_duration"):
                 counters.incr("jax_traces")
                 counters.incr("jax_trace_secs", secs)
@@ -393,8 +431,11 @@ def install_jax_hooks() -> None:
             if name.endswith("compilation_cache/cache_misses"):
                 counters.incr("jax_cache_misses")
             elif name.endswith("compilation_cache/cache_hits"):
+                _COMPILE_OUTCOME.loaded = True
                 counters.incr("jax_cache_hits")
 
+        for key in _LOADED + _FRESH + ("jax_lower_secs",):
+            counters.incr(key, 0.0)
         monitoring.register_event_duration_secs_listener(_on_duration)
         monitoring.register_event_listener(_on_event)
         _HOOKS_INSTALLED = True
