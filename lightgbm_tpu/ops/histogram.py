@@ -49,10 +49,13 @@ Value columns:
 - the ORDER of the int8 one-hot's rows is the kernel's own business:
   the contraction sums over data rows, so a permutation of the
   one-hot's rows is the same permutation of the accumulator's.  The
-  one-hot is made four rows to a 32-bit word at every bin count,
-  feature by feature on the 32-bin grid and slab by slab off it (the
-  coarse passes' 16 bins), whichever needs no regrouping
-  (``_onehot_int8``; the tier record's ``onehot``), and ONE helper on
+  one-hot is made four rows to a 32-bit word at every bin count, slab
+  by slab up to 32 bins (the coarse passes' 16 and the refine window's
+  32) and off the 32-bin grid, feature by feature at 64 bins and up,
+  whichever the chip runs faster: at 32 bins the slabs take each
+  feature's row as it stands where the words broadcast it over a
+  sublane group for every group they make (``_onehot_form``,
+  ``_onehot_int8``; the tier record's ``onehot``), and ONE helper on
   the XLA side, ``_feature_bin``, which every wrapper calls on its
   accumulator, puts the rows back to (feature, bin).  Nothing else
   knows the order.
@@ -240,10 +243,12 @@ class BinTiling(NamedTuple):
         from each row's subset index a 32-bit word at a time
         (:func:`_rhs_int8`), ``rows`` from it row by row
         (:func:`_rhs_bf16`); ``onehot``: the order the one-hot's rows
-        are built in, ``words`` or ``slabs`` by the bins where it is
-        int8 (:func:`_onehot_form`), ``plain`` (feature, bin) where it
-        is bf16.  All three follow the values: the caller says whether
-        the pass is given int8 ones (ops/grow.py
+        are built in, by the bins where it is int8
+        (:func:`_onehot_form`): ``slabs`` up to 32 bins (the coarse
+        and the refine passes) and off the 32-bin grid, ``words`` at
+        64 bins and up (a full-resolution pass); ``plain`` (feature,
+        bin) where it is bf16.  All three follow the values: the
+        caller says whether the pass is given int8 ones (ops/grow.py
         ``GrowParams.int8_values``).  ``chunks``: the feature blocks
         the grid walks (``f_pad // fc``)."""
         return {"f": self.f, "f_pad": self.f_pad, "fc": self.fc,
@@ -388,8 +393,26 @@ _TAIL = 4       # a features' tail of up to this many rows: own slabs
 def _onehot_form(b_pad: int) -> str:
     """The order :func:`_onehot_int8` builds in at ``b_pad`` bins:
     ``words`` (feature by feature) where the ``b_pad / 4`` words of a
-    feature fill whole 8-sublane groups, ``slabs`` elsewhere."""
-    return "words" if b_pad % 32 == 0 else "slabs"
+    feature fill two or more whole 8-sublane groups (64, 128, 256
+    bins), ``slabs`` elsewhere (8 to 56 bins, the 32-bin window
+    among them, and every ``b_pad`` off the 32-bin grid).
+
+    Measured alone on the chip, ms a pass, words against slabs
+    (``tools/check_routed_kernels.py``; PERF.md section 5).  At 32 bins
+    a feature's 8 words are ONE sublane group, and the words order
+    broadcasts the feature's row over it twice for that one group: the
+    refine pass 15.34 against 14.89 at 21M x 28, 32.45 against 32.11
+    at 20M x 67 (2144 one-hot rows against 2176: a tail of 3 takes
+    4), 26.41 against 25.71 at 16M x 68, 58.69 against 56.93 at
+    1.2M x 2000 (50 chunks), and Mosaic compiles it 0.8 to 1.7 s
+    sooner.  At 64 and 256 bins the broadcast serves two or eight
+    groups and the orders are level (25.96 and 25.82 at 21M x 28 and
+    64 bins, 47.00 and 46.83 at 16M x 68; 100.56 and 100.45, 184.81
+    and 184.86 at 256), or the slabs behind where they pad a tail
+    (57.73 against 58.37 at 20M x 67 and 64 bins); and a tail's slabs
+    may overrun a one-chunk accumulator there (67 features at 256
+    bins tile as ``f_pad`` 67)."""
+    return "words" if b_pad % 32 == 0 and b_pad > 32 else "slabs"
 
 
 def _slab_split(R: int) -> Tuple[int, int]:
@@ -425,27 +448,34 @@ def _onehot_int8(xb: jax.Array, b_pad: int) -> jax.Array:
     was some eleven vector operations a (32, 128) tile and set a coarse
     pass's pace at 18.2 to 18.5 us a one-hot row against the MXU's 13
     (PERF.md, PR 31).  The ``Q = b_pad / 4`` words of a feature lie
+    (:func:`_onehot_form` says which, and why)
 
-    - ``words``, ``Q`` a multiple of 8: feature by feature, word
-      ``r * Q + q``: int8 row ``r * b_pad + b`` holds ``xb[r] == b``.
-      The regrouping ``(R, Q, T) -> (R * Q, T)`` fills whole sublane
-      groups and costs nothing;
-    - ``slabs``, every other ``Q`` (16 bins: 4): there that regrouping
-      is a relayout (52.6 against 30.2 ms a routed coarse pass of
-      20M x 67; PERF.md, PR 29), so no three-dimensional value exists:
-      word ``q`` of EVERY feature is one compare and select on the
-      ``(R8, T)`` block as it stands, and the ``Q`` slabs are
-      concatenated on the sublane grid: word ``q * R8 + r``, int8 row
-      ``4 * (q * R8 + r) + k`` holds bin ``4q + k`` of feature ``r``.
-      A features' tail of up to 4 rows (28 = 24 + 4, 67 = 64 + 3)
-      would add 8 rows to every slab; it follows the whole groups in
-      slabs of 4 rows of its own, two to a sublane group (the tail
-      tiled twice, compared with a per-sublane ``q``): 448 and 1088
-      one-hot rows stream at 16 bins, not 512 and 1152, which is 0.6
-      to 1.0 ms of a routed coarse pass at the benchmark's shapes
-      (9.60 against 10.58 ms at 21M x 28, 18.40 against 19.23 at
-      20M x 67, where the plain form took 12.05 and 23.66; a row of
-      the slabs costs 13.1 to 14.0 us: PERF.md, PR 34)."""
+    - ``words``, ``Q`` a multiple of 8 and at least 16: feature by
+      feature, word ``r * Q + q``: int8 row ``r * b_pad + b`` holds
+      ``xb[r] == b``.  The regrouping ``(R, Q, T) -> (R * Q, T)``
+      fills whole sublane groups and costs nothing, but each feature's
+      row is broadcast over its groups' sublanes, once for ``hi`` and
+      once for ``byte``: at ``Q`` = 8 that is two broadcasts for one
+      group, some 4.75 vector operations a word vreg where the slabs
+      take 2.75;
+    - ``slabs``, every other ``Q`` (16 bins: 4; the 32-bin window: 8):
+      at 16 bins that regrouping is a relayout (52.6 against 30.2 ms a
+      routed coarse pass of 20M x 67, on the chip), so no
+      three-dimensional value exists: word ``q`` of EVERY feature is
+      one compare and select on the ``(R8, T)`` block as it stands,
+      and the ``Q`` slabs are concatenated on the sublane grid: word
+      ``q * R8 + r``, int8 row ``4 * (q * R8 + r) + k`` holds bin
+      ``4q + k`` of feature ``r``.  A features' tail of up to 4 rows
+      (28 = 24 + 4, 67 = 64 + 3) would add 8 rows to every slab; it
+      follows the whole groups in slabs of 4 rows of its own, two to a
+      sublane group (the tail tiled twice, compared with a per-sublane
+      ``q``): 448 and 1088 one-hot rows stream at 16 bins, not 512 and
+      1152, which is 0.6 to 1.0 ms of a routed coarse pass at the
+      benchmark's shapes (9.60 against 10.58 ms at 21M x 28, 18.40
+      against 19.23 at 20M x 67, where the plain form took 12.05 and
+      23.66; a row of the slabs costs 13.1 to 14.0 us: PERF.md, PR
+      34), and 896, 2176 (of 67 and of 68) and 1280 (a chunk of 40)
+      at 32 bins."""
     from jax.experimental.pallas import tpu as pltpu
     R, T = xb.shape
     Q = b_pad // 4

@@ -16,9 +16,11 @@ mode on the CPU:
   features' slabs and a tail's), 68 and 30 at 16, two-column and
   three-column values, with and without a missing bin;
 - the int8 one-hot in the order it is built in
-  (``ops/histogram._onehot_int8``: feature by feature, or slab by
-  slab off the 32-bin grid), put back by the wrappers' helper
-  (``_rows_to_feature_bin``), equals the plain one-hot row for row;
+  (``ops/histogram._onehot_int8``: slab by slab up to 32 bins and off
+  the 32-bin grid, feature by feature at 64 bins and up), put back by
+  the wrappers' helper (``_rows_to_feature_bin``), equals the plain
+  one-hot row for row, and a refine pass gives the same bits built
+  either way;
 - the largest partial sum of a tile (16384 rows of +127 or -127 in one
   bin) is exact;
 - the kernel's jaxpr: an int8 x int8 -> int32 ``dot_general`` and
@@ -105,7 +107,7 @@ def test_onehot_in_its_order_is_the_plain_onehot(b_pad, R):
     rows = H._onehot_rows(R, b_pad)
     assert rows % 32 == 0           # whole (32, 128) int8 tiles
     assert R * b_pad <= rows <= -(-R // 8) * 8 * b_pad
-    assert H._onehot_form(b_pad) == ("words" if b_pad in (32, 64)
+    assert H._onehot_form(b_pad) == ("words" if b_pad == 64
                                      else "slabs")
 
     def kernel(x_ref, o_ref):
@@ -122,13 +124,39 @@ def test_onehot_in_its_order_is_the_plain_onehot(b_pad, R):
 
 
 def test_rows_streamed_at_the_cells_shapes():
-    """What the coarse passes of the benchmark's cells stream: a tail
-    of 4 (of 28, of 68) or 3 (of 67) features is built as groups of
-    its own, not padded to the 8 rows of a slab."""
+    """What the coarse and the refine passes of the benchmark's cells
+    stream: a tail of 4 (of 28, of 68) or 3 (of 67) features is built
+    as groups of its own, not padded to the 8 rows of a slab (the tail
+    of 3 takes 4: 2176 rows of 67 features at 32 bins, where feature
+    by feature took 2144); a chunk of 40 of the wide cell has none."""
     assert [H._onehot_rows(f, 16) for f in (28, 67, 68, 30, 72)] == [
         448, 1088, 1088, 512, 1152]
-    assert [H._onehot_rows(f, 32) for f in (28, 67, 68)] == [
-        896, 2144, 2176]
+    assert [H._onehot_rows(f, 32) for f in (28, 67, 68, 40)] == [
+        896, 2176, 2176, 1280]
+
+
+@pytest.mark.parametrize("f", [28, 67])
+def test_refine_pass_is_the_same_under_both_builds(monkeypatch, f):
+    """A ``multi_win_lanes`` refine pass at the 32-bin window, its
+    one-hot built slab by slab and built feature by feature (the
+    ``words`` order the 32-bin passes took before): the same output
+    bit for bit, tail of 4 (28) and of 3 (67), with a missing bin."""
+    d = _data(f, 32, True, seed=f)
+    lanes = H.histogram_pallas_multi_win_lanes.__wrapped__  # a trace a call
+    args = (d["x"], d["v8"], d["li"], d["ids"], d["lo"], 32, W, RPB)
+    kw = dict(exact=True, miss_bin=d["mb"])
+    assert H._onehot_form(32) == "slabs"
+    slabs = np.asarray(lanes(*args, **kw))
+    asked = []
+
+    def words(b_pad):
+        asked.append(b_pad)
+        return "words"
+    monkeypatch.setattr(H, "_onehot_form", words)
+    by_words = np.asarray(lanes(*args, **kw))
+    assert 32 in asked              # the second pass was built by words
+    np.testing.assert_array_equal(slabs, by_words)
+    assert np.abs(slabs).sum() > 0
 
 
 @pytest.mark.parametrize("wrapper", ["multi", "multi_win_lanes"])

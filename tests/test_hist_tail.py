@@ -11,9 +11,10 @@ in front of a pass.  Pinned here, in interpret mode on the CPU:
   and at a chunked shape whose last block overhangs the matrix (67 at
   8 bins: five chunks of 16), with and without ``miss_bin``, uint8
   storage, int8 values (equality is exact); and where the int8
-  one-hot is built slab by slab (8, 16 and 24 bins), with a features'
-  tail in groups of its own (28, 67, 68), one padded to 8 (30) and
-  none (chunks of 16);
+  one-hot is built slab by slab (8, 16, 24 and 32 bins), with a
+  features' tail in groups of its own (28, 67, 68), one padded to 8
+  (30) and none (chunks of 16, among them a 32-bin pass whose
+  features chunk under a cut budget, as a wide set's refine pass);
 - the copy is gone: the wrapper's jaxpr holds no ``pad``,
   ``concatenate`` or ``dynamic_update_slice`` of an N-column array
   outside the ``pallas_call``, and a booster built with the ``fast``
@@ -34,20 +35,34 @@ N, RPB, W, FINE = 512, 256, 8, 256
 # (features, bins of the pass): tail 28->32, none, 67->72, 67->68, 5->8,
 # and 67->80 in five chunks of 16 (the last holds 3 stored features);
 # then the int8 one-hot's slab order (ops/histogram._onehot_int8) at
-# 8, 16 and 24 bins with a features' tail of 4, 3, 4 of 68 and 6 rows
-# (28 -> 24 + 4, 67 -> 64 + 3, 30 -> 32), and chunked (67 at 24 bins:
-# five chunks of 16, no tail)
+# 8, 16, 24 and 32 bins with a features' tail of 4, 3, 4 of 68 and 6
+# rows (28 -> 24 + 4, 67 -> 64 + 3, 30 -> 32), and chunked (67 at 24
+# bins: five chunks of 16, no tail); last, 44 features at 32 bins with
+# the tiler's budget cut (``CUT``), as a wide set's refine pass runs:
+# three chunks of 16, the last overhanging the matrix
 SHAPES = [(28, 16), (28, 32), (67, 16), (67, 32), (5, 16), (67, 8),
-          (28, 8), (28, 24), (68, 16), (30, 16), (67, 24)]
+          (28, 8), (28, 24), (68, 16), (30, 16), (67, 24), (68, 32),
+          (44, 32)]
+CUT = {(44, 32): 600_000}       # the tiler's VMEM budget for the shape
 WRAPPERS = ["single", "multi", "multi_win", "multi_routed",
             "multi_win_lanes"]
+
+
+def _tiling(f, bins):
+    """The tiling a batched pass over the shape runs with, under the
+    shape's budget."""
+    saved = H._VMEM_BUDGET
+    H._VMEM_BUDGET = CUT.get((f, bins), saved)
+    try:
+        return H.bin_tiling(bins, f, 128, RPB)
+    finally:
+        H._VMEM_BUDGET = saved
 
 
 def _cases():
     for wrapper in WRAPPERS:
         for f, bins in SHAPES:
-            til = H.bin_tiling(bins, f, 128, RPB)
-            if wrapper == "multi_routed" and not til.one_chunk:
+            if wrapper == "multi_routed" and not _tiling(f, bins).one_chunk:
                 continue            # the routed pass is one chunk only
             for miss in (False, True):
                 if wrapper == "single" and miss:
@@ -132,7 +147,10 @@ def _run(wrapper, d, bins, pallas: bool, two_col: bool = False,
 
 
 @pytest.mark.parametrize("wrapper,f,bins,miss", _cases())
-def test_tail_parity_with_segsum(wrapper, f, bins, miss):
+def test_tail_parity_with_segsum(monkeypatch, wrapper, f, bins, miss):
+    if (f, bins) in CUT:
+        monkeypatch.setattr(H, "_VMEM_BUDGET", CUT[f, bins])
+        assert not H.bin_tiling(bins, f, 128, RPB).one_chunk
     d = _data(f, bins, miss, seed=f * 100 + bins)
     got = _run(wrapper, d, bins, pallas=True)
     want = _run(wrapper, d, bins, pallas=False)
@@ -147,14 +165,15 @@ def test_tail_parity_with_segsum(wrapper, f, bins, miss):
 
 def test_tiling_of_the_cases():
     """The shapes above are the cases their names say."""
-    t = {s: H.bin_tiling(s[1], s[0], 128, RPB) for s in SHAPES}
+    t = {s: _tiling(*s) for s in SHAPES}
     assert [(t[s].f_pad, t[s].fc) for s in SHAPES] == [
         (32, 32), (28, 28), (72, 72), (68, 68), (8, 8), (80, 16),
-        (32, 32), (32, 32), (72, 72), (32, 32), (80, 16)]
+        (32, 32), (32, 32), (72, 72), (32, 32), (80, 16), (68, 68),
+        (48, 16)]
     assert [t[s].block_rows for s in SHAPES] == [
-        28, 28, 67, 67, 5, 16, 28, 28, 68, 30, 16]
+        28, 28, 67, 67, 5, 16, 28, 28, 68, 30, 16, 68, 16]
     assert [t[s].f_mask for s in SHAPES] == [
-        0, 0, 0, 0, 0, 67, 0, 0, 0, 0, 67]
+        0, 0, 0, 0, 0, 67, 0, 0, 0, 0, 67, 0, 44]
     assert not any(t[s].record()["xt_copied"] for s in SHAPES)
 
 
@@ -219,6 +238,5 @@ def test_fast_job_records_its_tiling(monkeypatch, f, extra, kinds):
         assert rec["xt_copied"] is False
         assert rec["t"] == 1024
         assert rec["mxu"] == "int8"     # quantized: int8 values
-        # 16 coarse bins: the one-hot slab by slab; the 32-bin window:
-        # feature by feature
-        assert rec["onehot"] == {"coarse": "slabs", "refine": "words"}[kind]
+        # 16 coarse bins and the 32-bin window: the one-hot slab by slab
+        assert rec["onehot"] == {"coarse": "slabs", "refine": "slabs"}[kind]

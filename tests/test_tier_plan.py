@@ -18,8 +18,9 @@ So is ``row_state`` (PR 33): the dump's rows take the plan's
 ``onehot`` (PR 34: the order the int8 one-hot is built in) was written
 into the dump's rows when it came: ``slabs`` at the coarse passes' 16
 and 8 bins, ``words`` on the 32-bin grid, ``plain`` where the pass
-contracts in bf16 (``test_onehot_follows_the_bins`` says so of every
-row).  A pass's ``chunks`` (``f_pad // fc``) and the record's
+contracts in bf16; the refine passes' 32 bins now read ``slabs`` too,
+and ``words`` is left at 64 bins and up (``test_onehot_follows_the_bins``
+says so of every row).  A pass's ``chunks`` (``f_pad // fc``) and the record's
 ``route`` (PR 35: where a wave's rows are routed) were written into
 the rows the same way, ``route`` by the row's own ``routed``
 (``kernel`` where it is true: every routed pass of the dump fits one
@@ -100,8 +101,8 @@ def test_plan_matches_parent(case):
 def test_onehot_follows_the_bins():
     """Every row's ``onehot`` is what its pass's type and bins give,
     reckoned here from the row's facts: bf16 passes build the plain
-    one-hot; int8 ones by words feature by feature on the 32-bin grid
-    and slab by slab off it."""
+    one-hot; int8 ones slab by slab up to 32 bins and off the 32-bin
+    grid, by words feature by feature at 64 bins and up."""
     seen = set()
     for case, row in GOLDEN.items():
         gp = row["grow_params"]
@@ -113,11 +114,15 @@ def test_onehot_follows_the_bins():
         for kind, rec in row["record"]["hist_tiling"].items():
             b_pad = -(-kinds[kind] // 8) * 8
             want = ("plain" if rec["mxu"] == "bf16" else
-                    "words" if b_pad % 32 == 0 else "slabs")
+                    "words" if b_pad % 32 == 0 and b_pad >= 64 else
+                    "slabs")
             assert rec["onehot"] == want, (case, kind)
-            seen.add((kind, want))
-    assert {("coarse", "slabs"), ("refine", "words"), ("full", "slabs"),
-            ("full", "words"), ("root", "plain")} <= seen
+            seen.add((kind, b_pad, want))
+    assert {("coarse", 16, "slabs"), ("refine", 32, "slabs"),
+            ("full", 64, "words"), ("full", 256, "words"),
+            ("root", 256, "plain")} <= seen
+    assert {k for k, b, w in seen if w == "slabs"} == {
+        "coarse", "refine", "full"}
 
 
 def test_wide_set_routes_by_gather():

@@ -33,13 +33,15 @@ Last, the order of the int8 one-hot's rows (``ops/histogram``
 ``_onehot_int8``, ``_feature_bin``): the batched kernels at the three
 cells' shapes, rows and lanes (21M x 28 on 64 two-column lanes, 20M x
 67 on 42, and a chip of the four-rank cell: 16M x 68 on 42), the
-PARENT's build of the one-hot (PR 33: the plain form off the 32-bin
-grid, kept here as the other side) beside the change's, both in this
-one process, each side its own trace.  The routed coarse pass and the
-root's coarse pass (16 bins) are what the order changes; the controls
-are a refine pass of each kind (32 bins: the words on both sides) and
-the routed coarse pass on float32 values (the bf16 contraction), which
-also has to equal the int8 one.  Every diff must be 0, and the ms a
+PARENT's build of the one-hot (on the 32-bin grid the ``words`` order,
+feature by feature, kept here as the other side) beside the change's,
+both in this one process, each side its own trace.  The refine pass
+of each kind (32 bins) is what the order changes; the controls are
+the routed and the root's coarse passes (16 bins: the slabs on both
+sides), the full-resolution pass at 64 and 256 bins (the words on
+both sides: against the slabs there the words measured level)
+and the routed coarse pass on float32 values (the bf16 contraction),
+which also has to equal the int8 one.  Every diff must be 0, and the ms a
 pass of each side is printed (medians of 6) with the seconds Mosaic
 took to compile it, the one-hot rows it streams, the us a one-hot row
 the pass measures (the unrouted pass of its kind at two feature
@@ -60,7 +62,6 @@ empty value, none).
 """
 import argparse
 import contextlib
-import math
 import os
 import statistics
 import sys
@@ -219,32 +220,35 @@ def check_bins(F: int, W: int, two_col: bool, B: int, shift: int,
 
 
 def _parent_onehot_int8(xb, b_pad):
-    """The int8 one-hot as the parent commit (PR 33) built it, kept
-    here as the other side: off the 32-bin grid the plain form
-    (compare element by element in int32, regroup, narrow twice), rows
-    in (feature, bin) order, feature rows of bin -1 up to the int8
-    tile; on it the words, which both sides share."""
-    if b_pad % 32 == 0:
+    """The int8 one-hot as the parent commit built it, kept here as
+    the other side: on the 32-bin grid the ``words`` order, feature by
+    feature (int8 row ``r * b_pad + b`` holds ``xb[r] == b``: each
+    feature's row broadcast over the sublanes of its ``(b_pad / 4, T)``
+    words, compared with their iota and selected); off it the slabs,
+    which both sides share."""
+    if b_pad % 32:
         return _CHANGE["_onehot_int8"](xb, b_pad)
+    from jax.experimental.pallas import tpu as pltpu
     R, T = xb.shape
-    extra = -R % (32 // math.gcd(b_pad, 32))
-    if extra:
-        xb = jnp.concatenate(
-            [xb, jnp.full((extra, T), -1, jnp.int32)], axis=0)
-        R += extra
-    onehot = (xb[:, None, :] ==
-              jax.lax.broadcasted_iota(jnp.int32, (R, b_pad, T), 1)
-              ).astype(jnp.int32)
-    return onehot.reshape(R * b_pad, T).astype(jnp.int8)
+    hi, byte = xb >> 2, jnp.left_shift(1, (xb & 3) << 3)
+    words = jnp.where(
+        hi[:, None, :] ==
+        jax.lax.broadcasted_iota(jnp.int32, (R, b_pad // 4, T), 1),
+        byte[:, None, :], 0).reshape(R * b_pad // 4, T)
+    return pltpu.bitcast(words, jnp.int8)
 
 
 def _parent_rows_to_feature_bin(acc, R, b_pad):
+    if b_pad % 32:
+        return _CHANGE["_rows_to_feature_bin"](acc, R, b_pad)
     return acc[..., :R * b_pad, :].reshape(
         *acc.shape[:-2], R, b_pad, acc.shape[-1])
 
 
 def _parent_onehot_rows(R, b_pad):
-    return (R + -R % (32 // math.gcd(b_pad, 32))) * b_pad
+    if b_pad % 32:
+        return _CHANGE["_onehot_rows"](R, b_pad)
+    return R * b_pad
 
 
 _CHANGE = {k: getattr(H, k) for k in (
@@ -275,12 +279,14 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
     (c2f shift 4: 16 coarse bins, a 32-bin window), the parent's build
     of the int8 one-hot beside the change's in one process: outputs
     equal bit for bit, the ms a pass of each side, the one-hot rows it
-    streams and the us a row.  The routed coarse pass and the root's
-    coarse pass are what the one-hot's order changes; the controls are
-    a refine pass of each kind (32 bins: the words on both sides) and
-    the routed coarse pass on float32 values (the bf16 contraction),
-    which must equal the int8 one bit for bit and read the same time
-    on both sides."""
+    streams and the us a row.  The refine pass of each kind is what
+    the one-hot's order changes; the controls are the routed and the
+    root's coarse passes (16 bins: the slabs on both sides), the
+    unrouted full-resolution pass at 64 and 256 bins (which no cell
+    runs, but which shares the build: the words on both sides) and the
+    routed coarse pass on float32 values (the bf16 contraction), which
+    must equal the int8 one bit for bit and read the same time on both
+    sides."""
     n = -(-rows // RPB) * RPB
     xb = jnp.asarray(np.random.default_rng(F).integers(
         0, 255, size=(F, n), dtype=np.uint8))
@@ -341,11 +347,15 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
             x, v, lb, ids_w, lo, 32, W, RPB, **kw)),
         "multi_win refine (the root's)": ("refine", lambda v, x, lo: win(
             x, v, selw, lo, 32, W, RPB, **kw)),
+        "multi full, 64 bins": ("full64", lambda v, x, lo: multi(
+            x, v, selw, 64, W, RPB, shift=2, **kw)),
+        "multi full, 256 bins": ("full256", lambda v, x, lo: multi(
+            x, v, selw, 256, W, RPB, **kw)),
     }
     if not chunked and F >= 32:     # a whole storage tile of rows
         passes["routed coarse in chunks (budget cut)"] = (
             "coarse", lambda v, x, lo: in_chunks(x, v, **kw))
-    bins = {"coarse": 16, "refine": 32}
+    bins = {"coarse": 16, "refine": 32, "full64": 64, "full256": 256}
 
     def timed(side, fn, *args):
         """(outputs, median ms of 6, seconds to compile) of ``fn``
@@ -404,7 +414,9 @@ def check_onehot_order(cell: str, F: int, rows: int, W: int,
     f2 = F - (80 if chunked else 32 if F > 32 else 16)
     us_a_row = {}
     for name, kind in (("multi coarse (the root's)", "coarse"),
-                       ("multi_win refine (the root's)", "refine")):
+                       ("multi_win refine (the root's)", "refine"),
+                       ("multi full, 64 bins", "full64"),
+                       ("multi full, 256 bins", "full256")):
         for side in SIDES:
             if (name, side) not in took:
                 continue
