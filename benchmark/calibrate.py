@@ -62,23 +62,23 @@ def main() -> int:
             if made is None or made[0] != key:
                 made = None
                 gc.collect()
-                made = (key, datagen.make_data(n, int(cfg["features"]),
-                                               cfg["data"], seed))
-            x, y = made[1]
+                made = (key, datagen.make(n, int(cfg["features"]),
+                                          cfg["data"], seed))
+            x, y, group = made[1]
             steps = int(cell.workload.get("reference_steps", 3))
             if args.program:
                 t0 = time.time()
-                tr = trainer_mod.Trainer(cell.params, x, y)
+                tr = trainer_mod.Trainer(cell.params, x, y, group=group)
+                run_mod.check_tier(cell, tr.tier())
                 for _ in range(1 + run_mod.warmup_steps(cell)):
                     tr.step()
-                run_mod.check_tier(cell, tr.tier())
                 produced = tr.produced()
                 tr.close()
                 del tr
                 gc.collect()
                 t1 = time.time()
                 nums = reference.compare(produced, x, y, cell.params, seed,
-                                         steps, log)
+                                         steps, log, group)
                 emit(cell=cell.name, seed=seed, who="program", numbers=nums,
                      train_s=round(t1 - t0, 1),
                      compare_s=round(time.time() - t1, 1))
@@ -93,9 +93,10 @@ def main() -> int:
             for who, kw in runs:
                 t0 = time.time()
                 produced = reference.train_in_place(x, y, cell.params,
-                                                    steps, seed, **kw)
+                                                    steps, seed, group=group,
+                                                    **kw)
                 nums = reference.compare(produced, x, y, cell.params, seed,
-                                         steps, log)
+                                         steps, log, group)
                 emit(cell=cell.name, seed=seed, who=who, numbers=nums,
                      took_s=round(time.time() - t0, 1))
     return 0

@@ -1,58 +1,53 @@
-"""The one general generator of training data: a dense float32 matrix
-and binary labels from ``--seed`` and a configuration's ``data`` block.
-
-A copy of ``bench.make_higgs_shaped``'s label model on
-``numpy.random.Generator`` float32 draws, in chunks of 1M rows that
-each have a stream of their own (so a few threads fill them and the
-result does not depend on which finishes first)."""
+"""Training data from ``--seed`` and a configuration's ``data`` block
+(``make``), made by the generator that the block names: the file
+``harness/generators/<generator>.py`` (found by ``cells.generator``),
+whose ``make(rows, features, spec, seed)`` returns ``x`` (float32,
+rows x features), ``y`` (float32) and ``group`` (int32 sizes of
+contiguous queries that sum to ``rows``, or None).  What it returns is
+checked here, so that neither the trainer nor the reference is handed
+a malformed set."""
 from __future__ import annotations
 
-from concurrent.futures import ThreadPoolExecutor
-from typing import Dict
+from typing import Dict, NamedTuple, Optional
 
 import numpy as np
 
-CHUNK = 1_000_000
-THREADS = 4
+from . import cells
 
 
-def make_data(rows: int, features: int, spec: Dict, seed: int):
-    """``spec`` keys: ``abs_every`` (every k-th column is |x|: momentum
-    like), ``integer_columns`` (the first k columns become counts,
-    floor(exp(integer_scale * x)): many ties, fewer bins),
-    ``logit_scale``, ``interaction`` ([i, j, weight]), ``bias`` and
-    ``model_seed`` of the label model, and ``generator``, which must be
-    ``higgs_shaped``.  The label model's weights come from
-    ``model_seed``, not from ``seed``: every seed draws fresh rows of
-    the same population, so that the trees, and with them the work of an
-    iteration, are alike from seed to seed."""
-    if spec.get("generator") != "higgs_shaped":
-        raise ValueError(f"unknown generator {spec.get('generator')!r}")
-    abs_every = int(spec.get("abs_every", 3))
-    n_int = int(spec.get("integer_columns", 0))
-    int_scale = np.float32(spec.get("integer_scale", 1.5))
-    i, j, w_ij = spec.get("interaction", [0, 1, 0.3])
-    scale = np.float32(spec.get("logit_scale", 0.5))
-    bias = np.float32(spec.get("bias", -0.1))
-    w = np.random.default_rng([int(spec.get("model_seed", 0)), 0xDA7A]) \
-        .standard_normal(features, dtype=np.float32)
-    x = np.empty((rows, features), np.float32)
-    y = np.empty(rows, np.float32)
+class Data(NamedTuple):
+    x: np.ndarray
+    y: np.ndarray
+    group: Optional[np.ndarray]
 
-    def fill(c: int) -> None:
-        lo, hi = c * CHUNK, min((c + 1) * CHUNK, rows)
-        rng = np.random.default_rng([seed, 0xDA7A, c + 1])
-        xc = x[lo:hi]
-        rng.standard_normal(out=xc, dtype=np.float32)
-        if abs_every > 0:
-            np.abs(xc[:, ::abs_every], out=xc[:, ::abs_every])
-        logits = (xc @ w) * scale + np.float32(w_ij) * xc[:, i] * xc[:, j] \
-            + bias
-        if n_int:
-            xc[:, :n_int] = np.floor(np.exp(int_scale * xc[:, :n_int]))
-        p = 1.0 / (1.0 + np.exp(-logits))
-        y[lo:hi] = rng.random(hi - lo, dtype=np.float32) < p
 
-    with ThreadPoolExecutor(THREADS) as pool:
-        list(pool.map(fill, range(-(-rows // CHUNK))))
+def make(rows: int, features: int, spec: Dict, seed: int,
+         root: str = cells.ROOT) -> Data:
+    name = spec.get("generator")
+    x, y, group = cells.generator(name, root).make(rows, features, spec,
+                                                   seed)
+    if not (x.dtype == np.float32 and x.shape == (rows, features)
+            and x.flags.c_contiguous
+            and y.dtype == np.float32 and y.shape == (rows,)):
+        raise ValueError(f"generator {name!r} made x {x.dtype} {x.shape}, "
+                         f"y {y.dtype} {y.shape}, not float32 "
+                         f"({rows}, {features}) and ({rows},)")
+    if group is not None:
+        group = np.asarray(group)
+        if not (group.dtype == np.int32 and group.ndim == 1
+                and group.size and int(group.min()) >= 1
+                and int(group.sum(dtype=np.int64)) == rows):
+            raise ValueError(f"generator {name!r} made query sizes that "
+                             f"are not int32, at least 1 and summing to "
+                             f"{rows}")
+    return Data(x, y, group)
+
+
+def make_data(rows: int, features: int, spec: Dict, seed: int,
+              root: str = cells.ROOT):
+    """``(x, y)`` of a generator that makes no query groups."""
+    x, y, group = make(rows, features, spec, seed, root)
+    if group is not None:
+        raise ValueError(f"generator {spec.get('generator')!r} makes "
+                         f"query groups: datagen.make returns them")
     return x, y

@@ -41,8 +41,8 @@ def lib():
                                "harness/ref_kernels.c")
         os.makedirs(BUILD_DIR, exist_ok=True)
         tmp = f"{so}.{os.getpid()}"
-        subprocess.run([cc, "-O2", "-shared", "-fPIC", "-o", tmp, src],
-                       check=True)
+        subprocess.run([cc, "-O2", "-shared", "-fPIC", "-o", tmp, src,
+                        "-lm"], check=True)
         os.replace(tmp, so)
     _LIB = ctypes.CDLL(so)
     _LIB.ref_split_rows.restype = _I64
@@ -119,3 +119,33 @@ def route_rows(x: np.ndarray, feature, threshold, left, right) -> np.ndarray:
                          _ptr(right), _I32(len(feature)), _ptr(out[lo:hi]))
     _map(run, n)
     return out
+
+
+def lambdarank(score: np.ndarray, label: np.ndarray, disc: np.ndarray,
+               bounds: np.ndarray, inv_max: np.ndarray, gains: np.ndarray,
+               sigmoid: float, norm_floor: float):
+    """(lambda, hessian) of every row: ``ref_lambdarank`` over runs of
+    whole queries that hold about as many pairs each, in threads."""
+    n, nq = len(score), len(bounds) - 1
+    score = np.ascontiguousarray(score, np.float64)
+    label = np.ascontiguousarray(label, np.int32)
+    disc = np.ascontiguousarray(disc, np.float64)
+    bounds = np.ascontiguousarray(bounds, np.int64)
+    inv_max = np.ascontiguousarray(inv_max, np.float64)
+    gains = np.ascontiguousarray(gains, np.float64)
+    g = np.zeros(n)
+    h = np.zeros(n)
+    L = lib()
+    pairs = np.cumsum(np.diff(bounds).astype(np.float64) ** 2)
+    cuts = np.searchsorted(pairs, pairs[-1] * np.arange(1, THREADS)
+                           / THREADS) if nq else []
+    edges = np.unique(np.concatenate([[0], cuts, [nq]])).astype(np.int64)
+
+    def run(q0, q1):
+        L.ref_lambdarank(_ptr(score), _ptr(label), _ptr(disc),
+                         _ptr(bounds), _ptr(inv_max), _I64(q0), _I64(q1),
+                         _ptr(gains), ctypes.c_double(sigmoid),
+                         ctypes.c_double(norm_floor), _ptr(g), _ptr(h))
+    with ThreadPoolExecutor(THREADS) as pool:
+        list(pool.map(run, edges[:-1], edges[1:]))
+    return g, h

@@ -2,6 +2,7 @@
  * Built once per checkout by harness/native.py; nothing of lightgbm_tpu
  * is read here.  Every function works on a contiguous run of rows so
  * that Python can hand disjoint runs to a few threads. */
+#include <math.h>
 #include <stdint.h>
 #include <stddef.h>
 
@@ -72,5 +73,47 @@ void ref_route_rows(const float *x, int64_t n, int32_t f_count,
             node = ((double)row[feature[node]] <= threshold[node])
                        ? left[node] : right[node];
         leaf_out[i] = ~node;
+    }
+}
+
+/* LambdaRank (NDCG) of the queries [q0, q1): query q is the rows
+ * bounds[q] .. bounds[q+1]; disc[i] is row i's discount at its
+ * position in its query sorted by score; inv_max[q] one over the
+ * query's best DCG; gains[l] label l's gain.  Every pair of a query
+ * whose labels differ adds its lambda and hessian to both rows; g and
+ * h come in zeroed. */
+void ref_lambdarank(const double *score, const int32_t *label,
+                    const double *disc, const int64_t *bounds,
+                    const double *inv_max, int64_t q0, int64_t q1,
+                    const double *gains, double sigmoid,
+                    double norm_floor, double *g, double *h)
+{
+    for (int64_t q = q0; q < q1; ++q) {
+        int64_t lo = bounds[q], hi = bounds[q + 1];
+        double im = inv_max[q];
+        if (im <= 0.0 || hi - lo < 2) continue;
+        double best = score[lo], worst = score[lo];
+        for (int64_t i = lo + 1; i < hi; ++i) {
+            if (score[i] > best) best = score[i];
+            if (score[i] < worst) worst = score[i];
+        }
+        int norm = best != worst;
+        for (int64_t i = lo; i < hi; ++i) {
+            for (int64_t j = i + 1; j < hi; ++j) {
+                int64_t a, b;           /* a: the higher label */
+                if (label[i] > label[j]) { a = i; b = j; }
+                else if (label[i] < label[j]) { a = j; b = i; }
+                else continue;
+                double ds = score[a] - score[b];
+                double delta = (gains[label[a]] - gains[label[b]])
+                               * fabs(disc[a] - disc[b]) * im;
+                if (norm) delta /= norm_floor + fabs(ds);
+                double p = 2.0 / (1.0 + exp(2.0 * sigmoid * ds));
+                double lam = delta * p;
+                double hes = 2.0 * delta * p * (2.0 - p);
+                g[a] -= lam; g[b] += lam;
+                h[a] += hes; h[b] += hes;
+            }
+        }
     }
 }

@@ -1,5 +1,9 @@
-"""The plain reference: histogram gradient boosting for a binary
-objective, in numpy float64 and the C loops of ref_kernels.c.
+"""The plain reference: histogram gradient boosting in numpy float64
+and the C loops of ref_kernels.c, for the objective of the file under
+``harness/objectives/`` that lists the trainer's ``objective``
+(``cells.objective``): its ``init_score``, its float64 ``gradients``
+and, where it has one, its ``loss``.  Query groups, where the data have
+them, go to the objective.
 
 It imports nothing of lightgbm_tpu and takes nothing the trainer made
 but its answers (trees, training score), which it judges.  The same
@@ -11,8 +15,10 @@ the reference routes every raw row through the trainer's tree, recounts
 its leaves, recomputes the root's hessian sum and each leaf's hessian
 sum and Newton step from its own float64 gradients at the state the
 trainer's earlier trees give, and grows its own best-first tree from
-that same state to see what loss decrease a sound step buys.  After the window it re-scores a sample of rows
-through every tree and holds the trainer's device score to that."""
+that same state to see what loss decrease a sound step buys (where the
+objective has no loss, what decrease of its second-order model).  After
+the window it re-scores a sample of rows through every tree and holds
+the trainer's device score to that."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -20,7 +26,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import native
+from . import cells, native
 
 BIN_SAMPLE_ROWS = 200_000
 SCORE_SAMPLE_ROWS = 65_536
@@ -78,7 +84,7 @@ class GrowParams:
 
 
 # ----------------------------------------------------------------------
-# binning, gradients, loss
+# binning, precision
 # ----------------------------------------------------------------------
 def make_bins(x: np.ndarray, max_bin: int, seed: int):
     """Quantile bins from a seeded sample: (uppers (F, 256) padded with
@@ -104,20 +110,6 @@ def make_bins(x: np.ndarray, max_bin: int, seed: int):
         uppers[j, :len(mids)] = mids
         nbins[j] = len(mids) + 1
     return uppers, nbins
-
-
-def init_score(y: np.ndarray) -> float:
-    p = float(np.mean(y, dtype=np.float64))
-    return float(np.log(p / (1.0 - p)))
-
-
-def gradients(score: np.ndarray, y: np.ndarray):
-    p = 1.0 / (1.0 + np.exp(-score))
-    return p - y, p * (1.0 - p)
-
-
-def logloss(score: np.ndarray, y: np.ndarray) -> float:
-    return float(np.mean(np.logaddexp(0.0, -(2.0 * y - 1.0) * score)))
 
 
 def _to_bf16(a: np.ndarray) -> np.ndarray:
@@ -228,26 +220,35 @@ def grow_tree(bins, uppers, nbins, g_hist, h_hist, g_leaf, h_leaf,
 def train_in_place(x, y, params: Dict, steps: int, seed: int,
                    hist_precision: str = "float64",
                    leaf_precision: str = "float64",
-                   fault: Optional[str] = None) -> Produced:
+                   fault: Optional[str] = None,
+                   group: Optional[np.ndarray] = None,
+                   root: str = cells.ROOT) -> Produced:
     """The reference run as if it were the trainer, for ``steps``
     iterations.  ``fault`` is None, ``state_unchanged`` (the score is
     not updated after a step), ``half_batch`` (the second half of the
-    rows is left out) or ``altered_answer`` (one leaf value of the
-    second tree is negated where it is produced)."""
+    rows, or of the queries where there are ``group``s, is left out) or
+    ``altered_answer`` (one leaf value of the second tree is negated
+    where it is produced)."""
+    obj = cells.objective(params.get("objective"), root)
     p = GrowParams.from_config(params)
     uppers, nbins = make_bins(x, p.max_bin, seed)
     bins = native.bin_rows(x, uppers, nbins)
     n = len(y)
-    # half_batch: the trees see the first half of the rows only; the
-    # trainer still says it trained on all of them and scores them all
-    rows = np.arange(n // 2, dtype=np.int32) if fault == "half_batch" \
-        else None
-    bias = init_score(y if rows is None else y[rows])
+    # half_batch: the trees see the first half of the rows (of whole
+    # queries) only; the trainer still says it trained on all of them
+    # and scores them all
+    rows, kept = None, group
+    if fault == "half_batch":
+        if group is not None:
+            kept = group[:len(group) // 2]
+        rows = np.arange(n // 2 if group is None else int(kept.sum()),
+                         dtype=np.int32)
+    bias = obj.init_score(y if rows is None else y[rows], kept, params)
     score = np.full(n, bias)
     rng = np.random.default_rng([seed, 0xC7])
     trees = []
     for k in range(steps):
-        g, h = gradients(score, y)
+        g, h = obj.gradients(score, y, group, params)
         gq, hq = cut_precision(g, h, hist_precision, rng)
         gl, hl = cut_precision(g, h, leaf_precision, rng)
         tree, leaf_of = grow_tree(bins, uppers, nbins, gq, hq, gl, hl, p,
@@ -278,10 +279,22 @@ def _worst_leaf(got, want) -> float:
     return float(np.max(np.abs(np.asarray(got, np.float64) - want) / denom))
 
 
+def _second_order(leaf_of, g, h, value) -> float:
+    """The change sum_l (G_l v_l + H_l v_l^2 / 2) that leaf values v on
+    a partition make to a loss of gradients g and hessians h."""
+    L = len(value)
+    return float(np.sum(np.bincount(leaf_of, g, L) * value
+                        + 0.5 * np.bincount(leaf_of, h, L) * value * value))
+
+
 def compare(produced: Produced, x, y, params: Dict, seed: int,
-            steps: int, log=lambda s: None) -> Dict[str, float]:
+            steps: int, log=lambda s: None,
+            group: Optional[np.ndarray] = None,
+            root: str = cells.ROOT) -> Dict[str, float]:
     """The numbers that decide ``correct`` (module docstring).  Every
     one is 0 for a trainer that agrees with the reference exactly."""
+    obj = cells.objective(params.get("objective"), root)
+    loss = getattr(obj, "loss", None)
     p = GrowParams.from_config(params)
     n = len(y)
     out = {"rows_gap": abs(produced.rows - n) / n,
@@ -297,13 +310,12 @@ def compare(produced: Produced, x, y, params: Dict, seed: int,
         return out
     uppers, nbins = make_bins(x, p.max_bin, seed)
     bins = native.bin_rows(x, uppers, nbins)
-    bias = init_score(y)
+    bias = obj.init_score(y, group, params)
     score = np.full(n, bias)
     for k in range(steps):
         tree = produced.trees[k]
         off = bias if k == 0 else 0.0
-        g, h = gradients(score, y)
-        loss0 = logloss(score, y)
+        g, h = obj.gradients(score, y, group, params)
         leaf_of = tree.route(x)
         L = tree.num_leaves
         cnt = np.bincount(leaf_of, minlength=L)
@@ -321,14 +333,26 @@ def compare(produced: Produced, x, y, params: Dict, seed: int,
         out["leaf_hess_gap"] = max(out["leaf_hess_gap"],
                                    _worst_leaf(tree.leaf_weight, H))
         after = score + (tree.leaf_value - off)[leaf_of]
-        loss_p = logloss(after, y)
         own, own_leaf = grow_tree(bins, uppers, nbins, g, h, g, h, p)
-        loss_r = logloss(score + own.leaf_value[own_leaf], y)
-        out["step_gain_gap"] = max(
-            out["step_gain_gap"], abs(loss_p - loss_r) / (loss0 - loss_r))
-        log(f"reference step {k}: loss before {loss0:.9f}, trainer's "
-            f"{loss_p:.9f}, reference's {loss_r:.9f} "
-            f"(leaves {L} vs {own.num_leaves})")
+        if loss is not None:
+            loss0 = loss(score, y, group, params)
+            loss_p = loss(after, y, group, params)
+            loss_r = loss(score + own.leaf_value[own_leaf], y, group,
+                          params)
+            gap = abs(loss_p - loss_r) / (loss0 - loss_r)
+            log(f"reference step {k}: loss before {loss0:.9f}, trainer's "
+                f"{loss_p:.9f}, reference's {loss_r:.9f} "
+                f"(leaves {L} vs {own.num_leaves})")
+        else:
+            # no loss: each tree's own values on its own partition, in
+            # the second-order model of the reference's gradients
+            q_p = _second_order(leaf_of, g, h, tree.leaf_value - off)
+            q_r = _second_order(own_leaf, g, h, own.leaf_value)
+            gap = abs(q_p - q_r) / -q_r
+            log(f"reference step {k}: second-order change, trainer's "
+                f"{q_p:.9g}, reference's {q_r:.9g} "
+                f"(leaves {L} vs {own.num_leaves})")
+        out["step_gain_gap"] = max(out["step_gain_gap"], gap)
         score = after
     # the device's score against every tree the trainer produced
     rng = np.random.default_rng([seed, 0x5C])

@@ -78,10 +78,11 @@ def _tree_arrays(tree) -> TreeArrays:
 
 class Trainer:
     def __init__(self, params: Dict, x: np.ndarray, y: np.ndarray,
-                 telemetry_file: Optional[str] = None):
+                 telemetry_file: Optional[str] = None,
+                 group: Optional[np.ndarray] = None):
         use_program()
         import lightgbm_tpu as lgb
-        self.dataset = lgb.Dataset(x, label=y, params=params)
+        self.dataset = lgb.Dataset(x, label=y, group=group, params=params)
         self.dataset.construct()
         self.booster = lgb.Booster(params, self.dataset)
         self.gbdt = self.booster._gbdt

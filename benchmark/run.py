@@ -153,17 +153,23 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     c_start = trainer_mod.counters()
 
     t0 = time.time()
-    x, y = datagen.make_data(n, features, cfg["data"], seed)
+    x, y, group = datagen.make(n, features, cfg["data"], seed, cell.root)
     spans["data_gen_s"] = time.time() - t0
     tmp = tempfile.mkdtemp(prefix="bench-")
     try:
         tr = trainer_mod.Trainer(
             cell.params, x, y,
-            os.path.join(tmp, "telemetry.jsonl") if trace else None)
+            os.path.join(tmp, "telemetry.jsonl") if trace else None,
+            group=group)
         spans["data_prep_s"] = time.time() - t0
+        queries = "" if group is None else f", {len(group)} queries"
         log(f"data: {n} x {features} from seed {seed}: generated in "
             f"{spans['data_gen_s']:.1f} s, binned and uploaded by "
-            f"{spans['data_prep_s']:.1f} s; positives {float(y.mean()):.4f}")
+            f"{spans['data_prep_s']:.1f} s; mean label "
+            f"{float(y.mean()):.4f}{queries}")
+        # the plan is whole once the booster is built: a trainer that
+        # lands on another tier stops here, before the warm-up
+        check_tier(cell, tr.tier())
         win = Window(tr, cell)
 
         # warm-up: iteration 0, then one block: every program of the
@@ -175,7 +181,6 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             tr.step()
         warm_trees = 1 + warm
         spans["warmup_s"] = time.time() - t0
-        check_tier(cell, tr.tier())
         c_warm = trainer_mod.counters()
         log(f"warm-up: {1 + warm} iterations in {spans['warmup_s']:.1f} s; "
             f"compile requests {c_warm.get('xla_compiles', 0):.0f} "
@@ -282,7 +287,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     t0 = time.time()
     steps = int(cell.workload.get("reference_steps", 3))
     numbers = reference.compare(produced, x, y, cell.params, seed, steps,
-                                log)
+                                log, group, cell.root)
     log(f"reference: {steps} steps compared in {time.time() - t0:.1f} s")
     limits = cell.workload["limits"]
     checks = {"failed_iterations": [failed, 0]}

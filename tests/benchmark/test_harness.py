@@ -1,14 +1,17 @@
 """The benchmark's own tests, at a size a CPU test run can hold.
 
 They cover the plain reference against the trainer on both growth
-tiers, the control (the reference in the precision below the cell's)
-and the planted faults coming out as not correct, the work count, the
-trace reduction on small recorded traces and on traces built by hand,
-the readers of the program's spans and counters, the loader finding
-files a later PR would add, and run.py's refusals.  Nothing here
-touches a JAX backend while it is imported."""
+tiers and on query-grouped data under lambdarank, the control (the
+reference in the precision below the cell's) and the planted faults
+coming out as not correct, the work count, the trace reduction on small
+recorded traces and on traces built by hand, the readers of the
+program's spans and counters, the loader finding files a later PR
+would add (a cell, a metric, a generator and an objective), and
+run.py's refusals.  Nothing here touches a JAX backend while it is
+imported."""
 from __future__ import annotations
 
+import copy
 import json
 import os
 import re
@@ -29,7 +32,8 @@ from harness import (cells, datagen, readers, reference,   # noqa: E402
                      trainer as trainer_mod, work, xplane)
 
 SEED = 2 ** 31 + 77          # the driver's seeds are large
-TINY = ("tiny.fused", "tiny.plain")
+TINY = ("tiny.fused", "tiny.plain", "tinyrank.fused")
+RANK = "tinyrank.fused"
 
 
 @pytest.fixture(scope="module")
@@ -68,19 +72,38 @@ def test_trainer_agrees_with_reference(bench_root, on_cpu, name):
 
 
 @pytest.mark.parametrize("name", TINY)
+def test_tier_record_is_whole_at_construction(bench_root, name):
+    """``run.py`` holds the tier record to the cell's file as soon as
+    the booster is built: the record is then already the one the
+    warm-up leaves."""
+    cell = cells.load_cell(name, bench_root)
+    x, y, group = datagen.make(cell.config["rows"], cell.config["features"],
+                               cell.config["data"], SEED, bench_root)
+    tr = trainer_mod.Trainer(cell.params, x, y, group=group)
+    built = copy.deepcopy(tr.tier())
+    bench_run.check_tier(cell, built)
+    for _ in range(1 + bench_run.warmup_steps(cell)):
+        tr.step()
+    assert tr.tier() == built
+    tr.close()
+
+
+@pytest.mark.parametrize("name", TINY)
 def test_control_is_not_correct(bench_root, name):
     """The reference in the precision below the cell's, put in the
     trainer's place, fails one of the cell's limits."""
     cell = cells.load_cell(name, bench_root)
-    x, y = datagen.make_data(cell.config["rows"], cell.config["features"],
-                             cell.config["data"], SEED)
+    x, y, group = datagen.make(cell.config["rows"], cell.config["features"],
+                               cell.config["data"], SEED, bench_root)
     c = cell.workload["control"]
-    exact = reference.train_in_place(x, y, cell.params, 3, SEED)
+    exact = reference.train_in_place(x, y, cell.params, 3, SEED,
+                                     group=group)
     cut = reference.train_in_place(x, y, cell.params, 3, SEED,
-                                   c["hist"], c["leaf"])
+                                   c["hist"], c["leaf"], group=group)
     limits = cell.workload["limits"]
-    n_exact = reference.compare(exact, x, y, cell.params, SEED, 3)
-    n_cut = reference.compare(cut, x, y, cell.params, SEED, 3)
+    n_exact = reference.compare(exact, x, y, cell.params, SEED, 3,
+                                group=group)
+    n_cut = reference.compare(cut, x, y, cell.params, SEED, 3, group=group)
     assert all(n_exact[k] <= v for k, v in limits.items()), n_exact
     assert any(n_cut[k] > v for k, v in limits.items()), n_cut
 
@@ -90,12 +113,18 @@ def _state_unchanged(monkeypatch):
 
 
 def _half_batch(monkeypatch):
+    """The first half of the rows, or of whole queries where there are
+    query groups."""
     init = trainer_mod.Trainer.__init__
 
-    def half(self, params, x, y, telemetry_file=None):
-        n = len(y) // 2
+    def half(self, params, x, y, telemetry_file=None, group=None):
+        if group is None:
+            n = len(y) // 2
+        else:
+            group = group[:len(group) // 2]
+            n = int(group.sum())
         init(self, params, np.ascontiguousarray(x[:n]), y[:n],
-             telemetry_file)
+             telemetry_file, group)
     monkeypatch.setattr(trainer_mod.Trainer, "__init__", half)
 
 
@@ -122,6 +151,17 @@ def test_fault_is_not_correct(bench_root, on_cpu, monkeypatch, plant):
     assert res["correct"] is False, res["checks"]
 
 
+@pytest.mark.parametrize("plant", [_state_unchanged, _half_batch,
+                                   _altered_answer],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_rank_fault_is_not_correct(bench_root, on_cpu, monkeypatch, plant):
+    """Lambdarank's cell, with the timed path broken underneath: half
+    the batch is the first half of the queries, whole."""
+    plant(monkeypatch)
+    res = run_tiny(bench_root, RANK)
+    assert res["correct"] is False, res["checks"]
+
+
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "altered_answer"])
 def test_reference_fault_is_not_correct(bench_root, fault):
@@ -132,6 +172,26 @@ def test_reference_fault_is_not_correct(bench_root, fault):
     nums = reference.compare(bad, x, y, cell.params, SEED, 3)
     assert any(not nums[k] <= v
                for k, v in cell.workload["limits"].items()), nums
+
+
+@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
+                                   "altered_answer"])
+def test_rank_reference_fault_is_not_correct(bench_root, fault):
+    """The faults planted in the lambdarank reference, put in the
+    trainer's place, fail the rank cell's limits; half the batch keeps
+    whole queries, so it shows as rows the trees never saw."""
+    cell = cells.load_cell(RANK, bench_root)
+    x, y, group = datagen.make(cell.config["rows"], cell.config["features"],
+                               cell.config["data"], SEED, bench_root)
+    bad = reference.train_in_place(x, y, cell.params, 3, SEED, fault=fault,
+                                   group=group)
+    nums = reference.compare(bad, x, y, cell.params, SEED, 3, group=group)
+    assert any(not nums[k] <= v
+               for k, v in cell.workload["limits"].items()), nums
+    if fault == "half_batch":
+        half = int(group[:len(group) // 2].sum())
+        assert nums["count_gap"] == pytest.approx(
+            (len(y) - half) / len(y), rel=1e-12)
 
 
 # ------------------------------------------------------------ reference
@@ -161,7 +221,8 @@ def test_bins_route_like_thresholds():
     uppers, nbins = reference.make_bins(x, p.max_bin, 3)
     bins = reference.native.bin_rows(x, uppers, nbins)
     assert bins.max() < 31 and nbins.max() <= 31
-    g, h = reference.gradients(np.zeros(len(y)), y)
+    g, h = cells.objective("binary").gradients(np.zeros(len(y)), y, None,
+                                               params)
     tree, leaf_of = reference.grow_tree(bins, uppers, nbins, g, h, g, h, p)
     assert tree.num_leaves == 9
     assert np.array_equal(tree.route(x), leaf_of)
@@ -185,8 +246,9 @@ def _brute_rows_touched(tree, x):
 
 def test_rows_touched_against_brute_force():
     x, y = datagen.make_data(3000, 4, {"generator": "higgs_shaped"}, 11)
-    params = {"num_leaves": 12, "learning_rate": 0.1, "min_data_in_leaf": 1,
-              "min_sum_hessian_in_leaf": 1.0, "max_bin": 63}
+    params = {"objective": "binary", "num_leaves": 12, "learning_rate": 0.1,
+              "min_data_in_leaf": 1, "min_sum_hessian_in_leaf": 1.0,
+              "max_bin": 63}
     made = reference.train_in_place(x, y, params, 1, 11)
     tree = made.trees[0]
     assert work.rows_touched(tree) == _brute_rows_touched(tree, x)
@@ -472,6 +534,117 @@ def test_loader_finds_files_added_later(bench_root):
     assert after == before | {"warmup_s"} and "warmup_s" not in other
     with pytest.raises(SystemExit):
         cells.load_cell("no.such.cell", bench_root)
+
+
+GENERATOR = '''"""A linear target with noise: a test's generator."""
+import numpy as np
+
+
+def make(rows, features, spec, seed):
+    rng = np.random.default_rng([seed, 0x11])
+    x = rng.standard_normal((rows, features), dtype=np.float32)
+    w = np.arange(1, features + 1, dtype=np.float32) * spec["scale"]
+    y = (x @ w + rng.standard_normal(rows, dtype=np.float32)).astype(
+        np.float32)
+    return x, y, None
+'''
+OBJECTIVE = '''"""Squared error: a test's objective."""
+import numpy as np
+
+ALIASES = ("regression", "l2", "mse")
+
+
+def init_score(y, group, params):
+    return float(np.mean(y, dtype=np.float64))
+
+
+def gradients(score, y, group, params):
+    return score - y, np.ones(len(y))
+
+
+def loss(score, y, group, params):
+    return float(np.mean(0.5 * (score - y) ** 2))
+'''
+
+
+def _add_files(root, files):
+    for rel, text in files.items():
+        with open(os.path.join(root, rel), "w") as f:
+            f.write(text if isinstance(text, str) else json.dumps(text))
+
+
+@pytest.fixture
+def reg_root(tmp_path):
+    """A fresh copy of benchmark/ to which a deployment is added as
+    files alone: a generator, a reference objective, a configuration
+    and a cell."""
+    root = str(tmp_path / "bench")
+    shutil.copytree(BENCH, root,
+                    ignore=shutil.ignore_patterns(".build", "__pycache__"))
+    shutil.copytree(os.path.join(HERE, "files"), root, dirs_exist_ok=True)
+    with open(os.path.join(root, "configs", "tiny.json")) as f:
+        config = json.load(f)
+    config.update(name="tinyreg", data={"generator": "linear_target",
+                                        "scale": 0.5})
+    config["params"]["objective"] = "regression"
+    with open(os.path.join(root, "workloads", "tiny.plain.json")) as f:
+        workload = json.load(f)
+    workload["config"] = "tinyreg"
+    _add_files(root, {"harness/generators/linear_target.py": GENERATOR,
+                      "harness/objectives/squared.py": OBJECTIVE,
+                      "configs/tinyreg.json": config,
+                      "workloads/tinyreg.plain.json": workload})
+    return root
+
+
+def test_loader_finds_generator_and_objective_added_later(reg_root, on_cpu):
+    """A deployment whose data and objective are new files loads and
+    runs, and is ``correct``, with no file of the copy edited."""
+    before = {p: open(os.path.join(BENCH, p), "rb").read()
+              for p in ("harness/datagen.py", "harness/reference.py",
+                        "harness/cells.py", "run.py")}
+    cell = cells.load_cell("tinyreg.plain", reg_root)
+    assert cell.root == reg_root
+    obj = cells.objective("l2", reg_root)
+    assert obj.__file__ == os.path.join(reg_root, "harness", "objectives",
+                                        "squared.py")
+    with pytest.raises(ValueError):
+        cells.objective("l2")           # not a file of the real harness
+    x, y, group = datagen.make(50, 3, cell.config["data"], SEED, reg_root)
+    assert group is None and x.shape == (50, 3)
+    res = bench_run.run_cell(cell, SEED, 0.3, False)
+    assert res["correct"], res["checks"]
+    assert res["observed"]["step_gain_gap"] < 0.05
+    for p, text in before.items():
+        with open(os.path.join(reg_root, p), "rb") as f:
+            assert f.read() == text
+
+
+@pytest.mark.parametrize("what", ["generator", "objective"])
+def test_unknown_names_are_refused_off_jax(reg_root, what):
+    """A configuration naming a generator or an objective no file
+    gives stops ``run.py`` at the loader, with the missing file in the
+    message, before JAX is imported."""
+    with open(os.path.join(reg_root, "configs", "tinyreg.json")) as f:
+        config = json.load(f)
+    if what == "generator":
+        config["data"]["generator"] = "no_such_shape"
+        missing = os.path.join("generators", "no_such_shape.py")
+    else:
+        config["params"]["objective"] = "huber"
+        missing = os.path.join("objectives", "huber.py")
+    _add_files(reg_root, {"configs/tinyreg.json": config})
+    code = ("import sys; sys.path.insert(0, sys.argv[1]); import run\n"
+            "try:\n    run.main(['--workload', 'tinyreg.plain', '--seed',"
+            " '1', '--seconds', '1'])\n"
+            "except SystemExit as e:\n"
+            "    print('jax' in sys.modules, e, file=sys.stderr)\n"
+            "    sys.exit(3)\n")
+    p = subprocess.run([sys.executable, "-c", code, reg_root],
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 3 and p.stdout.strip() == ""
+    last = p.stderr.strip().splitlines()[-1]
+    assert last.startswith("False ") and missing in last, p.stderr
 
 
 def _manifest():
