@@ -396,7 +396,9 @@ class GBDT:
             objective_rows=(
                 objective.shard_refusal() if objective is not None
                 else "custom objective: the host hands over gradients "
-                     "of the whole job's rows")))
+                     "of the whole job's rows"),
+            rank_layout=objective.layout if objective is not None
+            else None))
         self.grow_params = plan.grow_params
         self.tier_decision = plan.record
         self._counts_proxy = plan.grow_params.two_col
@@ -740,6 +742,12 @@ class GBDT:
             fn, rows = obj.gradient_fn_rows(), obj.rows()
             return lambda score: fn(score, rows)
         return obj.gradient_fn() or obj.get_gradients
+
+    def _tables_ride(self) -> bool:
+        """The objective has device tables, which the fused super-step
+        takes as its last argument."""
+        return self.objective is not None and \
+            bool(self.objective.table_names)
 
     def _score_rows(self, rows):
         """A per-row vector of ``n_pad`` rows at the score carry's
@@ -1128,10 +1136,18 @@ class GBDT:
             n_loc = n_pad // dist.row_shards
 
         pager_view = getattr(self, "_pager_view", None)
+        # the objective's device tables (lambdarank's query layout)
+        # ride as the last argument and are swapped in while the scan
+        # traces, so no table is a constant of the program; the
+        # battery's lifted scan keeps them closed over
+        tabled = not batched and self._tables_ride()
 
         def superstep(score, bag0, lr, quant_key, xt, base_mask,
                       num_bins, missing_type, is_cat, iters, fmasks,
                       tree_ids, *extras):
+            tables = {}
+            if tabled:
+                *extras, tables = extras
             if pager_view is not None:
                 # paged lane: the xt operand is a replicated dummy —
                 # the scan reads the matrix through page callbacks
@@ -1278,10 +1294,11 @@ class GBDT:
                     (host_rec, li.astype(li_dt), vals)
 
             try:
-                (final_sc, final_bag), (recs, leaf_idx_k, vals_k) = \
-                    jax.lax.scan(own_rows_step if own_rows else step,
-                                 (score, bag0),
-                                 (iters, fmasks, tree_ids))
+                with obj.tables_as(tables):
+                    (final_sc, final_bag), (recs, leaf_idx_k, vals_k) = \
+                        jax.lax.scan(own_rows_step if own_rows else step,
+                                     (score, bag0),
+                                     (iters, fmasks, tree_ids))
             finally:
                 if batched:
                     # the key/raw swap is trace-time state only —
@@ -1308,7 +1325,9 @@ class GBDT:
         closure capture would embed them in the remote-compile
         payload.  The objective's row tensors are arguments too where
         the row state is the shard's; elsewhere they stay
-        closure-captured because ``gradient_fn`` owns them.
+        closure-captured because ``gradient_fn`` owns them.  The
+        objective's tables (:meth:`Objective.tables`) are always
+        arguments, the last one.
 
         With a distributed learner the scan runs SPMD: the whole
         K-iteration program is wrapped in ``shard_map`` over the
@@ -1382,6 +1401,9 @@ class GBDT:
                     k: P(*([None] * (v.ndim - 1)), ax_name)
                     for k, v in self.objective.rows().items()}
                 in_specs = (sc_spec,) + in_specs[1:] + (rows_spec,)
+            if self._tables_ride():
+                # the objective's tables, whole on every device
+                in_specs = in_specs + (R,)
             if self._pager is not None:
                 # paged: the xt slot carries a replicated dummy; each
                 # program instance pages its OWN (f_loc, n_loc) block
@@ -1502,7 +1524,9 @@ class GBDT:
                 self._xt, self._base_mask, self._num_bins,
                 self._missing_type, self._is_cat, iters, fmasks,
                 tree_ids, *((self.objective.rows(),)
-                            if self._rows_on_shard else ()))
+                            if self._rows_on_shard else ()),
+                *((self.objective.tables(),)
+                  if self._tables_ride() else ()))
         # an abandoned attempt (elastic stall watchdog moved on and a
         # re-mesh owns ``self`` now) must not commit ANY state — the
         # checks bracket every device interaction
@@ -2597,10 +2621,17 @@ class GBDT:
         and ``collective_ops`` (kept for the telemetry record in
         ``_collective_last``).  Returns the trees' histogram passes,
         each tree's root pass included (a record's ``hist_passes``), or None on a tier
-        whose loop does not count (no batched passes)."""
+        whose loop does not count (no batched passes).  A pairwise
+        objective's iterations (one tree each) add ``rank_pair_slots``
+        (the slots its layout computes) and ``rank_pairs`` (its
+        queries' sum of L^2), on every tier."""
+        from ..utils.telemetry import counters
+        obj = self.objective
+        if getattr(obj, "pair_slots", None) is not None:
+            counters.incr("rank_pair_slots", n_trees * obj.pair_slots)
+            counters.incr("rank_pairs", n_trees * obj.pairs)
         if "n_arm_passes" not in recs:
             return None
-        from ..utils.telemetry import counters
         arm = np.atleast_1d(recs["n_arm_passes"])[:n_trees]
         waves = int(np.sum(np.atleast_1d(recs["n_waves"])[:n_trees]))
         self.last_arm_passes = int(arm[-1])

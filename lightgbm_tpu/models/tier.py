@@ -46,6 +46,9 @@ class TierFacts(NamedTuple):
     # (``Objective.shard_refusal``), or None: it is pointwise and hands
     # its row tensors over as arguments
     objective_rows: Optional[str] = None
+    # how a pairwise objective lays out its pairs (``Objective.layout``:
+    # lambdarank's ``buckets``), or None
+    rank_layout: Optional[str] = None
 
     @property
     def dist_active(self) -> bool:
@@ -392,4 +395,6 @@ def plan_tier(config: Config, facts: TierFacts) -> TierPlan:
                        if dist_active else [1]),
         "row_state": "replicated" if why_rows else "shard",
     }
+    if facts.rank_layout is not None:
+        record["rank_layout"] = facts.rank_layout
     return TierPlan(grow_params, record)

@@ -16,6 +16,7 @@ segment-id masked ops.
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Dict, Optional, Tuple, Type
 
 import jax
@@ -69,6 +70,14 @@ class Objective:
     # device its own rows of them as arguments (:meth:`rows`).  None
     # where a row's gradient reads other rows (lambdarank's queries)
     row_tensors: Optional[Tuple[str, ...]] = ("label", "weight")
+    # the device tables ``get_gradients`` reads beyond the row tensors,
+    # by attribute name (lambdarank's query layout): arguments of the
+    # programs that compute gradients (:meth:`gradient_fn`, the fused
+    # super-step), never their constants
+    table_names: Tuple[str, ...] = ()
+    # how the pairs of a listwise objective are laid out, for the tier
+    # record (``models/tier.py``); None for a pointwise objective
+    layout: Optional[str] = None
 
     # transform applied to raw score at predict time
     def __init__(self, config):
@@ -116,6 +125,14 @@ class Objective:
             yield
         finally:
             self.__dict__.update(saved)
+
+    def tables(self) -> Dict[str, object]:
+        """The objective's device tables, by attribute name (empty for
+        a pointwise objective)."""
+        return {k: getattr(self, k) for k in self.table_names}
+
+    # the tables swap as the row tensors do, while a program traces
+    tables_as = rows_as
 
     def place_rows(self, width: int, place) -> None:
         """Pad every row tensor to ``width`` rows and hand it to
@@ -210,9 +227,23 @@ class Objective:
         routing both paths through one compiled function is what makes
         the fused super-step bit-exact against the per-iteration path
         — and it is the faster form anyway (one pass over the score
-        array instead of one HBM round-trip per op)."""
+        array instead of one HBM round-trip per op).
+
+        An objective with :meth:`tables` hands them to the program as
+        an argument: the callable passes whatever :meth:`tables` holds
+        when it is called (a tracer's inside a program that swapped
+        them in, :meth:`tables_as`), so the program is the same for
+        every data set whose tables have the same shapes."""
         if getattr(self, "_gradient_fn_jit", None) is None:
-            self._gradient_fn_jit = jax.jit(self.get_gradients)
+            if not self.table_names:
+                self._gradient_fn_jit = jax.jit(self.get_gradients)
+            else:
+                def get_gradients(score, tables):
+                    with self.tables_as(tables):
+                        return self.get_gradients(score)
+                fn = jax.jit(get_gradients)
+                self._gradient_fn_jit = \
+                    lambda score: fn(score, self.tables())
         return self._gradient_fn_jit
 
     def boost_from_score(self, class_id: int = 0) -> float:
@@ -702,19 +733,93 @@ def default_label_gain(n: int = 31) -> np.ndarray:
                                    - 1.0)])
 
 
+# tiles of the pair tensors: a query's documents are padded to a
+# multiple of 8 up to 128, then of 128 (a lane-wide row)
+_SUBLANES, _LANES = 8, 128
+# slots (queries x L x L) of one chunk of a bucket's pair tensor
+_PAIR_SLOTS = int(2e7)
+# at most this many buckets: each is a loop of its own in the programs
+_MAX_BUCKETS = 8
+
+
+def _tile_length(lengths: np.ndarray) -> np.ndarray:
+    """Each query's length padded to the chip's tiles."""
+    lengths = np.asarray(lengths, np.int64)
+    return np.where(lengths <= _LANES, -(-lengths // _SUBLANES) * _SUBLANES,
+                    -(-lengths // _LANES) * _LANES)
+
+
+def bucket_lengths(lengths: np.ndarray,
+                   max_buckets: int = _MAX_BUCKETS) -> np.ndarray:
+    """The padded lengths of the buckets, ascending: at most
+    ``max_buckets`` of the tile lengths the queries take, the longest
+    always among them, chosen so that the pair slots
+    sum_q L_b(q)^2 are fewest (a query goes to the shortest bucket that
+    holds it; dynamic programming over the distinct tile lengths)."""
+    tops, counts = np.unique(_tile_length(lengths), return_counts=True)
+    k = len(tops)
+    below = np.concatenate([[0], np.cumsum(counts)])
+    sq = tops.astype(np.float64) ** 2
+    # cost[b, j]: least slots of the queries up to tops[j] in b + 1
+    # buckets, the last one tops[j]; prev[b, j]: that bucket's floor
+    cost = np.full((max_buckets, k), np.inf)
+    prev = np.full((max_buckets, k), -1)
+    cost[0] = below[1:] * sq
+    for b in range(1, max_buckets):
+        for j in range(1, k):
+            c = cost[b - 1, :j] + (below[j + 1] - below[1:j + 1]) * sq[j]
+            i = int(np.argmin(c))
+            cost[b, j], prev[b, j] = c[i], i
+    b, j = int(np.argmin(cost[:, -1])), k - 1
+    out = []
+    while j >= 0:
+        out.append(int(tops[j]))
+        j, b = int(prev[b, j]), b - 1
+    return np.asarray(out[::-1], np.int64)
+
+
+def bucket_plan(lengths: np.ndarray, max_buckets: int = _MAX_BUCKETS):
+    """``[(L, queries, chunks, cq)]``, a bucket each: its padded
+    length, the indices of its queries (a query goes to the shortest
+    bucket that holds it) and its chunks of ``cq`` queries, as even as
+    ``cq * L * L <= _PAIR_SLOTS`` allows, and at least two: XLA inlines
+    a loop of one trip, and the table-only part of its body is then
+    hoisted out of the fused super-step's scan, which the
+    per-iteration program has no loop to do; the two would fuse, and
+    round, differently."""
+    tops = bucket_lengths(lengths, max_buckets)
+    which = np.searchsorted(tops, _tile_length(lengths))
+    plan = []
+    for b, L in enumerate(int(t) for t in tops):
+        qs = np.flatnonzero(which == b)
+        chunks = max(2, -(-len(qs) // max(1, _PAIR_SLOTS // (L * L))))
+        plan.append((L, qs, chunks, -(-len(qs) // chunks)))
+    return plan
+
+
+def pair_slots(plan) -> int:
+    """The pair slots a :func:`bucket_plan` computes an iteration."""
+    return int(sum(chunks * cq * L * L for L, _, chunks, cq in plan))
+
+
 @register("lambdarank", "rank")
 class LambdaRank(Objective):
     """LambdaRank with NDCG gains (``rank_objective.hpp:19``).
 
     TPU-first: the reference's per-query pairwise loops become padded
-    (num_queries, max_docs) tensors — per-query sort, positional
-    discounts and an all-pairs (q, i, j) lambda tensor, chunked over
-    queries to bound memory.  Sigmoid uses the same
-    2/(1+exp(2*sigma*d)) shape the reference tabulates
+    tensors over buckets of queries of like length (``layout``): each
+    bucket a ``(queries, L)`` table of document indices, labels and
+    gains, its pairs an all-pairs (q, i, j) lambda tensor chunked over
+    queries to bound memory, with a per-query sort and positional
+    discounts.  The tables are the programs' arguments
+    (:meth:`Objective.tables`), not their constants.  Sigmoid uses the
+    same 2/(1+exp(2*sigma*d)) shape the reference tabulates
     (``rank_objective.hpp:194``).
     """
 
     row_tensors = None      # a document's lambda reads its query
+    table_names = ("_rank_tables",)
+    layout = "buckets"
 
     def __init__(self, config):
         super().__init__(config)
@@ -726,118 +831,126 @@ class LambdaRank(Objective):
                            else default_label_gain())
 
     def init(self, metadata, num_data):
+        from .utils.profiling import timed
+        from .utils.telemetry import counters
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             Log.fatal("lambdarank requires query information (set group)")
-        qb = np.asarray(metadata.query_boundaries)
-        self.num_queries = len(qb) - 1
-        cnts = np.diff(qb)
-        self.max_docs = int(cnts.max())
         lab = np.asarray(metadata.label).astype(np.int64)
         if lab.max() >= len(self.label_gain):
             Log.fatal("label %d exceeds label_gain table size %d",
                       int(lab.max()), len(self.label_gain))
-        # padded (nq, mq) row-index matrix; N = padding sentinel
-        nq, mq = self.num_queries, self.max_docs
-        idx = np.full((nq, mq), num_data, dtype=np.int32)
-        for q in range(nq):
-            idx[q, :cnts[q]] = np.arange(qb[q], qb[q + 1])
-        self._doc_idx = jnp.asarray(idx)
-        self._doc_valid = jnp.asarray(idx < num_data)
-        # inverse max DCG per query (truncated at max_position)
-        gains_per_row = self.label_gain[lab]
-        inv_max = np.zeros(nq)
-        for q in range(nq):
-            g = np.sort(gains_per_row[qb[q]:qb[q + 1]])[::-1]
-            g = g[:self.max_position]
-            dcg = np.sum(g / np.log2(np.arange(len(g)) + 2.0))
-            inv_max[q] = 1.0 / dcg if dcg > 0 else 0.0
-        self._inv_max_dcg = jnp.asarray(inv_max, jnp.float32)
-        # label/gain in PADDED (nq, mq) layout, precomputed once:
-        # gathering them per iteration costs two (nq*mq,)-element
-        # gathers of constants (XLA gathers are the slowest op on this
-        # target — see docs/Design.md)
-        lab_pad = np.concatenate([lab, [-1]])
-        gains_pad = np.concatenate([gains_per_row, [0.0]])
-        self._lbl_mat = jnp.asarray(lab_pad[idx], jnp.int32)
-        self._gain_mat = jnp.asarray(gains_pad[idx], jnp.float32)
+        with timed("rank/layout"):
+            self._rank_tables = self._layout(
+                np.asarray(metadata.query_boundaries, np.int64), lab,
+                num_data)
+        counters.set("rank_buckets", len(self._rank_tables["buckets"]))
+
+    def _inv_max_dcg(self, qb: np.ndarray, gains: np.ndarray) -> np.ndarray:
+        """1 / the best DCG of each query, truncated at
+        ``max_position`` (0 where it is 0)."""
+        nq = len(qb) - 1
+        qid = np.repeat(np.arange(nq), np.diff(qb))
+        order = np.lexsort((-gains, qid))       # by query, gain down
+        pos = np.arange(len(qid)) - qb[qid]
+        top = pos < self.max_position
+        dcg = np.bincount(qid[top], weights=(
+            gains[order] / np.log2(pos + 2.0))[top], minlength=nq)
+        return np.where(dcg > 0, 1.0 / np.where(dcg > 0, dcg, 1.0), 0.0)
+
+    def _layout(self, qb: np.ndarray, lab: np.ndarray, n: int,
+                max_buckets: int = _MAX_BUCKETS) -> Dict:
+        """The query tables of :func:`bucket_plan`'s buckets.  A bucket
+        of length L: ``idx`` ``(chunks, cq, L)`` (the documents' rows,
+        ``n`` on padding), ``lbl`` and ``gain`` beside it (-1 and 0 on
+        padding) and ``inv_max`` ``(chunks, cq)``.  ``pos`` ``(n,)`` is
+        each row's slot in the buckets' slots laid end to end.  Sets
+        ``pair_slots`` (what an iteration computes: sum over buckets of
+        chunks x cq x L^2) and ``pairs`` (sum_q L_q^2)."""
+        cnts = np.diff(qb)
+        gains = self.label_gain[lab]
+        inv_max = self._inv_max_dcg(qb, gains)
+        lab_pad = np.concatenate([lab, [-1]]).astype(np.int32)
+        gains_pad = np.concatenate([gains, [0.0]]).astype(np.float32)
+        pos = np.empty(n, np.int32)
+        buckets, base = [], 0
+        plan = bucket_plan(cnts, max_buckets)
+        for L, qs, chunks, cq in plan:
+            col = np.arange(L)
+            live = col < cnts[qs][:, None]
+            idx = np.full((chunks * cq, L), n, np.int32)
+            idx[:len(qs)] = np.where(live, qb[qs][:, None] + col, n)
+            im = np.zeros(chunks * cq, np.float32)
+            im[:len(qs)] = inv_max[qs]
+            flat = np.flatnonzero(live.reshape(-1))
+            pos[idx[:len(qs)].reshape(-1)[flat]] = base + flat
+            buckets.append({
+                "idx": jnp.asarray(idx.reshape(chunks, cq, L)),
+                "lbl": jnp.asarray(lab_pad[idx].reshape(chunks, cq, L)),
+                "gain": jnp.asarray(gains_pad[idx].reshape(chunks, cq, L)),
+                "inv_max": jnp.asarray(im.reshape(chunks, cq))})
+            base += idx.size
+        self.pair_slots = pair_slots(plan)
+        self.pairs = int(np.sum(cnts.astype(np.int64) ** 2))
+        return {"pos": jnp.asarray(pos), "buckets": tuple(buckets)}
 
     def get_gradients(self, score):
         # the whole pairwise computation runs as ONE jitted program:
-        # eagerly, every (cq, mq, mq) intermediate of the lambda chain
+        # eagerly, every (cq, L, L) intermediate of the lambda chain
         # materializes to HBM (tens of GB per iteration at this chip's
         # ~26 GB/s) — fused under jit it stays in registers/VMEM
-        nq, mq = self._doc_idx.shape
-        cq = max(1, min(nq, int(2e7 // max(mq * mq, 1))))
-        nchunks = (nq + cq - 1) // cq
-        n = int(score.reshape(-1).shape[0])
         return self._jitted_gradients(
-            self._grads_impl,
-            (score, self._doc_idx, self._doc_valid, self._inv_max_dcg,
-             self._lbl_mat, self._gain_mat),
-            n=n, nchunks=nchunks, cq=cq, norm=self.norm,
+            self._grads_impl, (score, self._rank_tables),
+            n=int(score.reshape(-1).shape[0]), norm=self.norm,
             sigmoid=self.sigmoid)
 
     @staticmethod
-    def _grads_impl(score, doc_idx_all, valid_all, inv_max_all,
-                    lbl_all, gain_all, weight, *, n, nchunks, cq, norm,
-                    sigmoid, weighted):
+    def _pairs(sc_pad, n, norm, sigmoid, args):
+        """One chunk of a bucket: each slot's lambda and hessian sums
+        over its query's pairs."""
+        doc_idx, inv_max, lbl, gain = args
+        valid = doc_idx < n
+        s = sc_pad[doc_idx]                          # (cq, L)
+        order = jnp.argsort(-jnp.where(valid, s, -jnp.inf), axis=1,
+                            stable=True)
+        rank = jnp.argsort(order, axis=1)            # row -> position
+        disc = 1.0 / jnp.log2(2.0 + rank.astype(jnp.float32))
+        # pairwise (cq, L, L): i = high candidate, j = low
+        li = lbl[:, :, None]
+        lj = lbl[:, None, :]
+        pair_ok = (li > lj) & valid[:, :, None] & valid[:, None, :]
+        ds = s[:, :, None] - s[:, None, :]
+        dg = gain[:, :, None] - gain[:, None, :]
+        dd = jnp.abs(disc[:, :, None] - disc[:, None, :])
+        delta = dg * dd * inv_max[:, None, None]
+        if norm:
+            smax = jnp.max(jnp.where(valid, s, -jnp.inf), axis=1)
+            smin = jnp.min(jnp.where(valid, s, jnp.inf), axis=1)
+            nz = (smax != smin)[:, None, None]
+            delta = jnp.where(nz, delta / (0.01 + jnp.abs(ds)), delta)
+        p = 2.0 / (1.0 + jnp.exp(jnp.clip(2.0 * sigmoid * ds, -60.0, 60.0)))
+        lam = jnp.where(pair_ok, -delta * p, 0.0)
+        hes = jnp.where(pair_ok, 2.0 * delta * p * (2.0 - p), 0.0)
+        g_doc = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
+        h_doc = jnp.sum(hes, axis=2) + jnp.sum(hes, axis=1)
+        return g_doc, h_doc
+
+    @staticmethod
+    def _grads_impl(score, tables, weight, *, n, norm, sigmoid, weighted):
         score = score.reshape(-1)
         sc_pad = jnp.concatenate([score, jnp.array([-jnp.inf],
                                                    score.dtype)])
-
-        def query_chunk(args):
-            doc_idx, valid, inv_max, lbl, gain = args
-            s = sc_pad[doc_idx]                      # (cq, mq)
-            order = jnp.argsort(-jnp.where(valid, s, -jnp.inf), axis=1,
-                                stable=True)
-            rank = jnp.argsort(order, axis=1)        # row -> position
-            disc = 1.0 / jnp.log2(2.0 + rank.astype(jnp.float32))
-            # pairwise (cq, mq, mq): i = high candidate, j = low
-            li = lbl[:, :, None]
-            lj = lbl[:, None, :]
-            pair_ok = (li > lj) & valid[:, :, None] & valid[:, None, :]
-            ds = s[:, :, None] - s[:, None, :]
-            dg = gain[:, :, None] - gain[:, None, :]
-            dd = jnp.abs(disc[:, :, None] - disc[:, None, :])
-            delta = dg * dd * inv_max[:, None, None]
-            if norm:
-                smax = jnp.max(jnp.where(valid, s, -jnp.inf), axis=1)
-                smin = jnp.min(jnp.where(valid, s, jnp.inf), axis=1)
-                nz = (smax != smin)[:, None, None]
-                delta = jnp.where(nz, delta / (0.01 + jnp.abs(ds)),
-                                  delta)
-            p = 2.0 / (1.0 + jnp.exp(jnp.clip(
-                2.0 * sigmoid * ds, -60.0, 60.0)))
-            lam = jnp.where(pair_ok, -delta * p, 0.0)
-            hes = jnp.where(pair_ok, 2.0 * delta * p * (2.0 - p), 0.0)
-            g_doc = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
-            h_doc = jnp.sum(hes, axis=2) + jnp.sum(hes, axis=1)
-            return doc_idx, g_doc, h_doc
-
-        nq, mq = doc_idx_all.shape
-        pad_q = nchunks * cq - nq
-        di = jnp.concatenate([doc_idx_all,
-                              jnp.full((pad_q, mq), n, jnp.int32)])
-        dv = jnp.concatenate([valid_all,
-                              jnp.zeros((pad_q, mq), bool)])
-        im = jnp.concatenate([inv_max_all, jnp.zeros(pad_q,
-                                                     jnp.float32)])
-        lm = jnp.concatenate([lbl_all,
-                              jnp.full((pad_q, mq), -1, jnp.int32)])
-        gm = jnp.concatenate([gain_all,
-                              jnp.zeros((pad_q, mq), jnp.float32)])
-        grad = jnp.zeros(n + 1, jnp.float32)
-        hess = jnp.zeros(n + 1, jnp.float32)
-        idxs, gs, hs = jax.lax.map(
-            query_chunk, (di.reshape(nchunks, cq, mq),
-                          dv.reshape(nchunks, cq, mq),
-                          im.reshape(nchunks, cq),
-                          lm.reshape(nchunks, cq, mq),
-                          gm.reshape(nchunks, cq, mq)))
-        grad = grad.at[idxs.reshape(-1)].add(gs.reshape(-1))
-        hess = hess.at[idxs.reshape(-1)].add(hs.reshape(-1))
-        grad, hess = grad[:n], hess[:n]
+        pairs = functools.partial(LambdaRank._pairs, sc_pad, n, norm,
+                                  sigmoid)
+        gs, hs = [], []
+        for b in tables["buckets"]:
+            g, h = jax.lax.map(pairs, (b["idx"], b["inv_max"], b["lbl"],
+                                       b["gain"]))
+            gs.append(g.reshape(-1))
+            hs.append(h.reshape(-1))
+        # a document sits in one slot: its row's sums are one gather
+        grad = jnp.concatenate(gs)[tables["pos"]]
+        hess = jnp.concatenate(hs)[tables["pos"]]
         if weighted:
             grad = grad * weight
             hess = hess * weight

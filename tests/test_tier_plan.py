@@ -38,6 +38,7 @@ import pytest
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.models.tier import TierFacts, plan_tier
+from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops.grow import c2f_bins, routed_gate
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -233,6 +234,30 @@ def test_row_state_refusals(extra, why):
     assert record["gates"]["row_state"].startswith(why)
 
 
+def test_rank_layout_only_where_pairs_are_laid_out():
+    """``rank_layout`` is in the record where the objective lays out
+    pairs (lambdarank's ``buckets``: the ``mslr137.fast@tpu`` row, the
+    plan's own answer) and in no other row; without it the plan is the
+    same record less that key."""
+    for row in GOLDEN.values():
+        assert ("rank_layout" in row["record"]) == \
+            (row["facts"].get("rank_layout") is not None)
+    row = GOLDEN["mslr137.fast@tpu"]
+    config = Config(dict(row["params"], verbose=-1))
+    assert create_objective(config.objective, config).layout == \
+        row["record"]["rank_layout"] == "buckets"
+    record = plan_tier(config, _facts(row)).record
+    without = plan_tier(config,
+                        _facts(row)._replace(rank_layout=None)).record
+    assert without == {k: v for k, v in record.items()
+                       if k != "rank_layout"}
+    for name, extra in (("binary", {}), ("regression", {}),
+                        ("multiclass", {"num_class": 3}),
+                        ("xentropy", {})):
+        cfg = Config(dict(extra, objective=name))
+        assert create_objective(name, cfg).layout is None
+
+
 def _cells():
     return sorted(os.path.basename(p)[:-len(".json")]
                   for p in glob.glob(os.path.join(BENCH, "workloads",
@@ -265,6 +290,8 @@ def test_workload_expect_tier(cell_name):
         max_bin=max_bin, any_cat=False, any_missing=False, efb_groups=0,
         forced=(), use_pool=True,
         rows_per_block=int(config.tpu_rows_per_block), monotone=(),
-        penalty=())).record
+        penalty=(),
+        rank_layout=create_objective(config.objective, config).layout)
+    ).record
     expect = cell.workload["expect_tier"]
     assert {k: record[k] for k in expect} == expect
